@@ -1,25 +1,12 @@
 #!/usr/bin/env bash
-# Robust local test runner: one pytest process per test file, sharing a
-# persistent XLA compilation cache.
+# The fast tier as the driver runs it: CPU, six xdist workers, one test
+# file per worker at a time (--dist loadfile keeps a file's module-scoped
+# fixtures — and the one process that may describe a TPU topology — in one
+# worker).  The workers share the persistent XLA compile cache that
+# tests/conftest.py arms (aot.compile_cache_dir).
 #
-# Why not one `pytest tests/`: the XLA:CPU compiler in the pinned jaxlib can
-# segfault after many compiles/executable-loads within a single process
-# (observed mid-suite in backend_compile_and_load / compilation-cache
-# (de)serialization).  Per-file processes keep each process comfortably
-# below the trigger, and the shared cache keeps aggregate runtime close to
-# a single warm run.  `pytest tests/` still works (and is what the wheel
-# environments with out-of-process compile services use).
-#
-# Usage: ./run_tests.sh [extra pytest args...]   e.g. ./run_tests.sh -m "not slow"
+# Usage: ./run_tests.sh [extra pytest args...]   e.g. ./run_tests.sh -m slow
 set -u
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/tpusppy_xla}"
-fail=0
-for f in tests/test_*.py; do
-  echo "== $f"
-  python -m pytest "$f" -q "$@"
-  rc=$?
-  # exit 5 = no tests collected (e.g. a fully slow-marked file under
-  # -m "not slow"): not a failure
-  if [ $rc -ne 0 ] && [ $rc -ne 5 ]; then fail=1; fi
-done
-exit $fail
+exec env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
+  --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+  --dist loadfile -p no:randomly "$@"

@@ -25,11 +25,29 @@ from tpusppy.solvers import aot
 from tpusppy.solvers.admm import ADMMSettings
 
 
+def _set_jax_cache(enabled, path=None):
+    """Re-arm jax's persistent compilation cache (the flag is memoized
+    at first use, hence the reset)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    if path is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    cc.reset_cache()
+
+
 @pytest.fixture
 def cache_dir(tmp_path):
+    """An armed AOT store with jax's persistent cache OFF: an executable
+    that comes out of a warm jax cache is deliberately not serialized on
+    XLA:CPU (test_executable_from_jax_cache_is_not_serialized), so the
+    serialize-on-miss pins below need every compile to be a real one."""
     d = tmp_path / "aot"
     aot.set_cache_path(str(d))
+    jax_dir = jax.config.jax_compilation_cache_dir
+    _set_jax_cache(False)
     yield str(d)
+    _set_jax_cache(True, jax_dir)
     aot.reset()
 
 
@@ -76,6 +94,89 @@ def test_miss_serialize_then_fresh_process_hit(cache_dir):
     assert metrics.value("aot.hits") == 1
     assert metrics.value("aot.misses") == 1
     assert np.all(np.isfinite(r3))
+
+
+def _roundtrip_on(x, cache_dir):
+    """Miss, then a fresh-store hit, for one placed input; returns the
+    loaded program's output."""
+    g = aot.cached_program(_toy(), "toy", key_extra=("placed",))
+    r1 = g(x, 2.0)
+    assert metrics.value("aot.misses") == 1
+    aot._loaded.clear()
+    r2 = aot.cached_program(_toy(), "toy", key_extra=("placed",))(x, 2.0)
+    assert metrics.value("aot.hits") == 1
+    assert metrics.value("aot.load_errors") == 0
+    assert not [f for f in os.listdir(cache_dir) if f.endswith(".bad")]
+    np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
+    return r2
+
+
+def test_one_device_of_eight_loads_onto_that_device(cache_dir):
+    """The entry records the device the program was compiled for: a
+    one-device program on a many-device host must come back on THAT
+    device, not on every device of the backend."""
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.arange(36.0).reshape(6, 6), dev)
+    assert _roundtrip_on(x, cache_dir).devices() == {dev}
+
+
+def test_four_device_mesh_program_roundtrips(cache_dir):
+    """A program sharded over a 4-device mesh (here devices 4..7 in
+    reverse, so assignment ORDER matters) reloads onto the same mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[4:8][::-1]), ("scen",))
+    sh = NamedSharding(mesh, P("scen"))
+    x = jax.device_put(jnp.arange(64.0).reshape(8, 8), sh)
+    r = _roundtrip_on(x, cache_dir)
+    assert r.sharding.device_set == set(mesh.devices.flat)
+
+
+def test_entry_for_absent_device_is_a_miss_not_a_quarantine(cache_dir):
+    g = aot.cached_program(_toy(), "toy")
+    x = np.ones((6, 6))
+    g(x, 2.0)
+    (name,) = _aotx_files(cache_dir)
+    path = os.path.join(cache_dir, name)
+    with open(path, "rb") as f:
+        obj = pickle.load(f)
+    obj["devices"] = [10 ** 6]
+    with open(path, "wb") as f:
+        pickle.dump(obj, f)
+    aot._loaded.clear()
+    assert np.all(np.isfinite(np.asarray(
+        aot.cached_program(_toy(), "toy")(x, 2.0))))
+    assert metrics.value("aot.hits") == 0
+    assert metrics.value("aot.load_errors") == 0
+    assert not os.path.exists(path + ".bad")
+
+
+def test_executable_from_jax_cache_is_not_serialized(cache_dir, tmp_path,
+                                                     monkeypatch):
+    """XLA:CPU: an executable handed back by jax's persistent cache
+    re-serializes into an artifact that loads and then fails at execute
+    (scripts/aot_cache_origin_probe.py), so the store must skip it —
+    detected per compiling thread, without touching jax's global flag."""
+    # a jax cache of this test's own, placed the way a caller places it
+    # (aot.arm_compile_cache follows the variable)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jaxcache"))
+    _set_jax_cache(True, str(tmp_path / "jaxcache"))
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        x = np.arange(36.0).reshape(6, 6)
+        r1 = np.asarray(aot.cached_program(_toy(), "toy")(x, 2.0))
+        (name,) = _aotx_files(cache_dir)       # a real compile: stored
+        assert metrics.value("aot.from_jax_cache") == 0
+        os.remove(os.path.join(cache_dir, name))
+        aot._loaded.clear()
+        r2 = np.asarray(aot.cached_program(_toy(), "toy")(x, 2.0))
+        assert metrics.value("aot.from_jax_cache") == 1
+        assert _aotx_files(cache_dir) == []
+        np.testing.assert_array_equal(r1, r2)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)
 
 
 def test_version_bump_is_clean_miss(cache_dir, monkeypatch):

@@ -13,9 +13,6 @@ import pytest
 
 from tpusppy.solvers import pallas_kernels
 
-pytestmark = pytest.mark.skipif(
-    not pallas_kernels.HAVE_PALLAS, reason="pallas unavailable")
-
 
 def _xla_sweeps(q, A, cl, cu, lb, ub, rho_a, rho_x, state, n_sweeps,
                 n_refine, sigma, alpha, Kinv, K):

@@ -23,7 +23,7 @@ One subsystem for every number and event the stack emits about itself:
 
 Grew out of the PR-3 fragments (hostsync fetch counters, per-segment
 ``mfu_pct`` / ``dispatch_overhead_pct``); see doc/observability.md for the
-event taxonomy and track naming.
+event catalogue and track naming.
 """
 
 from . import log, metrics, perfetto, report, telemetry, trace  # noqa: F401

@@ -5,7 +5,7 @@ The single source for every number the bench reports (the PR-3
 this): :mod:`tpusppy.solvers.hostsync` feeds the ``host_sync.*``
 counters on every decision-path fetch, the segmented dispatcher bills
 ``speculation.*``, the mailboxes count puts/skips, and so on — see
-doc/observability.md for the key taxonomy.
+doc/observability.md for the key catalogue.
 
 Metrics are ALWAYS on (unlike the trace ring): each update is one lock +
 an int/float add, cheap enough for every hot path that already crosses

@@ -276,7 +276,7 @@ class PHBase(SPOpt):
         window (``refresh_every - 1``: one legacy refresh dispatch + one
         megastep per cadence block), clamped by the watchdog cap
         (:func:`~tpusppy.solvers.segmented.megastep_cap` — a megastep is
-        N iterations of work against the worker's per-execution kill).
+        N iterations of work inside one dispatch budget).
         """
         from .extensions.extension import Extension
         from .ir import BucketedBatch

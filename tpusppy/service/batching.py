@@ -76,7 +76,7 @@ _CTR_WINDOWS = _metrics.counter("batching.windows")
 _G_SLOTS = _metrics.gauge("batching.slots")
 
 #: Source char for bound updates installed from the batched wheel —
-#: joins the established taxonomy ('*' default, 'M' megastep, 'I'
+#: joins the established glyph set ('*' default, 'M' megastep, 'I'
 #: integer escalation, 'R' resume seed; doc/pipeline.md).
 BATCH_SOURCE_CHAR = "B"
 

@@ -428,6 +428,9 @@ class SolveServer:
         from .. import tune as _tune
         from ..solvers import aot as _aot
 
+        # jax's compile cache sits at its fixed place (never under the
+        # per-process temp work_dir: a directory that moves never hits)
+        _aot.arm_compile_cache()
         if not _aot.cache_path():
             _aot.set_cache_path(os.path.join(self.work_dir, "aot"))
         if _aot.enabled():

@@ -8,7 +8,7 @@ programs — are compiled once per (shape, settings, mesh, toolchain) and
 then recompiled from scratch by EVERY process that touches them: every
 resume, every ladder rung, every ``dist_wheel`` controller pays the full
 XLA lower+compile again (UC ~17 s, farmer ~3.5 s per process —
-BENCH_r05/r06 ``compile_iter0_s``).  This module persists the compiled
+BENCH_r06 ``compile_iter0_s``).  This module persists the compiled
 executables themselves (``jax.jit(...).lower().compile()`` serialized via
 :mod:`jax.experimental.serialize_executable`) in a content-addressed
 on-disk cache, so a repeated, resumed, or ladder-sibling run skips XLA
@@ -39,15 +39,18 @@ belt-and-braces in-file version guard rejects foreign payloads that were
 renamed into place).  Corrupted/truncated files deserialize-fail into a
 clean miss-and-recompile, never a crash and never a stale hit.
 
-Fallback tier: arming this cache also points JAX's persistent
-compilation cache (``jax_compilation_cache_dir``) at ``<dir>/xla`` when
-the process hasn't configured one, so programs nobody explicitly wrapped
-still compile warm from the disk cache (they re-pay tracing, not XLA).
+Fallback tier: arming this cache also arms JAX's persistent compilation
+cache at :func:`compile_cache_dir` — ``$JAX_COMPILATION_CACHE_DIR`` when
+the caller placed one, else ``<checkout>/.jax_cache`` — so programs
+nobody explicitly wrapped still compile warm from the disk cache (they
+re-pay tracing, not XLA).  That function is the ONLY place the program
+names a compile-cache directory; a fixed path matters because the path
+is part of JAX's cache key (a directory that moves never hits).
 
 Scope: single-controller processes only (``jax.process_count() == 1``) —
 a multi-controller mesh's executables embed global device assignments
 this loader does not reconstruct.  See doc/autotuner.md ("Cold start")
-and doc/observability.md for the ``aot.*`` counter taxonomy.
+and doc/observability.md for the ``aot.*`` counter names.
 """
 
 from __future__ import annotations
@@ -71,7 +74,7 @@ _log = get_logger("aot")
 
 #: In-file payload format version (independent of the key hash — guards
 #: files renamed/copied into place from a foreign build).
-_FORMAT_VERSION = 1
+_FORMAT_VERSION = 2
 
 #: Cap for :func:`prewarm` with ``keys=None`` (newest-first): loading a
 #: whole long-lived cache directory eagerly would burn startup time on
@@ -85,6 +88,7 @@ _CTR_SERIALIZE_ERRORS = _metrics.counter("aot.serialize_errors")
 _CTR_UNSERIALIZABLE = _metrics.counter("aot.unserializable")
 _CTR_QUARANTINED = _metrics.counter("aot.quarantined")
 _CTR_PREWARMED = _metrics.counter("aot.prewarmed")
+_CTR_FROM_JAX_CACHE = _metrics.counter("aot.from_jax_cache")
 _HIST_COMPILE_S = _metrics.histogram("aot.compile_s")
 _HIST_SERIALIZE_S = _metrics.histogram("aot.serialize_s")
 _HIST_DESERIALIZE_S = _metrics.histogram("aot.deserialize_s")
@@ -102,7 +106,6 @@ _xla_work_lock = threading.RLock()
 _cache_path_override: str | None = None
 _loaded: dict = {}            # key -> loaded jax Compiled
 _session_keys: list = []      # keys compiled-or-loaded, insertion order
-_fallback_armed_for: str | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -153,35 +156,71 @@ def _multiprocess() -> bool:
 def reset():
     """Drop every in-memory executable and the path override (test
     isolation; on-disk files are untouched)."""
-    global _cache_path_override, _fallback_armed_for, _multiprocess_memo
+    global _cache_path_override, _multiprocess_memo
     with _lock:
         _loaded.clear()
         _session_keys.clear()
     _cache_path_override = None
-    _fallback_armed_for = None
     _multiprocess_memo = None
 
 
-def _ensure_fallback_cache(d: str):
-    """Arm JAX's persistent compilation cache at ``<dir>/xla`` as the
-    fallback tier for programs not explicitly AOT-wrapped — only when the
-    process hasn't already configured one (an operator's cache dir always
-    wins)."""
-    global _fallback_armed_for
-    if _fallback_armed_for == d:
-        return
-    _fallback_armed_for = d
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return
-    try:
-        import jax
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(d, "xla"))
-    except Exception as e:       # never let the fallback tier break a run
-        _log.warning("could not arm the jax compilation cache: %r", e)
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives for this program:
+    ``$JAX_COMPILATION_CACHE_DIR`` when the caller placed it, else the
+    fixed in-checkout ``.jax_cache`` (git-ignored)."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def arm_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at
+    :func:`compile_cache_dir` and export it so child processes share the
+    directory.  Idempotent; every entry point that wants warm compiles
+    (the AOT fallback tier, the wheel spinner, bench, the test suite)
+    calls this and nothing else."""
+    import jax
+
+    d = compile_cache_dir()
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", d)
+    if jax.config.jax_compilation_cache_dir != d:
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
+
+
+# ---------------------------------------------------------------------------
+# Executables that came out of JAX's persistent cache must not be
+# re-serialized on XLA:CPU: `serialize` of such an executable yields a
+# smaller artifact that LOADS in the next process and then fails at
+# execute ("Function wrapped_add not found"; reproduced on jaxlib 0.9.0 by
+# scripts/aot_cache_origin_probe.py).  JAX reports a persistent-cache hit
+# synchronously on the compiling thread, so a thread-local tally taken
+# around our own compile attributes the hit to exactly that compile — other
+# cylinder threads compiling at the same time never touch this thread's
+# count, and no process-global flag is toggled.
+# ---------------------------------------------------------------------------
+_tls = threading.local()
+_listener_registered = False
+
+
+def _on_jax_event(event, **_kw):
+    if event == "/jax/compilation_cache/cache_hits":
+        _tls.cache_hits = getattr(_tls, "cache_hits", 0) + 1
+
+
+def _thread_cache_hits() -> int:
+    global _listener_registered
+    if not _listener_registered:
+        with _lock:
+            if not _listener_registered:
+                import jax.monitoring
+
+                jax.monitoring.register_event_listener(_on_jax_event)
+                _listener_registered = True
+    return getattr(_tls, "cache_hits", 0)
 
 
 # ---------------------------------------------------------------------------
@@ -310,8 +349,12 @@ def serialize_safe(lowered) -> tuple[bool, set]:
 
 
 # ---------------------------------------------------------------------------
-# Disk format: pickle of {"v", "jax", "jaxlib", "platform", "payload"}
-# where payload is jax.experimental.serialize_executable.serialize(...).
+# Disk format: pickle of {"v", "jax", "jaxlib", "platform", "devices",
+# "payload"} where payload is
+# jax.experimental.serialize_executable.serialize(...) and devices the ids
+# the program was compiled for, in assignment order (the loader needs them:
+# without ``execution_devices`` a one-device program is loaded onto every
+# device of the backend and fails at its first call).
 # Writes are atomic (tempfile + os.replace) so a kill mid-write can never
 # leave a torn file; a torn/foreign file is just a cold cache.
 # ---------------------------------------------------------------------------
@@ -384,9 +427,11 @@ def _serialize_to_disk(key: str, kind: str, compiled):
     try:
         payload = _se.serialize(compiled)
         jv, jlv, plat = _versions()
+        devices = [int(d.id) for d in
+                   compiled.runtime_executable().local_devices()]
         blob = pickle.dumps({"v": _FORMAT_VERSION, "jax": jv,
                              "jaxlib": jlv, "platform": plat,
-                             "payload": payload})
+                             "devices": devices, "payload": payload})
         _atomic_write_bytes(_entry_path(key), blob)
     except Exception as e:
         # an unserializable program (or a read-only/full cache dir) must
@@ -428,7 +473,16 @@ def _deserialize_from_disk(key: str):
             # keys embed the toolchain, so this only triggers on files
             # renamed/copied into place — still just a miss
             return None
-        exe = _se.deserialize_and_load(*obj["payload"])
+        import jax
+
+        by_id = {int(d.id): d for d in jax.devices()}
+        if any(i not in by_id for i in obj["devices"]):
+            # compiled for a device this process does not have (a wider
+            # host wrote the entry): a miss, and the artifact stays
+            return None
+        exe = _se.deserialize_and_load(
+            *obj["payload"],
+            execution_devices=[by_id[i] for i in obj["devices"]])
     except Exception as e:
         # the ARTIFACT itself is bad (torn pickle, or this toolchain's
         # deterministic "Symbols not found" refusals): quarantine so no
@@ -529,7 +583,7 @@ class CachedProgram:
             exe = _loaded.get(key)
             if exe is not None:
                 return exe
-            _ensure_fallback_cache(cache_path())
+            arm_compile_cache()
             with _xla_work_lock, _trace.span("compile", "aot.load"):
                 exe = _deserialize_from_disk(key)
             if exe is not None:
@@ -544,11 +598,17 @@ class CachedProgram:
                         _trace.span("compile", "aot.compile") as _sp:
                     lowered = self._jitted.lower(*args, **kwargs)
                     safe, offending = serialize_safe(lowered)
+                    hits0 = _thread_cache_hits()
                     exe = lowered.compile()
+                    from_jax_cache = _thread_cache_hits() > hits0
                     if _trace.enabled():
                         _sp.add(key=key, kind=self.kind)
                 _HIST_COMPILE_S.add(time.perf_counter() - t0)
-                if safe:
+                if safe and from_jax_cache and _versions()[2] == "cpu":
+                    # see _thread_cache_hits: the artifact would load and
+                    # then fail at execute; the jax cache already holds it
+                    _CTR_FROM_JAX_CACHE.inc(1)
+                elif safe:
                     _serialize_to_disk(key, self.kind, exe)
                 else:
                     # by-pointer custom calls (see SAFE_CUSTOM_CALLS):

@@ -27,15 +27,15 @@ the bf16 peak divided by the pass count.  Report ``peak_note`` alongside
 from __future__ import annotations
 
 # bf16 MXU peak per chip, matched by substring against device_kind (first
-# hit wins; order matters for e.g. "v5" vs "v5p").  Sources: public TPU
-# spec sheets.  Unknown kinds fall back to the env override or None.
+# hit wins; order matters for e.g. "v5 lite" vs "v5p").  Sources: public
+# TPU spec sheets.  Unknown kinds (and CPU) have no peak: None.
 _TPU_PEAKS_BF16 = (
     ("v6e", 918e12),
     ("v6", 918e12),
     ("v5p", 459e12),
     ("v5e", 197e12),
     ("v5litepod", 197e12),
-    ("v5", 197e12),
+    ("v5 lite", 197e12),
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
@@ -51,10 +51,10 @@ PRECISION_PASSES = {"highest": 6, "high": 3, "default": 1}
 # (6x/2x): the per-scenario dense Pallas kernel runs its contractions in
 # exact f32 VPU math regardless of mode ("high" gains nothing there;
 # "default" gains only the bf16-storage bandwidth saving), while the XLA
-# MXU regimes gain the pass ratio.  Underestimating the speedup is
-# watchdog-safe (dispatches sized smaller than they could be);
-# overestimating would let a fused program outlive the worker's ~60 s
-# execution kill.  Revisit with measured sweep times per mode.
+# MXU regimes gain the pass ratio.  Underestimating the speedup is the
+# safe side (dispatches sized smaller than they could be); overestimating
+# would let a fused program outrun its dispatch budget.  Revisit with
+# measured sweep times per mode.
 SWEEP_SPEEDUP = {"highest": 1.0, "high": 1.0, "default": 1.25}
 
 
@@ -62,12 +62,6 @@ def sweep_speedup(mode) -> float:
     """Dispatch-model throughput factor for a sweep at precision ``mode``
     (None = "highest" = 1.0)."""
     return SWEEP_SPEEDUP.get(mode or "highest", 1.0)
-
-# Nominal CPU peak used when nothing better is known (one modern core's
-# order-of-magnitude f64 FMA throughput).  CPU MFU numbers exist so the
-# smoke bench exercises the full reporting path, not as a claim about the
-# host — the artifact carries peak_note for honesty.
-CPU_NOMINAL_PEAK = 5e10
 
 
 def sweep_flops(S, n, m, sparse_factor=1.0):
@@ -165,21 +159,15 @@ def ph_iteration_flops(S, n, m, sweeps, refresh_every=16, restarts=1,
 def device_peak_flops(device=None, matmul_precision="highest"):
     """(peak_flops_per_device, note) for MFU accounting.
 
-    ``TPUSPPY_PEAK_FLOPS`` (flops/s per device, already precision-adjusted)
-    overrides everything — the escape hatch for unknown hardware.  Returns
-    (None, reason) when no peak is known.
+    (None, reason) when no peak is known: a CPU run has no device
+    utilization to report, and an unknown ``device_kind`` is not guessed.
     """
-    import os
-
-    env = os.environ.get("TPUSPPY_PEAK_FLOPS")
-    if env:
-        return float(env), "TPUSPPY_PEAK_FLOPS override"
     if device is None:
         import jax
         device = jax.devices()[0]
     platform = getattr(device, "platform", "cpu")
     if platform == "cpu":
-        return CPU_NOMINAL_PEAK, "cpu nominal (order-of-magnitude)"
+        return None, "cpu: no device peak"
     kind = (getattr(device, "device_kind", "") or "").lower()
     passes = PRECISION_PASSES.get(matmul_precision, 1)
     for key, bf16 in _TPU_PEAKS_BF16:

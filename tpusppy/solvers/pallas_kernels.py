@@ -21,13 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # VMEM budget for one scenario block's matrices (bytes).  v5e has ~16 MB of
 # scoped VMEM per core, and the measured end-to-end footprint is ~5x the
@@ -200,7 +195,7 @@ def usable(S, m, n, platform=None, P=None, precision="highest") -> int | None:
 
     ``precision="default"`` widens the applicable range: bf16 matrix
     storage halves the per-scenario VMEM, so larger (m, n) still fit."""
-    if not HAVE_PALLAS or P is not None:
+    if P is not None:
         return None
     platform = platform or jax.default_backend()
     if platform != "tpu":
@@ -565,15 +560,13 @@ def fused_sweeps_sparse(q, rowcols, rowvals, colrows, colvals, Kinv, diagK,
 
 def sparse_kernel_possible(platform=None) -> bool:
     """Could :func:`fused_sweeps_sparse` EVER engage in this process:
-    Pallas importable + TPU backend + the experimental
+    TPU backend + the experimental
     ``TPUSPPY_PALLAS_SPARSE=1`` opt-in.  The ONE engagement gate —
     ``SparseA.from_dense``'s ``ell="auto"`` asks it before paying for the
     ELL twin build, and :func:`usable_sparse` layers the per-shape VMEM
     budget on top."""
     import os
 
-    if not HAVE_PALLAS:
-        return False
     platform = platform or jax.default_backend()
     return (platform == "tpu"
             and os.environ.get("TPUSPPY_PALLAS_SPARSE") == "1")
@@ -613,8 +606,6 @@ def usable_shared(S, m, n, platform=None, itemsize=4) -> int | None:
     of 8 for f32).  Reference-scale UC (n=16008) exceeds the matrix
     budget by orders of magnitude and correctly declines — the kernel is
     the small/medium-n shared-family fast path."""
-    if not HAVE_PALLAS:
-        return None
     platform = platform or jax.default_backend()
     if platform != "tpu":
         return None
