@@ -534,3 +534,202 @@ def test_wheel_trace_has_cylinder_tracks_and_final_gap(tmp_path,
     # perfetto artifact exists and is loadable
     doc = json.loads(open(dump["path"]).read())
     assert doc["traceEvents"]
+
+
+# ---------------------------------------------------------------------------
+# phases: one call site, three sinks (profiler trace, registry, ring)
+# ---------------------------------------------------------------------------
+
+def test_phase_feeds_the_registry_with_the_ring_off():
+    assert not trace.enabled()
+    trace.set_thread_track("spoke1:LagrangianOuterBound")
+    try:
+        with metrics.window() as win:
+            for _ in range(3):
+                with trace.phase("pass", k=1) as ph:
+                    time.sleep(0.001)
+                    ph.add(late=True)       # payload is the ring's: a no-op
+    finally:
+        trace.set_thread_track(None)
+    # the cylinder is the track up to its first ':'
+    assert win.delta("phase.spoke1.pass.count") == 3
+    assert 0.003 <= win.delta("phase.spoke1.pass.secs") < 0.5
+    assert trace.events() == []
+    assert trace.cylinder() == "main"
+
+
+def test_phase_records_a_ring_span_with_payload_when_on():
+    trace.enable()
+    trace.set_thread_track("hub")
+    try:
+        with trace.phase("megastep", n_live=15) as ph:
+            with trace.phase("fetchlike"):
+                pass
+            ph.add(iters=15)
+    finally:
+        trace.set_thread_track(None)
+    evs = [e for e in trace.events() if e.kind == "span"]
+    assert [(e.track, e.name) for e in evs] == [("hub", "fetchlike"),
+                                                 ("hub", "megastep")]
+    assert evs[1].payload == {"n_live": 15, "iters": 15}
+    assert evs[0].payload is None
+    assert evs[1].t <= evs[0].t and evs[0].dur <= evs[1].dur
+    # ... and the registry all the same
+    assert metrics.value("phase.hub.megastep.count") == 1
+    assert metrics.value("phase.hub.megastep.secs") == pytest.approx(
+        evs[1].dur)
+
+
+def test_phase_open_across_disable_keeps_counting_but_drops_its_event():
+    trace.enable()
+    ph = trace.phase("stale")
+    ph.__enter__()
+    trace.disable()
+    trace.reset()
+    trace.enable()
+    ph.__exit__(None, None, None)
+    assert trace.events() == []
+    assert metrics.value("phase.main.stale.count") == 1
+
+
+def test_phase_off_path_cost_is_pinned():
+    """The ring off and no profiler session: a phase is an annotation that
+    checks one flag, two registry adds and two clock reads.  Best of five
+    batches, so that a loaded machine does not flake it; the pin catches a
+    path that rebuilds its names or rebinds jax at every call."""
+    assert not trace.enabled()
+    with trace.phase("warm"):           # binds jax.profiler, once
+        pass
+    assert trace._annotation_cls is not None
+    n, best = 20_000, float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with trace.phase("noop"):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 10e-6, f"phase() off path too slow: {best * 1e9:.0f}ns"
+    assert metrics.value("phase.main.noop.count") == 5 * n
+    assert trace.events() == []
+
+
+def _on_track(track, fn):
+    def run():
+        trace.set_thread_track(track)
+        fn()
+
+    return threading.Thread(target=run, name=track)
+
+
+def test_per_cylinder_host_sync_counters_sum_to_the_process_wide():
+    def fetches(k):
+        def go():
+            for i in range(k):
+                hostsync.fetch(np.arange(4.0), overlapped=(i % 3 == 0))
+        return go
+
+    with metrics.window() as win:
+        threads = [_on_track("hub", fetches(7)),
+                   _on_track("spoke1:LagrangianOuterBound", fetches(5))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        hostsync.fetch(np.zeros(2))                     # this thread: main
+    d = win.deltas()
+    assert (d["host_sync.count.hub"], d["host_sync.count.spoke1"],
+            d["host_sync.count.main"]) == (7, 5, 1)
+    by = [k for k in d if k.startswith("host_sync.count.")]
+    assert sum(d[k] for k in by) == d["host_sync.count"] == 13
+    # (the registry keeps other tests' cylinders, zeroed: they add nothing)
+    blocked = [k for k in d if k.startswith("host_sync.blocked_secs.")]
+    assert {k for k in blocked if d[k] > 0} == {
+        "host_sync.blocked_secs." + c for c in ("hub", "spoke1", "main")}
+    assert sum(d[k] for k in blocked) == pytest.approx(
+        d["host_sync.blocked_secs"], rel=1e-9)
+    # an overlapped fetch blocks nobody: counted, not billed
+    assert d["host_sync.overlapped"] == 3 + 2
+
+
+def test_phases_reach_the_profiler_trace_on_their_own_threads_lines(tmp_path):
+    """Under a ``jax.profiler`` session (CPU here) two named threads'
+    phases, and a fetch, stand as ``tpusppy:<cylinder>:<name>`` on two
+    different lines of the written XSpace."""
+    import glob
+
+    import jax
+
+    both = threading.Barrier(2, timeout=60)
+
+    def work(name):
+        def go():
+            both.wait()       # alive together: the OS gives a finished
+            for _ in range(3):                  # thread's id, and so its
+                with trace.phase(name):         # line, to the next one
+                    hostsync.fetch(np.zeros(2))
+            both.wait()
+        return go
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        threads = [_on_track("hub", work("megastep")),
+                   _on_track("spoke2:XhatShuffleInnerBound", work("pass"))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            names = {ev.name for ev in line.events
+                     if ev.name.startswith(trace.ANNOTATION_PREFIX)}
+            if names:
+                lines.append(names)
+    assert sorted(lines, key=sorted) == [
+        {"tpusppy:hub:megastep", "tpusppy:hub:fetch"},
+        {"tpusppy:spoke2:pass", "tpusppy:spoke2:fetch"}]
+
+
+def test_rescue_rows_counts_on_a_tiny_wheel_whose_rescue_fires():
+    """A hub-only wheel whose Iter0 sweeps are cut short: every row misses
+    ``straggler_tol`` and is re-solved on the host, which ``rescue.rows``
+    counts and the ``rescue`` phase times; the cap's leftovers are counted
+    apart."""
+    from tpusppy.cylinders import PHHub
+    from tpusppy.models import farmer
+    from tpusppy.opt.ph import PH
+    from tpusppy.spin_the_wheel import WheelSpinner
+
+    S = 5
+    options = {"defaultPHrho": 1.0, "PHIterLimit": 1, "convthresh": -1.0,
+               "straggler_lp_max": 3,
+               "solver_options": {"max_iter": 5, "polish": False}}
+    hub = {"hub_class": PHHub, "hub_kwargs": {"options": {}},
+           "opt_class": PH,
+           "opt_kwargs": {"options": options,
+                          "all_scenario_names":
+                              farmer.scenario_names_creator(S),
+                          "scenario_creator": farmer.scenario_creator,
+                          "scenario_creator_kwargs": {"num_scens": S}}}
+    with metrics.window() as win:
+        ws = WheelSpinner(hub, []).run()
+    d = win.deltas()
+    pri = np.asarray(ws.opt.pri_res)
+    assert d["phase.hub.rescue.count"] >= 1
+    assert d["rescue.rows"] >= 3                    # Iter0's, at the cap
+    assert d["rescue.left_at_batch"] >= S - 3
+    assert d["rescue.rows"] + d["rescue.left_at_batch"] >= S
+    assert np.isfinite(pri).all()
+    # the wheel's own phases, on the tracks the spinner gives its threads
+    for key in ("phase.main.build", "phase.hub.iter0", "phase.hub.refresh",
+                "phase.hub.xbar", "phase.hub.w_update",
+                "phase.main.teardown"):
+        assert d[key + ".count"] >= 1 and d[key + ".secs"] > 0, key
+    assert d["phase.hub.rescue.secs"] <= d["phase.hub.iter0.secs"]

@@ -421,7 +421,7 @@ class PHHub(Hub):
             )
 
     def sync(self):
-        with _trace.span("hub", "sync"):
+        with _trace.phase("sync"):
             if self.has_w_spokes:
                 self.send_ws()
             if self.has_nonant_spokes:
@@ -484,29 +484,30 @@ class PHHub(Hub):
         # every ``linger_nudge_secs`` keeps their warm-started refinement
         # going at a fraction of the old every-poll Put traffic
         nudge = float(self.options.get("linger_nudge_secs", 2.0))
-        t0 = time.time()
-        last_trace = 0.0
-        while time.time() - t0 < linger:
-            if self.supervisor is not None and self.supervisor.all_lost():
-                # nobody left to harvest from: idling out the linger
-                # budget would only delay the (already best-known) result
-                global_toc("Hub linger: all spokes lost — ending harvest",
-                           True)
-                break
-            self._nudge_epoch = int((time.time() - t0) / max(nudge, 0.25))
-            self.sync()
-            # quiet convergence check (is_converged prints a trace row per
-            # call — at poll frequency that floods the screen); trace at
-            # most every 5s
-            if time.time() - last_trace > 5.0:
-                last_trace = time.time()
-                if self.is_converged():
+        with _trace.phase("linger"):
+            t0 = time.time()
+            last_trace = 0.0
+            while time.time() - t0 < linger:
+                if self.supervisor is not None and self.supervisor.all_lost():
+                    # nobody left to harvest from: idling out the linger
+                    # budget would only delay the (already best-known) result
+                    global_toc("Hub linger: all spokes lost — ending harvest",
+                               True)
+                    break
+                self._nudge_epoch = int((time.time() - t0) / max(nudge, 0.25))
+                self.sync()
+                # quiet convergence check (is_converged prints a trace row per
+                # call — at poll frequency that floods the screen); trace at
+                # most every 5s
+                if time.time() - last_trace > 5.0:
+                    last_trace = time.time()
+                    if self.is_converged():
+                        global_toc("Hub linger: gap certified", True)
+                        break
+                elif self.determine_termination():
                     global_toc("Hub linger: gap certified", True)
                     break
-            elif self.determine_termination():
-                global_toc("Hub linger: gap certified", True)
-                break
-            time.sleep(0.5)
+                time.sleep(0.5)
 
     def finalize(self):
         return self.opt.post_loops()

@@ -139,14 +139,16 @@ class LagrangianOuterBound(OuterBoundWSpoke):
         self.opt.W = np.zeros(
             (self.opt.batch.num_scenarios, self.opt.nonant_length)
         )
-        self.trivial_bound = self.lagrangian()
-        self.bound = self.trivial_bound
+        with self.bound_pass():
+            self.trivial_bound = self.lagrangian()
+            self.bound = self.trivial_bound
         self.dk_iter = 1
         while not self.got_kill_signal():
             if self.new_Ws:
-                bound = self._set_weights_and_solve()
-                if bound is not None and np.isfinite(bound):
-                    self.bound = bound
+                with self.bound_pass():
+                    bound = self._set_weights_and_solve()
+                    if bound is not None and np.isfinite(bound):
+                        self.bound = bound
                 self.dk_iter += 1
 
     def finalize(self):

@@ -17,6 +17,7 @@ import time
 
 import numpy as np
 
+from ..obs import trace as _trace
 from ..resilience import faults as _faults
 from ..resilience import supervisor as _supervisor
 from .spcommunicator import KILL_ID, SPCommunicator
@@ -40,6 +41,7 @@ class Spoke(SPCommunicator):
         # get-or-create costs a lock + dict probe per call)
         self._hb_gauge = _supervisor.heartbeat_gauge(
             f"spoke{self.strata_rank}")
+        self._waiting = None     # the open ``wait`` phase, while spinning
 
     # lengths negotiated by WheelSpinner before mailbox construction
     def buffer_lengths(self) -> tuple[int, int]:
@@ -72,8 +74,20 @@ class Spoke(SPCommunicator):
         if not self._new_locals:
             # nothing fresh: yield the core so the hub thread can progress
             # (the reference relies on MPI async progress for the same effect)
+            if self._waiting is None:
+                # ONE ``wait`` phase per stretch of spinning, not one per
+                # 2 ms poll: it closes when the hub posts something new
+                self._waiting = _trace.phase("wait").__enter__()
             time.sleep(0.002)
+        elif self._waiting is not None:
+            self._waiting.__exit__(None, None, None)
+            self._waiting = None
         return self.remote_write_id == KILL_ID
+
+    def bound_pass(self):
+        """The ``pass`` phase of this spoke: one bound pass, from the hub's
+        new W / nonants to the bound put."""
+        return _trace.phase("pass")
 
     def peek_kill_signal(self) -> bool:
         """Kill check that does NOT consume payload freshness — safe to call

@@ -135,7 +135,8 @@ class XhatShuffleInnerBound(InnerBoundNonantSpoke):
         while not self.got_kill_signal():
             if self.new_nonants:
                 self._seen = True
-                self._try_candidates()
+                with self.bound_pass():
+                    self._try_candidates()
 
     def finalize(self):
         """One final candidate pass with the last hub nonants (the

@@ -16,6 +16,13 @@ cylinder threads); fixed subsystem timelines ("host-sync", "dispatch",
 recorded per event so the Perfetto exporter can keep concurrent spans on
 one logical track from interleaving their begin/end pairs.
 
+Coarse phases of a cylinder (a dozen per hub iteration at most) go
+through :func:`phase` instead of :func:`span`: one call site feeds the
+profiler's trace (``jax.profiler.TraceAnnotation``, so the phase lands on
+the clock of the device's operations), the always-on registry
+(``phase.<cylinder>.<name>.{secs,count}``) and, when enabled, this ring.
+This is the only file of the program that names ``jax.profiler``.
+
 Enablement: ``TPUSPPY_TRACE=<path>`` in the environment turns tracing on
 at import and registers an atexit flush of ``<path>`` (Perfetto JSON)
 plus ``<path>.report.json`` (the :mod:`.report` summary); programmatic
@@ -32,6 +39,8 @@ import os
 import threading
 import time
 from typing import NamedTuple
+
+from . import metrics as _metrics
 
 #: Default ring capacity (events).  At the wheel's event rates (~10-100
 #: events/iteration) this keeps minutes of history; the ring drops the
@@ -110,6 +119,12 @@ def set_thread_track(name: str | None):
 
 def thread_track() -> str:
     return getattr(_tls, "track", None) or "main"
+
+
+def cylinder() -> str:
+    """The calling thread's cylinder: its track up to the first ``:``
+    (``hub``, ``spoke1``, ``spoke2``; ``main`` outside a wheel)."""
+    return thread_track().split(":", 1)[0]
 
 
 def enable(path: str | None = None, capacity: int | None = None):
@@ -219,6 +234,97 @@ def span(track: str | None, name: str, **payload):
     if not _enabled:
         return _NULL
     return _Span(track, name, payload or None)
+
+
+# ---------------------------------------------------------------------------
+# Phases: one call site, three sinks (profiler trace, registry, ring).
+# ---------------------------------------------------------------------------
+#: Prefix of every annotation the program writes into a profiler trace:
+#: ``tpusppy:<cylinder>:<name>``.
+ANNOTATION_PREFIX = "tpusppy:"
+
+
+_annotation_cls = None       # bound at first use: obs imports without jax
+
+
+def _bind_annotation():
+    global _annotation_cls
+    try:
+        from jax.profiler import TraceAnnotation as cls
+    except ImportError:
+        cls = contextlib.nullcontext     # takes, and ignores, the name
+    _annotation_cls = cls
+    return cls
+
+
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation("tpusppy:<cylinder>:<name>")`` for
+    the calling thread: a flag check while no profiler session runs, a
+    span on the thread's own line of the profiler's trace otherwise."""
+    cls = _annotation_cls or _bind_annotation()
+    return cls(f"{ANNOTATION_PREFIX}{cylinder()}:{name}")
+
+
+_phase_sites: dict = {}      # (track, name) -> (annotation name, secs, count)
+
+
+def _phase_site(track, name):
+    # unlocked: two threads racing here write the same triple (the
+    # registry hands both the one counter object)
+    site = _phase_sites.get((track, name))
+    if site is None:
+        cyl = track.split(":", 1)[0]
+        key = f"phase.{cyl}.{name}"
+        site = _phase_sites[(track, name)] = (
+            f"{ANNOTATION_PREFIX}{cyl}:{name}",
+            _metrics.counter(key + ".secs"), _metrics.counter(key + ".count"))
+    return site
+
+
+class _Phase:
+    __slots__ = ("name", "payload", "track", "site", "ann", "t0", "gen")
+
+    def __init__(self, name, payload):
+        self.name = name
+        self.payload = payload
+
+    def __enter__(self):
+        self.track = track = getattr(_tls, "track", None) or "main"
+        self.site = site = (_phase_sites.get((track, self.name))
+                            or _phase_site(track, self.name))
+        self.ann = (_annotation_cls or _bind_annotation())(site[0])
+        self.gen = _gen
+        self.ann.__enter__()
+        self.t0 = _perf()
+        return self
+
+    def add(self, **kw):
+        """Attach payload discovered mid-phase (ring only)."""
+        if _enabled:
+            if self.payload is None:
+                self.payload = {}
+            self.payload.update(kw)
+
+    def __exit__(self, *exc):
+        dur = _perf() - self.t0
+        self.ann.__exit__(*exc)
+        _, secs, count = self.site
+        secs.inc(dur)
+        count.inc(1)
+        if _enabled and self.gen == _gen:
+            _buffer.add(Event(self.t0, threading.get_ident(), self.track,
+                              self.name, "span", dur, self.payload or None))
+        return False
+
+
+def phase(name: str, **payload):
+    """Context manager for one coarse phase of the calling cylinder.
+    Always: a profiler annotation ``tpusppy:<cylinder>:<name>`` and the
+    registry counters ``phase.<cylinder>.<name>.{secs,count}``; with the
+    ring enabled also a span event on the thread's track, ``payload`` and
+    ``.add()`` as for :func:`span`.  Not for fine-grained sites: those
+    keep :func:`span` and its free disabled path."""
+    return _Phase(name, payload)
 
 
 def record_span(track: str | None, name: str, t0: float, dur: float,

@@ -108,16 +108,18 @@ class PHBase(SPOpt):
 
     def Compute_Xbar(self, verbose=False):
         """Per-node weighted averages of nonants (phbase.py:27-107)."""
-        xk = self._nonants_cached()                              # (S, K)
-        self.xbars, self.xsqbars = self._node_avgs(xk)
+        with _trace.phase("xbar"):
+            xk = self._nonants_cached()                          # (S, K)
+            self.xbars, self.xsqbars = self._node_avgs(xk)
         if verbose:
             global_toc(f"xbar[:8]={self.xbars[0][:8]}")
 
     def Update_W(self, verbose=False):
         """Dual update W += rho (x - xbar) (phbase.py:293-318)."""
-        xk = self._nonants_cached()
-        self.W = self.W + self.rho * (xk - self.xbars)
-        self._bump_state_version()
+        with _trace.phase("w_update"):
+            xk = self._nonants_cached()
+            self.W = self.W + self.rho * (xk - self.xbars)
+            self._bump_state_version()
         if verbose:
             global_toc(f"W[0][:8]={self.W[0][:8]}")
 
@@ -150,19 +152,35 @@ class PHBase(SPOpt):
         return q2
 
     def solve_ph_subproblems(self):
-        self.extobject.pre_solve_loop()
-        q, q2 = self._augmented_q()
-        self.solve_loop(q=q, q2=q2)
-        self.extobject.post_solve_loop()
+        with _trace.phase("solve"):
+            self.extobject.pre_solve_loop()
+            q, q2 = self._augmented_q()
+            self.solve_loop(q=q, q2=q2)
+            self.extobject.post_solve_loop()
 
     # ---- drivers ------------------------------------------------------------
     def Iter0(self) -> float:
         """Initial solves with W=prox off; returns the trivial bound
         (phbase.py:758-872)."""
+        # one phase from the plain solve to the first hub sync: the
+        # refresh, rescue, xbar, w_update and sync phases nest inside
+        with _trace.phase("iter0"):
+            self._iter0()
+        # serving SLO seam (doc/serving.md): the solve server records
+        # time-to-iter-1 per request here — the warm-path acceptance
+        # metric (a warm family reaches this point without compiling)
+        cb = self.options.get("on_iter0_done")
+        if cb is not None:
+            try:
+                cb()
+            except Exception:   # a telemetry hook must never cost the run
+                pass
+        return self.trivial_bound
+
+    def _iter0(self):
         self.extobject.pre_iter0()
         self._iter = 0
-        with _trace.span(None, "iter0"):
-            self.solve_loop()  # plain objective
+        self.solve_loop()  # plain objective
         feas = self.feas_prob()
         if feas < 1.0 - 1e-6:
             # residuals above feas_tol conflate two states: a truly
@@ -230,16 +248,6 @@ class PHBase(SPOpt):
             f"Iter0 trivial bound {self.trivial_bound:.4f} conv {self.conv:.3e}",
             self.options.get("display_progress", False),
         )
-        # serving SLO seam (doc/serving.md): the solve server records
-        # time-to-iter-1 per request here — the warm-path acceptance
-        # metric (a warm family reaches this point without compiling)
-        cb = self.options.get("on_iter0_done")
-        if cb is not None:
-            try:
-                cb()
-            except Exception:   # a telemetry hook must never cost the run
-                pass
-        return self.trivial_bound
 
     def _apply_resume(self):
         """Re-seat checkpointed PH state, when a resume was requested.
@@ -1198,8 +1206,6 @@ class PHBase(SPOpt):
         _, self.xsqbars = self._node_avgs(self._nonants_cached())
         self._bump_state_version()
         _metrics.inc("phstate.boundary_fetches")
-        if _trace.enabled():
-            _trace.instant(None, "phstate_boundary_fetch", iter=self._iter)
 
     def _spcomm_needs_host_state(self) -> bool:
         """Whether the imminent ``spcomm.sync()`` will read host PH state:
@@ -1278,7 +1284,7 @@ class PHBase(SPOpt):
         # one span per PH iteration on the cylinder's own track
         # (the wheel spinner names cylinder threads; solo runs land
         # on "main") — the hub/spoke timeline rows of the trace
-        with _trace.span(None, "ph_iter") as _sp:
+        with _trace.phase("ph_iter") as _sp:
             self.extobject.miditer()
             self.solve_ph_subproblems()
             self.Compute_Xbar()

@@ -490,9 +490,11 @@ class SolveServer:
                 t.done.set()
                 continue
             try:
-                creator, names, kwargs, opt_options = self._resolve(t.req)
-                canon = _canonical.ingest(names, creator, kwargs,
-                                          options=opt_options)
+                with _trace.phase("ingest"):
+                    creator, names, kwargs, opt_options = \
+                        self._resolve(t.req)
+                    canon = _canonical.ingest(names, creator, kwargs,
+                                              options=opt_options)
                 t.req.creator_kwargs = kwargs
                 t.canonical, t.opt_options = canon, opt_options
                 t.creator, t.names = creator, names
@@ -746,9 +748,12 @@ class SolveServer:
                     f"request {req.request_id!r} rejected")
         if _faults.active():               # deterministic slow-ingest
             _faults.on_ingest()            # injection (stall_ingest)
-        creator, names, kwargs, opt_options = self._resolve(req)
-        canon = _canonical.ingest(names, creator, kwargs,
-                                  options=opt_options)
+        # phase ``ingest`` on the submitting thread: the scenario creator's
+        # calls and the canonical batch, before the request is queued
+        with _trace.phase("ingest"):
+            creator, names, kwargs, opt_options = self._resolve(req)
+            canon = _canonical.ingest(names, creator, kwargs,
+                                      options=opt_options)
         t = _Tenant(req, canon, opt_options, creator, names, self.work_dir)
         t.req.creator_kwargs = kwargs
         with self._cv:
@@ -1394,8 +1399,11 @@ class SolveServer:
 
             if _aot.enabled():
                 _aot.prewarm()
-        hub_dict, spokes = self._build_wheel(
-            t, lambda: self._want_preempt(t, slice_start), on_iter0_done)
+        # phase ``slice_build`` on the executor's thread: the hub and spoke
+        # dicts, up to the WheelSpinner's own ``build``
+        with _trace.phase("slice_build"):
+            hub_dict, spokes = self._build_wheel(
+                t, lambda: self._want_preempt(t, slice_start), on_iter0_done)
         _CTR_SLICES.inc(1)
         # the executor is the ONLY thread doing device work, so registry
         # window deltas here are this slice's traffic (the wheel's own

@@ -44,6 +44,18 @@ _CTR_COUNT = _metrics.counter("host_sync.count")
 _CTR_OVERLAPPED = _metrics.counter("host_sync.overlapped")
 _CTR_BLOCKED = _metrics.counter("host_sync.blocked_secs")
 _CTR_FETCH = _metrics.counter("host_sync.fetch_secs")
+# the same two by cylinder (``host_sync.count.hub``, ...): the process-wide
+# counters sum three cylinder threads, these say whose fetches blocked
+_BY_CYLINDER: dict = {}
+
+
+def _cylinder_counters(cyl):
+    pair = _BY_CYLINDER.get(cyl)
+    if pair is None:
+        pair = _BY_CYLINDER[cyl] = (
+            _metrics.counter("host_sync.count." + cyl),
+            _metrics.counter("host_sync.blocked_secs." + cyl))
+    return pair
 
 
 def _stack():
@@ -109,20 +121,26 @@ def fetch(x, overlapped: bool = False):
     transfer-guard contract; numpy/scalar inputs pass through unchanged
     (scripted test stand-ins take this path)."""
     t0 = time.perf_counter()
-    try:
-        import jax
-        out = jax.device_get(x)
-    except ImportError:                  # pure-host callers (unit tests)
-        out = np.asarray(x)
+    # on the profiler's clock too: the fetch as the calling cylinder's
+    # ``tpusppy:<cylinder>:fetch`` beside the device's operations
+    with _trace.annotation("fetch"):
+        try:
+            import jax
+            out = jax.device_get(x)
+        except ImportError:              # pure-host callers (unit tests)
+            out = np.asarray(x)
     dt = time.perf_counter() - t0
     for tr in _stack():
         tr.add(dt, overlapped)
+    count, blocked = _cylinder_counters(_trace.cylinder())
     _CTR_COUNT.inc(1)
+    count.inc(1)
     _CTR_FETCH.inc(dt)
     if overlapped:
         _CTR_OVERLAPPED.inc(1)
     else:
         _CTR_BLOCKED.inc(dt)
+        blocked.inc(dt)
     if _trace.enabled():
         # retroactive span: the fetch wall-time on the "host-sync" track
         _trace.record_span("host-sync", "fetch", t0, dt,

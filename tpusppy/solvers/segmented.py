@@ -273,9 +273,6 @@ def bill_megastep(S, n, m, n_iters, sweeps, sparse_factor=1.0,
                                          sparse_factor)
     if fl:
         _metrics.inc("dispatch.flops", fl)
-    if _trace.enabled():
-        _trace.instant("dispatch", "megastep", S=S, n=n, m=m,
-                       iters=int(n_iters), sweeps=float(sweeps))
     return fl
 
 
@@ -296,9 +293,6 @@ def bill_bound_pass(S, n, m, sweeps, sparse_factor=1.0,
                                       n_evals=n_evals)
     if fl:
         _metrics.inc("dispatch.flops", fl)
-    if _trace.enabled():
-        _trace.instant("dispatch", "bound_pass", S=S, n=n, m=m,
-                       sweeps=float(sweeps))
     return fl
 
 

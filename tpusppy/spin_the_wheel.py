@@ -178,9 +178,37 @@ class WheelSpinner:
         return opt_kwargs
 
     def run(self):
+        t_build0 = time.monotonic()
+        # phase ``build``: construction, up to the spoke threads' start
+        with _trace.phase("build"):
+            hub_comm, hub_opt, spoke_comms, sup, ckpt_mgr = self._build(
+                t_build0)
+            threads, errors = self._start_spokes(spoke_comms, sup)
+        _trace.set_thread_track("hub")
+        try:
+            hub_comm.main()
+        except BaseException:
+            # the spokes must not outlive a hub that raised
+            _trace.set_thread_track(None)
+            hub_comm.send_terminate()
+            raise
+        _trace.set_thread_track(None)
+        # phase ``teardown``: hub main's return to run()'s return
+        # (terminate, joins, finalize), back on the caller's track
+        with _trace.phase("teardown"):
+            hub_comm.send_terminate()
+            # construction + hub loop: gap-based termination happened HERE;
+            # the spoke teardown below (final bound-tightening passes,
+            # lingering MILPs) can add minutes that are bookkeeping, not
+            # time-to-certified-gap — benchmarks report this figure
+            self.gap_wall_secs = time.monotonic() - t_build0
+            self._teardown(hub_comm, hub_opt, spoke_comms, sup, ckpt_mgr,
+                           threads, errors)
+        return self
+
+    def _build(self, t_build0):
         from .resilience import supervisor as _supervisor
 
-        t_build0 = time.monotonic()
         fabric = WindowFabric()
 
         # Hub opt + communicator (spin_the_wheel.py:92-116)
@@ -218,9 +246,12 @@ class WheelSpinner:
         global_toc(
             f"wheel constructed ({1 + len(spoke_comms)} cylinders) in "
             f"{time.monotonic() - t_build0:.1f}s", True)
+        return hub_comm, hub_opt, spoke_comms, sup, ckpt_mgr
 
-        # Run spokes on threads, hub on this thread (role dispatch analogue of
-        # spin_the_wheel.py:119-127)
+    @staticmethod
+    def _start_spokes(spoke_comms, sup):
+        # Run spokes on threads, hub on the caller's (role dispatch analogue
+        # of spin_the_wheel.py:119-127)
         threads = []
         errors = []
 
@@ -243,18 +274,10 @@ class WheelSpinner:
             t.start()
             threads.append(t)
             sup.note_thread(i + 1, t)
+        return threads, errors
 
-        _trace.set_thread_track("hub")
-        try:
-            hub_comm.main()
-        finally:
-            _trace.set_thread_track(None)
-            hub_comm.send_terminate()
-            # construction + hub loop: gap-based termination happened HERE;
-            # the spoke teardown below (final bound-tightening passes,
-            # lingering MILPs) can add minutes that are bookkeeping, not
-            # time-to-certified-gap — benchmarks report this figure
-            self.gap_wall_secs = time.monotonic() - t_build0
+    def _teardown(self, hub_comm, hub_opt, spoke_comms, sup, ckpt_mgr,
+                  threads, errors):
         deadline = time.monotonic() + 900.0   # shared across all joins
         for i, t in enumerate(threads):
             # lost spokes get a short grace, not the whole deadline: a
@@ -315,7 +338,6 @@ class WheelSpinner:
         # a traced wheel banks its artifact NOW (not at interpreter exit:
         # the driver may SIGKILL a lingering process)
         _trace.flush_if_enabled()
-        return self
 
     def _write_result_sidecar(self):
         """When TPUSPPY_RESULT_JSON names a path, bank {inner, outer,
@@ -457,6 +479,7 @@ def _spoke_worker(fabric_spec, spoke_dict, strata_rank):
         opt, strata_rank, fabric, **spoke_dict.get("spoke_kwargs", {}))
     with open(_ready_path(tag, strata_rank), "w") as f:
         f.write("ready")
+    _trace.set_thread_track(f"spoke{strata_rank}:{comm.__class__.__name__}")
     try:
         comm.main()
     finally:

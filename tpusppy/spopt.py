@@ -468,11 +468,10 @@ class SPOpt(SPBase):
                                    ref_worst=slot.get("ref_worst"))
                 st_full = dataclasses.replace(self.admm_settings,
                                               sweep_precision="highest")
-                with _trace.span(None, "solve.frozen_full_precision"):
-                    cand, _ = segmented.solve_frozen_segmented(
-                        frozen_fn, args, slot["factors"], st_full,
-                        warm=slot["warm"], want_converged=False)
-                    meas_c = self._fetch_measure(cand)
+                cand, _ = segmented.solve_frozen_segmented(
+                    frozen_fn, args, slot["factors"], st_full,
+                    warm=slot["warm"], want_converged=False)
+                meas_c = self._fetch_measure(cand)
             # accept when the sweep budget sufficed (converged to eps) OR
             # every scenario already sits inside the rescue-tolerance
             # ladder: an adaptive re-solve of a plateaued batch (UC prox
@@ -497,7 +496,7 @@ class SPOpt(SPBase):
             if st_adpt.sweep_precision not in (None, "highest"):
                 st_adpt = dataclasses.replace(st_adpt,
                                               sweep_precision="highest")
-            with _trace.span(None, "solve.refresh"):
+            with _trace.phase("refresh"):
                 sol, factors, _ = segmented.solve_factored_segmented(
                     frozen_fn, factored_fn, args, st_adpt,
                     warm=slot.get("warm") if warm else None, shared=shared,
@@ -640,9 +639,18 @@ class SPOpt(SPBase):
         bad = np.flatnonzero(~(pri <= tol_s) | ~(dua <= tol_s))
         if bad.size == 0:
             return sol, meas
+        with _trace.phase("rescue", rows=int(bad.size)):
+            return self._rescue_rows(
+                sol, q, q2, lb, ub, self.batch if batch is None else batch,
+                meas, bad, is_qp)
+
+    def _rescue_rows(self, sol, q, q2, lb, ub, b, meas, bad, is_qp):
+        """The host re-solves of :meth:`_rescue_stragglers` for the rows
+        ``bad``; bills ``rescue.rows`` (re-solved host-exact) and
+        ``rescue.left_at_batch`` (rows a cap left at batch accuracy)."""
         from .solvers import scipy_backend
 
-        b = self.batch if batch is None else batch
+        pri, dua = meas["pri"], meas["dua"]
         q = np.asarray(q, dtype=float)
         q2 = np.asarray(q2, dtype=float)
         lb = np.asarray(lb, dtype=float)
@@ -654,7 +662,7 @@ class SPOpt(SPBase):
         pri = pri.copy()
         dua = dua.copy()
         done = np.array(hostsync.fetch(sol.done), copy=True)
-        n_resc = 0
+        n_resc = n_left = 0
         qp_bad = bad[is_qp[bad]]
         if qp_bad.size:
             # QP scenarios: batched host IPM over the straggler slice
@@ -678,6 +686,7 @@ class SPOpt(SPBase):
                         f"scenario(s) left at batch accuracy (n="
                         f"{b.num_vars} > straggler_qp_max_n={max_n})",
                         True)
+                n_left += int(qp_bad.size)
                 qp_bad = np.empty(0, dtype=int)
             chunk = max(1, int(self.options.get("straggler_qp_chunk", 16)))
             for lo in range(0, qp_bad.size, chunk):
@@ -705,6 +714,7 @@ class SPOpt(SPBase):
             # solve; rescue the worst offenders, leave the rest at batch
             # accuracy (bounds stay certified via weak duality regardless)
             worst = np.argsort(-np.maximum(pri[lp_bad], dua[lp_bad]))
+            n_left += int(lp_bad.size) - max_lp
             lp_bad = lp_bad[worst[:max_lp]]
         # shared-A families: ONE csr conversion per rescue round (the
         # (m, n) dense scan per scenario was the hot cost at WECC scale) —
@@ -732,6 +742,8 @@ class SPOpt(SPBase):
             dua[s] = 0.0
             done[s] = True
             n_resc += 1
+        _metrics.inc("rescue.rows", n_resc)
+        _metrics.inc("rescue.left_at_batch", n_left)
         if n_resc:
             global_toc(
                 f"straggler rescue: {n_resc}/{b.num_scenarios} scenarios "
@@ -945,7 +957,7 @@ class SPOpt(SPBase):
         # the PH prox objective, so every scenario is QP
         _, tol_qp = self._straggler_tols()
         bounds = bound_live is not None
-        with _trace.span(None, "solve.megastep") as _sp:
+        with _trace.phase("megastep") as _sp:
             fn = self._megastep_fn(n_req, pack, bounds=bounds)
             if bounds:
                 state, packed = fn(
@@ -969,7 +981,7 @@ class SPOpt(SPBase):
                 bounds=bounds,
                 int_sweep=bounds and self._inwheel_int_sweep_on())
             if _trace.enabled():
-                _sp.add(n_live=n_live, executed=meas["executed"],
+                _sp.add(n_live=n_live, iters=meas["executed"],
                         refresh_hit=meas["refresh_hit"],
                         bound_pass=bool(meas.get("bound_computed")))
         executed = meas["executed"]
@@ -1136,7 +1148,7 @@ class SPOpt(SPBase):
         _, tol_qp = self._straggler_tols()
         shapes = [(idx.size, sub.num_vars) for idx, sub in b.buckets]
         bounds = bound_live is not None
-        with _trace.span(None, "solve.megastep") as _sp:
+        with _trace.phase("megastep") as _sp:
             fnb = self._bucketed_megastep_fn(n_req, bounds=bounds)
             if bounds:
                 states, packed = fnb(
@@ -1156,7 +1168,7 @@ class SPOpt(SPBase):
                 hostsync.fetch(packed), n_req, shapes, K, bounds=bounds,
                 int_sweep=bounds and self._inwheel_int_sweep_on())
             if _trace.enabled():
-                _sp.add(n_live=n_live, executed=bmeas["executed"],
+                _sp.add(n_live=n_live, iters=bmeas["executed"],
                         refresh_hit=bmeas["refresh_hit"], buckets=len(arrs))
         executed = bmeas["executed"]
         # scatter the per-bucket blocks into the global layout so the
