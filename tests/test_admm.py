@@ -230,3 +230,126 @@ class TestBlockedExplicitInverse:
         sol = solve_single(c, np.zeros(11), A, cl, cu, lb, ub, st)
         obj = float(c @ np.asarray(sol.x))
         assert abs(obj - ref.obj) <= 1e-5 * max(1.0, abs(ref.obj))
+
+
+@pytest.fixture
+def lanes_interpreted(monkeypatch):
+    """Answer ``usable_solve`` as the TPU would and run ``lanes_solve``
+    through the Pallas interpreter: the tests steer the program's choice,
+    the program has no option for it."""
+    import functools
+
+    from tpusppy.solvers import pallas_kernels as pk
+
+    usable, solve = pk.usable_solve, pk.lanes_solve
+    monkeypatch.setattr(
+        pk, "usable_solve",
+        lambda S, N, R, platform=None, **kw: usable(S, N, R, "tpu", **kw))
+    monkeypatch.setattr(pk, "lanes_solve",
+                        functools.partial(solve, interpret=True))
+
+
+F32 = ADMMSettings(dtype="float32", eps_abs=1e-5, eps_rel=1e-5)
+
+
+class TestPolishOnLanesSolve:
+    """``_polish`` in float32 with its saddle systems on the batched
+    elimination kernel (interpreted) against the ``jnp.linalg.solve`` path,
+    farmer S=128: same scenarios accepted, same vertex."""
+
+    S = 128
+
+    def batch(self):
+        return ScenarioBatch.from_problems(
+            [farmer.scenario_creator(nm, num_scens=self.S)
+             for nm in farmer.scenario_names_creator(self.S)])
+
+    @staticmethod
+    def both_paths(fn, request):
+        """``fn()`` traced on the XLA path, then with the kernel forced."""
+        import jax
+
+        xla = jax.jit(fn)()
+        request.getfixturevalue("lanes_interpreted")
+        return xla, jax.jit(lambda: fn())()
+
+    @staticmethod
+    def close(a, b, scale, rel):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.all(np.abs(a - b) <= rel * np.maximum(1.0, scale))
+
+    def test_adaptive_solve_accepts_the_same_scenarios(self, request):
+        """The program's own flow: the float32 ADMM iterate, then the
+        polish, accepted per scenario only where it beats the iterate."""
+        from tpusppy.solvers import admm
+
+        b = self.batch()
+        args = (b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub)
+        xla, lanes = self.both_paths(
+            lambda: admm._solve_impl(*args, F32, None), request)
+        np.testing.assert_array_equal(np.asarray(xla.raw[0]),
+                                      np.asarray(lanes.raw[0]))
+        took = lambda s: np.any(np.asarray(s.x) != np.asarray(s.raw[0]),
+                                axis=1)
+        assert 0 < took(xla).sum() < self.S
+        np.testing.assert_array_equal(took(xla), took(lanes))
+        self.close(xla.pri_res, lanes.pri_res,
+                   np.abs(np.asarray(xla.z)).max(axis=1), 1e-5)
+        self.close(xla.dua_res, lanes.dua_res,
+                   np.abs(np.asarray(b.c)).max(axis=1), 1e-5)
+        obj = lambda s: b.objective(np.asarray(s.x, np.float64))
+        self.close(obj(xla), obj(lanes), np.abs(obj(xla)), 1e-6)
+
+    def test_polish_from_an_accurate_iterate(self, request):
+        """Every scenario's polish accepted (the incoming residuals are
+        infinite): all 128 vertices agree between the two paths."""
+        import jax.numpy as jnp
+
+        from tpusppy.solvers import admm
+
+        b = self.batch()
+        args = (b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub)
+        s64 = solve_batch(*args, ADMMSettings())
+        dt = jnp.float32
+        c, q2, A, cl, cu, lb, ub, masks, _ = admm._prep(*args, F32, None)
+        D, E = admm._ruiz(A, q2, F32.scaling_iters)
+        cost = 1.0 / jnp.maximum(jnp.max(jnp.abs(c * D), axis=1), 1e-8)
+        qs, q2s, As, cls, cus, lbs, ubs, _, (x, z, y, yx) = admm._scale(
+            c, q2, A, cl, cu, lb, ub, D, E, cost, None,
+            (s64.x, s64.z, s64.y, s64.yx), dt)
+        inf = jnp.full((self.S,), jnp.inf, dt)
+        one = jnp.ones((self.S,), dt)
+        state = admm._IterState(
+            x, z, jnp.clip(x, lbs, ubs), y, yx, inf, inf, one, one,
+            jnp.zeros((), jnp.int32), jnp.asarray(jnp.inf, dt),
+            jnp.zeros((), jnp.int32))
+        xla, lanes = self.both_paths(
+            lambda: admm._polish(state, qs, q2s, As, cls, cus, lbs, ubs,
+                                 masks, F32), request)
+        assert np.all(np.isfinite(xla.pri)) and np.all(np.isfinite(lanes.pri))
+        self.close(xla.pri, lanes.pri, np.abs(np.asarray(xla.z)).max(axis=1),
+                   1e-5)
+        self.close(xla.dua, lanes.dua, np.abs(np.asarray(qs)).max(axis=1),
+                   1e-5)
+        obj = lambda s: b.objective(np.asarray(s.x * D, np.float64))
+        self.close(obj(xla), obj(lanes), np.abs(obj(xla)), 1e-6)
+
+    @pytest.mark.parametrize("use_pallas, counted", [("auto", 2), (True, 2),
+                                                     (False, 0)])
+    def test_refresh_counter(self, lanes_interpreted, use_pallas, counted):
+        """``refresh.lanes_linalg`` counts once per refresh when the
+        selector engages, never under ``use_pallas=False``."""
+        from tpusppy.obs import metrics
+        from tpusppy.opt.ph import PH
+
+        ph = PH({"defaultPHrho": 1.0, "PHIterLimit": 1,
+                 "solver_refresh_every": 1,
+                 "solver_options": dict(
+                     dtype="float32", eps_abs=1e-5, eps_rel=1e-5,
+                     use_pallas=use_pallas, megastep=1)},
+                farmer.scenario_names_creator(self.S), farmer.scenario_creator,
+                scenario_creator_kwargs={"num_scens": self.S})
+        ph.solve_loop()
+        ph.solve_loop()
+        assert metrics.value("phase.main.refresh.count") == 2
+        assert metrics.value("refresh.lanes_linalg") == counted

@@ -6,8 +6,8 @@ device and compiles it: what Mosaic or XLA:TPU would refuse on the chip
 (unaligned blocks, scoped-VMEM overflow, i64 block indices) is refused
 here, at no chip time.  Shapes are the ones ``chip_smoke.py`` runs: the
 ``serve`` phase's farmer (S=1000, crops_multiplier=4) for the per-scenario
-sweep kernel and the wheel megastep, and the served ``uc_lite`` family for
-the shared-A kernel.
+sweep kernel, the polish's elimination kernel and the wheel megastep, and
+the served ``uc_lite`` family for the shared-A kernel.
 
 Rules this file keeps (the driver runs the suite under ``-n 6``): nothing
 chip-related happens at import or collection; the topology is described
@@ -103,6 +103,20 @@ def test_fused_sweeps_compiles_at_the_served_farmer_shape(one_chip, chip32):
     compiled = pk.fused_sweeps.lower(
         *args, n_sweeps=max(1, st.check_every), n_refine=st.solve_refine,
         sigma=float(st.sigma), alpha=float(st.alpha), bs=bs).compile()
+    assert _has_mosaic_kernel(compiled)
+
+
+def test_lanes_solve_compiles_at_the_served_farmer_polish_shape(one_chip,
+                                                                chip32):
+    """The polish's (n+m) saddle systems of farmer S=1000 x4, one
+    right-hand side: the block the selector picks fits Mosaic's VMEM."""
+    b = _farmer_batch(2)
+    S, N = FARMER_S, b.num_vars + b.num_rows
+    bs = pk.usable_solve(S, N, 1, platform="tpu")
+    assert bs == 128
+    compiled = pk.lanes_solve.lower(
+        _spec((N, N, S), one_chip), _spec((N, 1, S), one_chip),
+        bs=bs).compile()
     assert _has_mosaic_kernel(compiled)
 
 

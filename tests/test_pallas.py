@@ -428,3 +428,158 @@ def test_sparse_ell_roundtrip_and_scaling():
             dense2[cr[j, jj], j] += cv[j, jj]
     np.testing.assert_allclose(dense2, As, rtol=1e-12, atol=1e-14)
     assert sp.astype(jnp.float32).ell.rowvals.dtype == jnp.float32
+
+
+# --------------------------------------------------------------------------
+# lanes_solve: batched elimination, scenario on the lanes
+# --------------------------------------------------------------------------
+
+def _lanes(M, rhs, bs=128):
+    """lanes_solve through the interpreter on (S, N, N), (S, N, R) float32
+    arrays in the batch-first layout of ``jnp.linalg.solve``."""
+    import jax.numpy as jnp
+
+    x = pallas_kernels.lanes_solve(
+        jnp.transpose(jnp.asarray(M), (1, 2, 0)),
+        jnp.transpose(jnp.asarray(rhs), (1, 2, 0)), bs=bs, interpret=True)
+    return np.transpose(np.asarray(x), (2, 0, 1))
+
+
+def _rel_err(x, ref):
+    """Per-scenario relative error against the float64 solution."""
+    S = x.shape[0]
+    return (np.linalg.norm((x - ref).reshape(S, -1), axis=1)
+            / np.linalg.norm(ref.reshape(S, -1), axis=1))
+
+
+def _assert_as_good_as_xla(M, rhs):
+    """The kernel does the LU's arithmetic in another order of additions:
+    both are held to the float64 solution, the kernel's error to a small
+    multiple of ``jnp.linalg.solve``'s own in float32."""
+    import jax.numpy as jnp
+
+    assert M.dtype == np.float32 and rhs.dtype == np.float32
+    got = _lanes(M, rhs)
+    assert got.dtype == np.float32 and got.shape == rhs.shape
+    xla = np.asarray(jnp.linalg.solve(jnp.asarray(M), jnp.asarray(rhs)))
+    ref = np.linalg.solve(M.astype(np.float64), rhs.astype(np.float64))
+    e_got, e_xla = _rel_err(got, ref), _rel_err(xla, ref)
+    assert np.all(np.isfinite(got))
+    assert np.median(e_got) <= 2.0 * np.median(e_xla) + 1e-7
+    assert e_got.max() <= 8.0 * e_xla.max() + 1e-6
+    # and to each other, at the scale of the worse of the two
+    assert np.all(_rel_err(got, xla.astype(np.float64))
+                  <= 8.0 * np.maximum(e_got, e_xla) + 1e-6)
+
+
+@pytest.mark.parametrize("S", [128, 1000])      # 1000: a ragged last block
+@pytest.mark.parametrize("R", ["one", "N"])
+@pytest.mark.parametrize("N", [12, 44, 72, 100])
+def test_lanes_solve_matches_linalg_solve_on_random_systems(N, R, S):
+    rng = np.random.RandomState(1000 * N + S)
+    M = (rng.randn(S, N, N) / np.sqrt(N)
+         + 2.0 * np.eye(N)).astype(np.float32)   # well conditioned
+    if R == "one":
+        rhs = rng.randn(S, N, 1).astype(np.float32)
+    else:   # an inverse: the identity on the right
+        rhs = np.broadcast_to(np.eye(N, dtype=np.float32), (S, N, N)).copy()
+    _assert_as_good_as_xla(M, rhs)
+
+
+def _farmer_saddle(S, crops_multiplier):
+    """The polish's saddle systems (``admm._polish.kkt_solve_full``: the
+    stationarity row of a bound-active column replaced by ``x_j = bound``,
+    an inactive row by the identity row ``nu_i = 0``) at the active sets of
+    a farmer batch's float64 LP solutions, in float32."""
+    from tpusppy.ir import ScenarioBatch
+    from tpusppy.models import farmer
+    from tpusppy.solvers.admm import ADMMSettings, solve_batch
+
+    base = min(S, 128)
+    b = ScenarioBatch.from_problems(
+        [farmer.scenario_creator(nm, num_scens=base,
+                                 crops_multiplier=crops_multiplier)
+         for nm in farmer.scenario_names_creator(base)])
+    sol = solve_batch(b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub,
+                      ADMMSettings())
+    x, A = np.asarray(sol.x), np.asarray(b.A)
+    n, m = b.num_vars, b.num_rows
+    Ax = np.einsum("smn,sn->sm", A, x)
+    tol = 1e-6 * (1.0 + np.abs(Ax))
+    row_act = (np.abs(Ax - b.cl) < tol) | (np.abs(Ax - b.cu) < tol)
+    at_ub = np.abs(x - b.ub) < 1e-6 * (1.0 + np.abs(x))
+    var_act = (np.abs(x - b.lb) < 1e-6 * (1.0 + np.abs(x))) | at_ub
+    assert 0 < row_act.mean() < 1 and 0 < var_act.mean() < 1
+    pd = 1e-6
+    va, ra = var_act[:, :, None], row_act[:, :, None]
+    eye_n, eye_m = np.eye(n)[None], np.eye(m)[None]
+    M = np.zeros((base, n + m, n + m))
+    M[:, :n, :n] = np.where(va, eye_n, pd * eye_n)
+    M[:, :n, n:] = np.where(va, 0.0, np.swapaxes(A, 1, 2))
+    M[:, n:, :n] = np.where(ra, A, 0.0)
+    M[:, n:, n:] = np.where(ra, -pd * eye_m, eye_m)
+    rhs = np.concatenate([
+        np.where(var_act, np.where(at_ub, b.ub, b.lb), -b.c),
+        np.where(row_act, np.where(np.abs(Ax - b.cu) < tol, b.cu, b.cl),
+                 0.0)], axis=1)
+    # Ruiz-like row/column scaling, as the program's systems are scaled
+    r = 1.0 / np.sqrt(np.abs(M).max(axis=2, keepdims=True))
+    c = 1.0 / np.sqrt(np.abs(M * r).max(axis=1, keepdims=True))
+    M, rhs = M * r * c, rhs * r[:, :, 0]
+    reps = -(-S // base)
+    M = np.tile(M, (reps, 1, 1))[:S]
+    rhs = np.tile(rhs, (reps, 1))[:S]
+    return M.astype(np.float32), rhs[..., None].astype(np.float32)
+
+
+@pytest.mark.parametrize("S", [128, 1000])
+@pytest.mark.parametrize("crops_multiplier", [1, 4])   # n+m = 18, 72
+def test_lanes_solve_on_the_polish_saddle_systems(crops_multiplier, S):
+    M, rhs = _farmer_saddle(S, crops_multiplier)
+    assert M.shape[1] == 18 * crops_multiplier
+    _assert_as_good_as_xla(M, rhs)
+
+
+def test_lanes_solve_singular_system_is_nonfinite_on_its_lane_only():
+    rng = np.random.RandomState(3)
+    S, N = 128, 12
+    M = (rng.randn(S, N, N) / np.sqrt(N) + 2.0 * np.eye(N)).astype(
+        np.float32)
+    rhs = rng.randn(S, N, 1).astype(np.float32)
+    bad = 37
+    M[bad, :, 5] = 0.0          # a zero column: no pivot to find
+    got = _lanes(M, rhs)
+    assert not np.all(np.isfinite(got[bad]))
+    ok = np.arange(S) != bad
+    assert np.all(np.isfinite(got[ok]))
+    ref = np.linalg.solve(M[ok].astype(np.float64),
+                          rhs[ok].astype(np.float64))
+    assert _rel_err(got[ok], ref).max() < 1e-4
+
+
+def test_lanes_solve_pivots_where_no_pivoting_would_fail():
+    """A zero on the diagonal with a usable entry below it: partial
+    pivoting solves what elimination in place would divide by zero."""
+    S, N = 128, 12
+    rng = np.random.RandomState(4)
+    M = (rng.randn(S, N, N) / np.sqrt(N) + 2.0 * np.eye(N)).astype(
+        np.float32)
+    M[::2, 0, 0] = 0.0          # every other lane needs a row swap at once
+    rhs = rng.randn(S, N, 1).astype(np.float32)
+    _assert_as_good_as_xla(M, rhs)
+
+
+@pytest.mark.parametrize("case, expect", [
+    (dict(S=1000, N=72, R=1, platform="tpu"), 128),
+    (dict(S=128, N=72, R=1, platform="tpu"), 128),
+    (dict(S=1000, N=44, R=44, platform="tpu"), 128),
+    (dict(S=1000, N=72, R=1, platform="cpu"), None),     # off the TPU
+    (dict(S=1000, N=72, R=1), None),                     # the tests' backend
+    (dict(S=127, N=72, R=1, platform="tpu"), None),      # lanes not filled
+    (dict(S=1000, N=72, R=1, platform="tpu", dtype="float64"), None),
+    (dict(S=1000, N=100, R=1, platform="tpu"), None),    # past the budget
+    (dict(S=1000, N=72, R=72, platform="tpu"), None),    # past the budget
+], ids=lambda v: "-".join(f"{k}{x}" for k, x in v.items())
+   if isinstance(v, dict) else None)
+def test_usable_solve_gating(case, expect):
+    assert pallas_kernels.usable_solve(**case) == expect

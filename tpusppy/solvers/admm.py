@@ -252,6 +252,27 @@ def _ruiz(A, q2, iters):
     return D, E
 
 
+def _lanes_bs(st: "ADMMSettings", S, N, dt):
+    """Block size when ``pallas_kernels.lanes_solve`` takes a batch of S
+    (N, N) systems with one right-hand side each, else None (the XLA
+    path).  ``use_pallas=False`` turns the kernel off as it turns the sweep
+    kernel off; "auto" and True follow ``usable_solve`` (TPU, float32, a
+    batch of 128 or more, the VMEM budget)."""
+    if st.use_pallas is False:
+        return None
+    from . import pallas_kernels
+    return pallas_kernels.usable_solve(S, N, 1, dtype=dt)
+
+
+def lanes_linalg(st: "ADMMSettings", S, m, n) -> bool:
+    """Whether an adaptive (refresh) solve of an (S, m, n) dense batch
+    runs its polish on ``lanes_solve``: the host's twin of the choice
+    ``_polish`` makes while tracing (spopt counts ``refresh.lanes_linalg``
+    by it)."""
+    return bool(st.polish
+                and _lanes_bs(st, S, n + m, st.jdtype()) is not None)
+
+
 def _factor(q2, A, rho_a, rho_x, sigma, P=None):
     """Cholesky of K = P + diag(q2) + sigma I + A' diag(rho_a) A + diag(rho_x).
 
@@ -311,6 +332,12 @@ def _explicit_inverse(K):
         # Embed K into a 128x128 identity-extended SPD and slice back.
         # TPU-only (trace-time check): other backends' lowerings are fine
         # and would just pay ~3x the flops for the padding.
+        # Every dense batch still reaches this lowering: the refresh
+        # solve's four inverses are 4-8% of its program (PERF.md section
+        # 5), so only the polish's LUs moved to pallas_kernels.lanes_solve.
+        # That kernel against the identity would take batches of 128 or
+        # more up to n = 45 (its VMEM budget): 64 < n < 128 pads here
+        # either way.
         if 64 < n < 128 and jax.default_backend() == "tpu":
             pad = 128 - n
             eye_pad = jnp.eye(128, dtype=K.dtype)[n:, :]
@@ -759,6 +786,29 @@ def _polish(state: _IterState, q, q2, A, cl, cu, lb, ub, masks,
     # coordinates by the recovery step below.
     delta = jnp.asarray(max(st.polish_delta, 1e-7), dt)
     AL_ITERS = 4
+    lanes_bs = _lanes_bs(st, S, n + m, dt)
+
+    def saddle_solve_lanes(var_act, var_b, row_act, row_b, Qblock, pd):
+        """``kkt_solve_full``'s system, assembled scenario-last and solved
+        by the batched elimination kernel: XLA's LU walks the n+m columns
+        one at a time over the (S, n+m, n+m) batch, nine times a polish;
+        the kernel keeps 128 scenarios' systems in VMEM throughout."""
+        from . import pallas_kernels
+        va = var_act.T[:, None, :]
+        ra = row_act.T[:, None, :]
+        M = jnp.concatenate([
+            jnp.concatenate([
+                jnp.where(va, eye_n[0][:, :, None],
+                          jnp.transpose(Qblock, (1, 2, 0))),
+                jnp.where(va, 0.0, jnp.transpose(A, (2, 1, 0)))], axis=1),
+            jnp.concatenate([
+                jnp.where(ra, jnp.transpose(A, (1, 2, 0)), 0.0),
+                jnp.where(ra, -pd, 1.0) * jnp.eye(m, dtype=dt)[:, :, None]],
+                axis=1)], axis=0)
+        rhs = jnp.concatenate([jnp.where(var_act, var_b, -q),
+                               jnp.where(row_act, row_b, 0.0)], axis=1)
+        return pallas_kernels.lanes_solve(
+            M, rhs.T[:, None, :], bs=lanes_bs)[:, 0, :].T
 
     def kkt_solve_full(act_lo, act_up, v_lo, v_up):
         """Row-replacement saddle LU at (n+m) — float32's accurate option.
@@ -785,17 +835,22 @@ def _polish(state: _IterState, q, q2, A, cl, cu, lb, ub, masks,
         Qblock = jax.vmap(jnp.diag)(q2) + pd * eye_n
         if P is not None:
             Qblock = Qblock + P
-        va = var_act[:, :, None]
-        ra = row_act[:, :, None]
-        M = jnp.zeros((S, N, N), dt)
-        rhs = jnp.zeros((S, N), dt)
-        M = M.at[:, :n, :n].set(jnp.where(va, eye_n, Qblock))
-        M = M.at[:, :n, n:].set(jnp.where(va, 0.0, jnp.swapaxes(A, 1, 2)))
-        rhs = rhs.at[:, :n].set(jnp.where(var_act, var_b, -q))
-        M = M.at[:, n:, :n].set(jnp.where(ra, A, 0.0))
-        M = M.at[:, n:, n:].set(jnp.where(ra, -pd * eye_m, eye_m))
-        rhs = rhs.at[:, n:].set(jnp.where(row_act, row_b, 0.0))
-        sol = jnp.linalg.solve(M, rhs[..., None])[..., 0]
+        if lanes_bs is not None:
+            sol = saddle_solve_lanes(var_act, var_b, row_act, row_b, Qblock,
+                                     pd)
+        else:
+            va = var_act[:, :, None]
+            ra = row_act[:, :, None]
+            M = jnp.zeros((S, N, N), dt)
+            rhs = jnp.zeros((S, N), dt)
+            M = M.at[:, :n, :n].set(jnp.where(va, eye_n, Qblock))
+            M = M.at[:, :n, n:].set(
+                jnp.where(va, 0.0, jnp.swapaxes(A, 1, 2)))
+            rhs = rhs.at[:, :n].set(jnp.where(var_act, var_b, -q))
+            M = M.at[:, n:, :n].set(jnp.where(ra, A, 0.0))
+            M = M.at[:, n:, n:].set(jnp.where(ra, -pd * eye_m, eye_m))
+            rhs = rhs.at[:, n:].set(jnp.where(row_act, row_b, 0.0))
+            sol = jnp.linalg.solve(M, rhs[..., None])[..., 0]
         xp, yp = sol[:, :n], sol[:, n:]
         # bound duals absorb the stationarity residual at active columns
         Pxp = (q2 * xp if P is None

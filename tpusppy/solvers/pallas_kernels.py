@@ -210,6 +210,130 @@ def usable(S, m, n, platform=None, P=None, precision="highest") -> int | None:
 
 
 # --------------------------------------------------------------------------
+# Batched dense elimination, scenario on the lanes
+# --------------------------------------------------------------------------
+#
+# XLA:TPU expands LuDecomposition / Cholesky / TriangularSolve on a batch of
+# small matrices into one dependent step per column over arrays laid out
+# batch-outermost: the refresh solve's nine (n+m)-sized polish LUs cost far
+# more than their few 1e8 flops (PERF.md section 5).  This kernel does the
+# same arithmetic in the layout of ``fused_sweeps``: a block of
+# scenarios' systems stays in VMEM from the first pivot to the last
+# back-substitution, every step one full-width VPU pass with the scenario on
+# the lanes, a grid over blocks.
+
+
+def _lanes_solve_kernel(M_ref, rhs_ref, x_ref, W_ref, *, N):
+    """Gaussian elimination with partial pivoting on the augmented block
+    [M | rhs], then back-substitution.  ``M_ref`` (N, N, Sb): row on the
+    leading (untiled) axis, column on the sublanes, scenario on the lanes;
+    ``rhs_ref``/``x_ref`` (N, R, Sb); ``W_ref`` the working copy of M.
+
+    The pivot of column k is the entry of largest magnitude at or below
+    the diagonal, the first such on ties (the rule of
+    ``jnp.linalg.solve``'s LU).  It differs per lane, so one scan down the
+    rows keeps the running best row by selects, and a second scan
+    eliminates, handing the displaced row k to the lane's pivot row as it
+    passes: no gather and no per-scenario control flow.  Columns left of
+    ``k``'s sublane tile are never read again and are not updated.  A zero
+    pivot divides by zero: non-finite values on that lane only."""
+    W_ref[...] = M_ref[...]
+    x_ref[...] = rhs_ref[...]
+
+    def column(i, k):
+        return W_ref[i, pl.ds(k, 1), :]
+
+    # the pivot columns one sublane tile at a time, so that the slab a row
+    # operation touches is a static slice; within a tile the column is a
+    # loop index (an unrolled step per column multiplies the time to trace
+    # and lower the kernel by N)
+    for c0 in range(0, N - 1, 8):
+
+        def step(k, _, c0=c0):
+            row_k = W_ref[k, c0:, :]
+            rhs_k = x_ref[k]
+            a_kk = column(k, k)
+
+            def search(i, carry):
+                best, piv, idx, prow, prhs = carry
+                a = column(i, k)
+                better = jnp.abs(a) > best
+                return (jnp.where(better, jnp.abs(a), best),
+                        jnp.where(better, a, piv),
+                        jnp.where(better, i, idx),
+                        jnp.where(better, W_ref[i, c0:, :], prow),
+                        jnp.where(better, x_ref[i], prhs))
+
+            _, piv, idx, prow, prhs = jax.lax.fori_loop(
+                k + 1, N, search,
+                (jnp.abs(a_kk), a_kk, jnp.full(a_kk.shape, k, jnp.int32),
+                 row_k, rhs_k))
+
+            def eliminate(i, _):
+                took = idx == i      # this lane's pivot came from row i
+                f = jnp.where(took, a_kk, column(i, k)) / piv
+                W_ref[i, c0:, :] = (jnp.where(took, row_k, W_ref[i, c0:, :])
+                                    - f * prow)
+                x_ref[i] = jnp.where(took, rhs_k, x_ref[i]) - f * prhs
+                return 0
+
+            jax.lax.fori_loop(k + 1, N, eliminate, 0)
+            W_ref[k, c0:, :] = prow
+            x_ref[k] = prhs
+            return 0
+
+        jax.lax.fori_loop(c0, min(c0 + 8, N - 1), step, 0)
+
+    def back(t, _):
+        j = N - 1 - t
+        xj = x_ref[j] / column(j, j)
+        x_ref[j] = xj
+
+        def substitute(i, _):
+            x_ref[i] = x_ref[i] - column(i, j) * xj
+            return 0
+
+        jax.lax.fori_loop(0, j, substitute, 0)
+        return 0
+
+    jax.lax.fori_loop(0, N, back, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("bs", "interpret"))
+def lanes_solve(M, rhs, *, bs, interpret=False):
+    """Solve ``M[:, :, s] x = rhs[:, :, s]`` for every scenario ``s``.
+
+    ``M`` (N, N, S) and ``rhs`` (N, R, S), scenario last: R = 1 for one
+    right-hand side, R = N with the identity for an inverse.  Returns x
+    (N, R, S).  ``bs`` scenarios a grid step (:func:`usable_solve`); a
+    ragged last block computes on padding that is never written back."""
+    N, R, S = rhs.shape
+    spec = lambda d1: pl.BlockSpec((N, d1, bs), lambda i: (0, 0, i),
+                                   memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_lanes_solve_kernel, N=N),
+        grid=((S + bs - 1) // bs,),
+        in_specs=[spec(N), spec(R)],
+        out_specs=spec(R),
+        out_shape=jax.ShapeDtypeStruct((N, R, S), M.dtype),
+        scratch_shapes=[pltpu.VMEM((N, N, bs), M.dtype)],
+        interpret=interpret,
+    )(M, rhs)
+
+
+def usable_solve(S, N, R, platform=None, dtype=jnp.float32) -> int | None:
+    """Block size if :func:`lanes_solve` applies, else None: float32 on
+    the TPU, a batch that fills the 128 lanes, and 128 scenarios' systems
+    inside the VMEM budget (the kernel holds M three times: Mosaic's two
+    input buffers and the working copy)."""
+    platform = platform or jax.default_backend()
+    if platform != "tpu" or jnp.dtype(dtype) != jnp.float32 or S < 128:
+        return None
+    per_scen = (N * N + 2 * N * R) * 4
+    return 128 if 128 * per_scen <= _VMEM_BUDGET else None
+
+
+# --------------------------------------------------------------------------
 # Fused shared-A sweep kernel (the frozen shared-engine fast path)
 # --------------------------------------------------------------------------
 #

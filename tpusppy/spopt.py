@@ -505,6 +505,9 @@ class SPOpt(SPBase):
                 slot["sig"] = sig
                 slot["age"] = 1
                 meas = self._fetch_measure(sol)
+            if not shared and admm.lanes_linalg(st_adpt, *args[2].shape):
+                # this refresh's polish ran on pallas_kernels.lanes_solve
+                _metrics.inc("refresh.lanes_linalg")
             # full-precision residual floor of this family at this
             # operating point — the mixed-precision guard's reference
             slot["ref_worst"] = float(
