@@ -21,7 +21,7 @@ import threading
 
 import numpy as np
 
-from ..harness import core, observe, reference, tracered
+from ..harness import core, observe, tracered
 from .wheel import wheel_evidence
 
 
@@ -44,6 +44,9 @@ def make_server(watches, annotate):
             hub_dict, spokes = super()._build_wheel(t, check, on_iter0_done)
             hub_dict["opt_class"] = observe.probed(hub_dict["opt_class"],
                                                    watch)
+            for sd in spokes:
+                sd["spoke_class"] = observe.probed_spoke(sd["spoke_class"],
+                                                         watch)
             return hub_dict, spokes
 
     return ProbedServer()
@@ -129,6 +132,7 @@ def run(ctx):
 
     module = importlib.import_module("tpusppy.models." + conf["model"])
     names = module.scenario_names_creator(int(conf["num_scens"]))
+    reference = core.load_reference(conf, ctx["bench_dir"])
     requests, evidence, failed = [], [], 0
     for r in sorted(done, key=lambda r: r["t_submit"]):
         rec, ws = r["record"], watches.get(r["rid"], [])
@@ -150,9 +154,10 @@ def run(ctx):
         kwargs = module.kw_creator(**dict(r["kw"],
                                           num_scens=int(conf["num_scens"])))
         evidence.append(wheel_evidence(
-            reference.RefData(module, names, kwargs), w, w.opt,
-            rec["outer"], rec["inner"], ctx["seed"], record=rec,
-            iter_limit=iter_limit))
+            reference(module, names, kwargs), w, w.opt,
+            rec["outer"], rec["inner"],
+            observe.incumbent_of([c for sl in ws for c in sl.spokes]),
+            ctx["seed"], record=rec, iter_limit=iter_limit))
     if not requests:
         raise RuntimeError("no request completed inside the window")
     n = len(requests)

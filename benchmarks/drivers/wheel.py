@@ -18,7 +18,7 @@ import importlib
 
 import numpy as np
 
-from ..harness import core, observe, reference, tracered
+from ..harness import core, observe, tracered
 
 ITERATIONS_OUT_OF_REACH = 1_000_000
 
@@ -63,10 +63,10 @@ def build_wheel(conf, data_seed, watch):
     return hub_dict, spokes, module, names, kwargs
 
 
-def wheel_evidence(ref, watch, opt, outer, inner, seed, **more):
+def wheel_evidence(ref, watch, opt, outer, inner, incumbent, seed, **more):
     """What the wheel left behind, copied off the program's objects."""
     return dict(
-        ref=ref, watch=watch, seed=seed,
+        ref=ref, watch=watch, seed=seed, incumbent=incumbent,
         x=np.array(opt.local_x, dtype=float), W=np.array(opt.W, dtype=float),
         xbars=np.array(opt.xbars, dtype=float),
         rho=np.array(opt.rho, dtype=float),
@@ -139,10 +139,11 @@ def run(ctx):
     if iters <= 0:
         raise RuntimeError("no hub iteration completed inside the window")
 
-    ref = reference.RefData(module, names, kwargs)
+    ref = core.load_reference(conf, ctx["bench_dir"])(module, names, kwargs)
     evidence = [wheel_evidence(ref, watch, opt, hub.BestOuterBound,
-                               hub.BestInnerBound, ctx["seed"],
-                               first_iteration=rule.c0)]
+                               hub.BestInnerBound,
+                               observe.incumbent_of(ws.spoke_comms),
+                               ctx["seed"], first_iteration=rule.c0)]
     _abs_gap, rel_gap = hub.compute_gaps()
     return {
         "attempted": iters, "failed": 0,

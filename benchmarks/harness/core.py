@@ -9,20 +9,25 @@ per-layer metric is a file of its own, found here by the name that
   drivers/<kind>.py              ``run(ctx) -> dict``: one general driver
                                  per kind of traffic
   layer_metrics/<metric>.py      ``read(obs) -> float | None``
+  references/<name>.py           ``Reference(module, names, creator_kwargs)``:
+                                 the plain truth of a deployment, named by
+                                 the configuration's ``"reference"``
+  checks/<check>.py              ``value(ev, spec) -> float | None``: one
+                                 number that decides ``correct``
 """
 
 from __future__ import annotations
 
 import importlib
-import importlib.util
 import json
 import os
 import sys
 import time
 
+from . import byname
 from . import checks as _checks
 
-BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = byname.BENCH_DIR
 ROOT = os.path.dirname(BENCH_DIR)
 
 
@@ -62,15 +67,19 @@ def load_reader(metric, bench_dir=BENCH_DIR):
     """``layer_metrics/<metric>.py``, or, for a quantity that is split by
     the end-to-end metric it moves (``idle_pct.wheel``, ``idle_pct.serve``),
     the one reader named for what stands before the first dot."""
-    path = os.path.join(bench_dir, "layer_metrics", metric + ".py")
-    if not os.path.exists(path):
-        path = os.path.join(bench_dir, "layer_metrics",
-                            metric.split(".", 1)[0] + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.layer_metrics." + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return byname.load("layer_metrics", metric, bench_dir, "read", stem=True)
+
+
+DEFAULT_REFERENCE = "two_stage_lp"
+
+
+def load_reference(conf, bench_dir=BENCH_DIR):
+    """The class ``Reference`` of ``references/<name>.py``, where ``name`` is
+    the configuration's ``"reference"``; a configuration that names none
+    gets the continuous two-stage one."""
+    return byname.load("references",
+                       conf.get("reference", DEFAULT_REFERENCE), bench_dir,
+                       "Reference")
 
 
 def device_info(chips, bench_dir=BENCH_DIR):
@@ -166,7 +175,7 @@ def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
     ctx = {
         "cell": name, "config": conf, "workload": wl, "seed": int(seed),
         "data_seed": data_seed(seed), "seconds": float(seconds),
-        "trace": bool(trace), "t_start": t_start,
+        "trace": bool(trace), "t_start": t_start, "bench_dir": bench_dir,
     }
     obs = driver.run(ctx)
     obs["workload"] = wl
@@ -192,7 +201,7 @@ def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
                                   "unit": m["unit"]}
 
     # the reference runs last: the window has closed, the peak is read
-    correct, rows = _checks.decide(obs["evidence"], wl["checks"])
+    correct, rows = _checks.decide(obs["evidence"], wl["checks"], bench_dir)
     line = {"correct": bool(correct and obs["failed"] == 0),
             "attempted": int(obs["attempted"]), "failed": int(obs["failed"]),
             "metrics": metrics, "device": device}

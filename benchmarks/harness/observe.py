@@ -79,6 +79,8 @@ class HubWatch:
         # megastep window): (W, xbars) before it, (x, W, xbars) after
         self.steps = []
         self.step_iters = 0          # iterations of the newest hub step
+        self.spokes = []             # the wheel's spoke communicators, where
+        #                              :func:`probed_spoke` registered them
         self.on_boundary = None      # harness hook: (watch, t, it) -> bool
 
     # -- filled through the subclass seam ---------------------------------
@@ -165,6 +167,40 @@ def probed(ph_cls, watch):
 
     ProbedPH.__name__ = ph_cls.__name__
     return ProbedPH
+
+
+def probed_spoke(spoke_cls, watch):
+    """``spoke_cls`` whose instances are remembered in ``watch.spokes``: the
+    way to a spoke of a wheel that the server builds and drops itself (a
+    solo ``WheelSpinner`` keeps its own in ``spoke_comms``)."""
+
+    class ProbedSpoke(spoke_cls):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            watch.spokes.append(self)
+
+    ProbedSpoke.__name__ = spoke_cls.__name__
+    return ProbedSpoke
+
+
+def incumbent_of(spoke_comms):
+    """The (S, n) solution behind the best inner bound that a spoke of the
+    wheel kept (``InnerBoundNonantSpoke.best_snapshot``: the bound and its
+    ``best_solution_cache``, read as a pair), copied; None where no spoke
+    kept one.  Read after the wheel tore down."""
+    best, sol = None, None
+    for comm in spoke_comms:
+        if not hasattr(comm, "best_snapshot"):
+            continue
+        bound, cache = comm.best_snapshot()
+        if cache is None or not np.isfinite(bound):
+            continue
+        better = best is None or (
+            bound < best if getattr(comm, "is_minimizing", True)
+            else bound > best)
+        if better:
+            best, sol = bound, np.array(cache, dtype=float)
+    return sol
 
 
 def hub_device_ready(opt):

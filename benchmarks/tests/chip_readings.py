@@ -56,7 +56,8 @@ def one(core, checks, cell, seed, seconds, control, request_options,
     obs = driver.run({
         "cell": cell["name"], "config": conf, "workload": wl,
         "seed": seed, "data_seed": core.data_seed(seed),
-        "seconds": seconds, "trace": False, "t_start": t0})
+        "seconds": seconds, "trace": False, "t_start": t0,
+        "bench_dir": core.BENCH_DIR})
     t1 = time.monotonic()
     correct, rows = checks.decide(obs["evidence"], wl["checks"])
     per_wheel = []

@@ -6,11 +6,16 @@ import json
 import os
 import re
 
-from benchmarks.harness import core
+import pytest
+
+from benchmarks.harness import byname, checks, core
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# what every reference has (benchmarks/README.md, "The reference's interface")
+REFERENCE_METHODS = ("objective", "scenario_opt", "lin_min", "ef", "xbar_of",
+                     "w_after", "infeasibility", "prox_gap")
 
 
 def bench():
@@ -87,12 +92,34 @@ def test_every_cell_has_its_files_and_reports_enough():
         wl = cell["workload_file"]
         assert os.path.exists(os.path.join(
             core.BENCH_DIR, "drivers", wl["driver"] + ".py"))
-        from benchmarks.harness import checks
-
-        assert wl["checks"] and all(c["name"] in checks.CHECKS
-                                    for c in wl["checks"])
+        assert wl["checks"]
+        for c in wl["checks"]:
+            assert callable(checks.load_check(c["name"])), c["name"]
+        ref = core.load_reference(cell["config_file"])
+        assert all(callable(getattr(ref, m)) for m in REFERENCE_METHODS)
         for m in cell["per_layer"]:
             assert callable(core.load_reader(m["name"]))
+
+
+def test_a_configuration_without_the_key_gets_the_two_stage_lp():
+    ref = core.load_reference({})
+    assert ref.__module__ == "benchmarks.references.two_stage_lp"
+    named = core.load_reference({"reference": "two_stage_mip"})
+    assert named.__module__ == "benchmarks.references.two_stage_mip"
+    assert hasattr(named, "ef_int") and not hasattr(ref, "ef_int")
+    assert not os.path.exists(os.path.join(core.BENCH_DIR, "harness",
+                                           "reference.py"))
+    assert not hasattr(checks, "CHECKS")
+
+
+def test_a_name_with_no_file_says_which_files_there_are():
+    with pytest.raises(FileNotFoundError, match="prox_gap_rel"):
+        checks.load_check("no_such_check")
+    with pytest.raises(FileNotFoundError, match="two_stage_lp"):
+        core.load_reference({"reference": "no_such_reference"})
+    # only a per-layer metric shares a file by its stem
+    with pytest.raises(FileNotFoundError):
+        byname.load("checks", "prox_gap_rel.wheel", core.BENCH_DIR, "value")
 
 
 def test_a_split_metric_shares_the_reader_named_for_its_stem():

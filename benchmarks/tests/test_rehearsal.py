@@ -107,24 +107,25 @@ def test_traced_run_on_the_cpu_prints_no_device_metric(run):
     assert "busy_s" not in line["device"] and "breakdown" not in line
 
 
-def test_every_single_hub_step_of_the_window_is_compared(run):
+def test_every_single_hub_step_of_the_window_is_compared(run, monkeypatch):
     seen = []
-    real = checks.prox_gap_rel
+    load = checks.load_check
 
-    def counting(ev, p):
-        seen.append((len(checks._steps(ev)), len(ev["watch"].steps)))
-        return real(ev, p)
+    def counting(name, *a):
+        def value(ev, p):
+            if name == "prox_gap_rel":
+                seen.append((len(checks._steps(ev)), len(ev["watch"].steps)))
+            return load(name, *a)(ev, p)
 
-    checks.CHECKS["prox_gap_rel"] = counting
-    try:
-        line = run("farmer_cm4_s1000.wheel",
-                   config=dict(FARMER, solver_options=dict(F64, megastep=1)),
-                   workload={"warmup_iterations": 5, "checks": [
-                       # a tiny deployment's first steps leave a row stalled
-                       {"name": "prox_gap_rel", "limit": 0.5, "n_check": 4},
-                       TIGHT[1], TIGHT[3]]})
-    finally:
-        checks.CHECKS["prox_gap_rel"] = real
+        return value
+
+    monkeypatch.setattr(checks, "load_check", counting)
+    line = run("farmer_cm4_s1000.wheel",
+               config=dict(FARMER, solver_options=dict(F64, megastep=1)),
+               workload={"warmup_iterations": 5, "checks": [
+                   # a tiny deployment's first steps leave a row stalled
+                   {"name": "prox_gap_rel", "limit": 0.5, "n_check": 4},
+                   TIGHT[1], TIGHT[3]]})
     v = values(line)
     assert line["correct"], bad(line)
     assert v["w_update_rel"] is not None and v["prox_gap_rel"] is not None
@@ -219,22 +220,74 @@ def test_command_line_refuses_to_run_without_a_tpu():
     assert p.stdout.strip() == ""
 
 
+INCUMBENT = [{"name": "incumbent_infeas_rel", "limit": 1e-3},
+             {"name": "incumbent_frac", "limit": 1e-6},
+             {"name": "incumbent_nonant_spread", "limit": 1e-6},
+             {"name": "inner_vs_incumbent_rel", "limit": 1e-6}]
+
+
+@pytest.mark.parametrize("cell,over", [
+    ("farmer_cm4_s1000.wheel", {"warmup_iterations": 5}),
+    ("farmer_cm4_s1000.serve1", {"request_options": {"linger_secs": 0.0}})])
+def test_the_incumbent_is_read_from_the_spoke_that_kept_it(run, cell, over):
+    """A tiny float64 farmer wheel does get an inner bound: the evidence
+    holds the point it is the price of, solo and served, and the four
+    checks of the incumbent read it (the farmer has no integer column, so
+    under ``two_stage_mip`` its fractionality is 0, and under the default
+    reference, which reads no ``is_int``, there is nothing to compare)."""
+    skip = dict(INCUMBENT[1], absent="skip")
+    line = run(cell, seconds=1.0,
+               config=dict(FARMER, max_iterations=12),
+               workload=dict(over, checks=[INCUMBENT[0], skip] + INCUMBENT[2:]))
+    assert line["correct"], bad(line)
+    v = values(line)
+    assert v["incumbent_frac"] is None
+    assert all(v[c["name"]] is not None for c in INCUMBENT if c is not
+               INCUMBENT[1])
+    line = run(cell, seconds=1.0,
+               config=dict(FARMER, max_iterations=12,
+                           reference="two_stage_mip"),
+               workload=dict(over, checks=INCUMBENT))
+    assert line["correct"], bad(line)
+    assert values(line)["incumbent_frac"] == 0.0
+
+
 def test_a_new_cell_configuration_and_metric_are_files_only(tmp_path, run):
-    """A later PR adds a configuration, a cell and a per-layer metric as
-    new files and new entries of BENCHMARK.json: no file that is there
-    changes."""
+    """A later PR adds a configuration, a cell, a per-layer metric, a
+    reference and a check as new files and new entries of BENCHMARK.json:
+    no file that is there changes."""
     root = tmp_path
     shutil.copytree(os.path.join(ROOT, "benchmarks"), root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    before = {p: (root / "benchmarks" / p).read_bytes() for p in
-              ("run.py", "harness/core.py", "drivers/wheel.py")}
+
+    def files():
+        return {os.path.relpath(os.path.join(d, f), root): open(
+                    os.path.join(d, f), "rb").read()
+                for d, _dirs, fs in os.walk(root / "benchmarks") for f in fs
+                if "__pycache__" not in d}
+
+    before = files()
+    # a reference of its own: the default one with one more truth, which
+    # only the new check asks for
+    (root / "benchmarks/references/farmer_acres.py").write_text(
+        'from benchmarks.references.two_stage_lp import Reference as _LP\n\n\n'
+        'class Reference(_LP):\n'
+        '    def acres(self):\n'
+        '        return float(self.ub[0][self.nonant].max())\n')
+    (root / "benchmarks/checks/xbar_over_acres.py").write_text(
+        'from benchmarks.harness import checks as H\n\n\n'
+        'def value(ev, spec):\n'
+        '    if not H.ref_has(ev, "acres"):\n'
+        '        return None\n'
+        '    return float(ev["xbars"].sum(axis=1).max() / ev["ref"].acres())\n')
     conf = json.load(open(root / "benchmarks/configs/farmer_cm4_s1000.json"))
-    conf.update(FARMER, num_scens=12)
+    conf.update(FARMER, num_scens=12, reference="farmer_acres")
     (root / "benchmarks/configs/farmer_tiny.json").write_text(json.dumps(conf))
     wl = json.load(open(root / "benchmarks/workloads/farmer_cm4_s1000.wheel.json"))
     wl.update(warmup_iterations=3, trace_seconds=1,
-              checks=loose("farmer_cm4_s1000.wheel"))
+              checks=loose("farmer_cm4_s1000.wheel")
+              + [{"name": "xbar_over_acres", "limit": 1.0 + 1e-6}])
     (root / "benchmarks/workloads/farmer_tiny.wheel.json").write_text(
         json.dumps(wl))
     (root / "benchmarks/layer_metrics/hub_bound_updates.py").write_text(
@@ -259,5 +312,15 @@ def test_a_new_cell_configuration_and_metric_are_files_only(tmp_path, run):
     assert line["correct"], bad(line)
     assert "hub_bound_updates" in line["metrics"]
     assert "mega_iter_pct" in line["metrics"]
-    assert before == {p: (root / "benchmarks" / p).read_bytes()
-                      for p in before}
+    # the new check read the new reference's truth: the acres planted are
+    # the acres there are
+    assert 0.5 < values(line)["xbar_over_acres"] <= 1.0 + 1e-6
+    after = files()
+    assert {p: after[p] for p in before} == before
+    assert len(after) == len(before) + 5
+    # under a reference that lacks the method the new check has nothing to
+    # compare, and that is not correct
+    ok, rows = checks.decide(
+        [{"ref": core.load_reference({})}],
+        [{"name": "xbar_over_acres", "limit": 1}], str(root / "benchmarks"))
+    assert not ok and rows[0]["value"] is None
