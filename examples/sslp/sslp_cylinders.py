@@ -6,6 +6,22 @@ xhatshuffle / cross-scenario-cut spokes.  Example::
 
     python sslp_cylinders.py --num-scens 5 --max-iterations 30 \
         --default-rho 5.0 --rel-gap 0.01 --lagrangian --xhatshuffle
+
+The benchmark's deployment ``sslp_10_50_2000`` (SIPLIB's instance of that
+name as the reference's ``sslp_cylinders.py --instance-name sslp_10_50_2000
+--default-rho 1 --lagrangian --xhatshuffle`` runs it;
+``benchmarks/configs/sslp_10_50_2000.json``) is this wheel as::
+
+    python sslp_cylinders.py --num-scens 2000 --sslp-num-servers 10 \
+        --sslp-num-clients 50 --default-rho 1 --rel-gap 0.001 \
+        --no-adaptive-rho --lagrangian --xhatshuffle \
+        --solver-options "dtype=float32 eps_abs=1e-05 eps_rel=1e-05"
+
+with the creator's ``relax_integers=False`` (integer in both stages), which
+this command line has no flag for: ``benchmarks/drivers/wheel.py`` hands it
+to ``sslp.kw_creator``.  Every scenario's ``A`` is the same, so the batch
+runs the shared-A engine (``ScenarioBatch.from_problems`` finds it by
+value).
 """
 
 from tpusppy.models import sslp

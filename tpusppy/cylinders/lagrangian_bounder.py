@@ -55,7 +55,10 @@ class LagrangianOuterBound(OuterBoundWSpoke):
 
     def lagrangian(self) -> float:
         """Solve the W-augmented batch and return the dual bound
-        (lagrangian_bounder.py:19-56): E[obj + W·x_nonant].
+        (lagrangian_bounder.py:19-56): E[obj + W·x_nonant].  The
+        per-scenario certificates behind it stay in ``last_certificates``;
+        :meth:`_put` keeps them with their W when the bound is the best so
+        far (:meth:`OuterBoundWSpoke.best_certificates`).
 
         The objective comes from the opt object's own ``_augmented_q`` (with
         W on, prox off per ``lagrangian_prep``) so the assembly stays single-
@@ -69,6 +72,18 @@ class LagrangianOuterBound(OuterBoundWSpoke):
         gap a pure LP-relaxation bound cannot.  The lift is budget-elastic
         and valid at ANY completed subset of scenarios.
         """
+        certs = self._certificates()
+        self.last_certificates = certs
+        return float(self.opt.probs @ certs)
+
+    def _put(self, bound):
+        """Report ``bound`` to the hub, keeping the certificates of the
+        pass that made it if it is the best so far."""
+        self.keep_if_best(bound, self.last_certificates, self.opt.W)
+        self.bound = bound
+
+    def _certificates(self) -> np.ndarray:
+        """(S,) certified per-scenario bounds at the opt object's W."""
         opt = self.opt
         q, q2 = opt._augmented_q()
         donor_cfg = opt.options.get("lagrangian_dual_donors")
@@ -125,10 +140,10 @@ class LagrangianOuterBound(OuterBoundWSpoke):
                 kw = {k: v for k, v in lift_cfg.items() if k != "every"}
                 lifted, n = milp_lift(opt.batch, q, base, **kw)
                 self.last_milp_lift_count = n
-                return float(opt.probs @ lifted)
+                return lifted
         if base is not None:
-            return float(opt.probs @ base)
-        return opt.Edualbound(q=q, q2=q2)
+            return base
+        return opt.Edualbound_perscen(q=q, q2=q2)
 
     def _set_weights_and_solve(self) -> float:
         self.opt.W = np.asarray(self.localWs, dtype=float).copy()
@@ -141,14 +156,14 @@ class LagrangianOuterBound(OuterBoundWSpoke):
         )
         with self.bound_pass():
             self.trivial_bound = self.lagrangian()
-            self.bound = self.trivial_bound
+            self._put(self.trivial_bound)
         self.dk_iter = 1
         while not self.got_kill_signal():
             if self.new_Ws:
                 with self.bound_pass():
                     bound = self._set_weights_and_solve()
                     if bound is not None and np.isfinite(bound):
-                        self.bound = bound
+                        self._put(bound)
                 self.dk_iter += 1
 
     def finalize(self):
@@ -159,11 +174,13 @@ class LagrangianOuterBound(OuterBoundWSpoke):
         additionally polished by projected subgradient ascent on the INTEGER
         Lagrangian dual — every iterate is a certified bound, the best one
         is reported.  This is the reference Lagranger spoke's own-steps
-        posture (lagranger_bounder.py) with MIP subproblem minima.
+        posture (lagranger_bounder.py) with MIP subproblem minima.  (The
+        ascent's own bound keeps no certificates: ``best_certificates``
+        stands at the best of the passes.)
         """
         self.final_bound = self._set_weights_and_solve()
         if np.isfinite(self.final_bound):
-            self.bound = self.final_bound
+            self._put(self.final_bound)
         ascent_cfg = dict(self.opt.options.get("lagrangian_milp_ascent")
                           or {})
         # the hub ships its current (outer, inner) bounds in the W payload

@@ -189,11 +189,49 @@ class _BoundWSpoke(_BoundNonantLenSpoke):
 
 
 class OuterBoundWSpoke(_BoundWSpoke):
+    """Outer bound from the hub's W, kept with its evidence: beside the best
+    bound it reported, the per-scenario certificates ``d_s`` that sum to it
+    and the ``W`` they were computed at, so that a reader can hold each
+    ``d_s`` to the minimum of scenario ``s``'s own program at that ``W``
+    after the wheel tore down (:meth:`best_certificates`), as
+    :meth:`InnerBoundNonantSpoke.best_snapshot` lets one hold the inner
+    bound to its point."""
+
     converger_spoke_types = (
         ConvergerSpokeType.OUTER_BOUND,
         ConvergerSpokeType.W_GETTER,
     )
     converger_spoke_char = 'O'
+
+    def __init__(self, spbase_object, strata_rank, fabric, options=None):
+        super().__init__(spbase_object, strata_rank, fabric, options)
+        self.is_minimizing = self.opt.is_minimizing
+        # (bound, certificates, W), written as one under the lock: the
+        # reader may be another thread while this spoke is mid-pass
+        self._best_lock = threading.Lock()
+        self._best = (-math.inf if self.is_minimizing else math.inf,
+                      None, None)
+
+    def keep_if_best(self, bound, certificates, W) -> bool:
+        """Keep ``certificates`` ((S,), whose expectation is ``bound``) and
+        the ``W`` ((S, K)) behind them if ``bound`` is the best so far."""
+        if bound is None or not np.isfinite(bound):
+            return False
+        with self._best_lock:
+            best = self._best[0]
+            if not (bound > best if self.is_minimizing else bound < best):
+                return False
+            self._best = (float(bound), np.array(certificates, dtype=float),
+                          np.array(W, dtype=float))
+        return True
+
+    def best_certificates(self):
+        """(bound, d, W): the best outer bound this spoke reported, its
+        per-scenario certificates ``d`` (S,) (each with its scenario's
+        objective constant in it) and the ``W`` (S, K) they were computed
+        at; ``d`` and ``W`` are None until a finite bound came."""
+        with self._best_lock:
+            return self._best
 
 
 class _BoundNonantSpoke(_BoundNonantLenSpoke):

@@ -25,6 +25,7 @@ import dataclasses
 
 import numpy as np
 
+from .obs import metrics as _metrics
 from .scenario_tree import ScenarioNode, TreeInfo, build_tree
 
 INF = np.inf
@@ -41,20 +42,23 @@ class LinearModelBuilder:
     def __init__(self, name: str):
         self.name = name
         self._varnames: list[str] = []
+        self._names: set[str] = set()
         self._lb: list[float] = []
         self._ub: list[float] = []
         self._c: list[float] = []
         self._q2: list[float] = []
         self._is_int: list[bool] = []
-        self._rows: list[tuple[dict, float, float]] = []
+        # (dict column -> value, or (columns, coefficients)), cl, cu
+        self._rows: list[tuple] = []
         self.nodes: list[ScenarioNode] = []
         self.prob: float | None = None
         self.const: float = 0.0
 
     def add_var(self, name, lb=0.0, ub=INF, cost=0.0, quad=0.0, integer=False) -> int:
         """Declare a variable; returns its flat index."""
-        if name in self._varnames:
+        if name in self._names:
             raise ValueError(f"duplicate variable {name}")
+        self._names.add(name)
         self._varnames.append(name)
         self._lb.append(float(lb))
         self._ub.append(float(ub))
@@ -64,15 +68,37 @@ class LinearModelBuilder:
         return len(self._varnames) - 1
 
     def add_vars(self, prefix, k, **kw) -> list[int]:
-        return [self.add_var(f"{prefix}[{i}]", **kw) for i in range(k)]
+        return self.add_named_vars([f"{prefix}[{i}]" for i in range(k)], **kw)
 
-    def add_row(self, coeffs: dict, cl=-INF, cu=INF):
-        """Add constraint cl <= sum_j coeffs[j]*x_j <= cu (indices or names)."""
-        idx = {
-            (self._varnames.index(k) if isinstance(k, str) else int(k)): float(v)
-            for k, v in coeffs.items()
-        }
-        self._rows.append((idx, float(cl), float(cu)))
+    def add_named_vars(self, names, lb=0.0, ub=INF, cost=0.0, quad=0.0,
+                       integer=False) -> list[int]:
+        """Declare ``len(names)`` variables at once; every keyword is one
+        value for all of them or one value each.  Returns their indices."""
+        names = list(names)
+        k, first = len(names), len(self._varnames)
+        if len(set(names)) != k or not self._names.isdisjoint(names):
+            dup = next(nm for i, nm in enumerate(names)
+                       if nm in self._names or nm in names[:i])
+            raise ValueError(f"duplicate variable {dup}")
+        self._names.update(names)
+        self._varnames.extend(names)
+        for store, value, kind in ((self._lb, lb, float), (self._ub, ub, float),
+                                   (self._c, cost, float),
+                                   (self._q2, quad, float),
+                                   (self._is_int, integer, bool)):
+            store.extend(np.broadcast_to(np.asarray(value, dtype=kind),
+                                         (k,)).tolist())
+        return list(range(first, first + k))
+
+    def add_row(self, coeffs, cl=-INF, cu=INF):
+        """Add constraint cl <= sum_j coeffs[j]*x_j <= cu: ``coeffs`` a dict
+        (column index or name -> coefficient) or a whole row as a pair
+        (column indices, coefficients)."""
+        if isinstance(coeffs, dict):
+            coeffs = {
+                (self._varnames.index(k) if isinstance(k, str) else int(k)):
+                float(v) for k, v in coeffs.items()}
+        self._rows.append((coeffs, float(cl), float(cu)))
 
     def add_eq(self, coeffs, rhs):
         self.add_row(coeffs, rhs, rhs)
@@ -94,8 +120,11 @@ class LinearModelBuilder:
         cl = np.zeros(m)
         cu = np.zeros(m)
         for r, (coeffs, lo, hi) in enumerate(self._rows):
-            for j, v in coeffs.items():
-                A[r, j] = v
+            if isinstance(coeffs, dict):
+                for j, v in coeffs.items():
+                    A[r, j] = v
+            else:
+                A[r, coeffs[0]] = coeffs[1]
             cl[r], cu[r] = lo, hi
         return ScenarioProblem(
             name=self.name,
@@ -171,6 +200,27 @@ def _pad_problem(p: ScenarioProblem, n: int, m: int) -> ScenarioProblem:
     )
 
 
+def _shares_one_A(problems) -> bool:
+    """Whether every scenario carries the same constraint matrix: the same
+    object, or one of the same shape and content (a creator that builds its
+    rows anew for every scenario, as the families ported through
+    ``LinearModelBuilder`` do).  The comparison stops at the first scenario
+    that differs, so a family whose matrices are random (farmer's yields)
+    pays for one pair.  THE one place that decides sharedness: everything
+    downstream reads ``ScenarioBatch.A_shared``."""
+    A0 = problems[0].A
+    by_value = False
+    for p in problems[1:]:
+        if p.A is A0:
+            continue
+        if p.A.shape != A0.shape or not np.array_equal(p.A, A0):
+            return False
+        by_value = True
+    if by_value:
+        _metrics.inc("ingest.a_shared_by_value")
+    return True
+
+
 @dataclasses.dataclass
 class ScenarioBatch:
     """A stacked batch of scenarios + compiled tree info.
@@ -197,10 +247,11 @@ class ScenarioBatch:
     # keyed on it (SPOpt._solve_sig) invalidate
     version: int = 0
     # Shared constraint matrix (m, n), set when every scenario carries the
-    # SAME A object (uncertainty in costs/rhs/bounds only — the reference's
-    # headline UC is this shape: wind enters the power-balance rhs).  Model
-    # creators opt in by reusing one numpy array across their
-    # ScenarioProblems; ``.A`` is then a broadcast view (no (S, m, n) memory)
+    # SAME A, by identity or by value (uncertainty in costs/rhs/bounds only —
+    # the reference's headline UC is this shape: wind enters the
+    # power-balance rhs).  ``from_problems`` finds it (``_shares_one_A``): a
+    # creator need not reuse one numpy array, though one that does spares
+    # the comparison.  ``.A`` is then a broadcast view (no (S, m, n) memory)
     # and solves dispatch to the shared-A engine
     # (tpusppy.solvers.shared_admm), which keeps ONE (n, n) factorization
     # for the whole batch.
@@ -221,11 +272,9 @@ class ScenarioBatch:
 
         n = max(p.num_vars for p in problems)
         m = max(p.num_rows for p in problems)
-        # identity-shared A detection BEFORE padding (padding never triggers
-        # for a shared family — all members have the same shape by
-        # construction)
-        A0 = problems[0].A
-        a_shared = all(p.A is A0 for p in problems)
+        # shared-A detection BEFORE padding (a shared family needs none:
+        # all members have one shape)
+        a_shared = _shares_one_A(problems)
         problems = [_pad_problem(p, n, m) for p in problems]
 
         tree = build_tree(problems)
@@ -240,7 +289,7 @@ class ScenarioBatch:
             var_names = None
 
         if a_shared:
-            A_shared = np.ascontiguousarray(A0)
+            A_shared = np.ascontiguousarray(problems[0].A)
             A = np.broadcast_to(A_shared[None], (len(problems), m, n))
         else:
             A_shared = None

@@ -10,9 +10,17 @@ The reference reads SIPLIB ``.dat`` instances (``sslp_15_45_5`` etc.); here
 instances are generated from a seeded stream with the same shape — pass
 ``num_servers``/``num_clients`` mirroring the instance-name convention
 (sslp_<servers>_<clients>_<scens>).
+
+Only the right-hand side is random, so every scenario's ``A`` has the same
+content: each is built anew here (whole rows handed to the builder), and
+``ScenarioBatch.from_problems`` finds the matrix shared by value, so the
+batch runs the shared-A engine (one (n, n) factor for all scenarios) with
+nothing for this file to opt into.
 """
 
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -45,9 +53,11 @@ def inparser_adder(cfg):
     cfg.add_to_config("sslp_num_clients", "number of clients", int, 15)
 
 
+@functools.lru_cache(maxsize=8)
 def _instance_data(num_servers, num_clients, seedoffset):
     """Deterministic instance-wide data (demands, costs, revenues) shared by
-    all scenarios; SIPLIB-shaped magnitudes."""
+    all scenarios; SIPLIB-shaped magnitudes.  Drawn once per instance, not
+    once per scenario: the arrays are read, never written."""
     stream = np.random.RandomState(90210 + seedoffset)
     demand = stream.randint(1, 10, size=(num_clients, num_servers)).astype(
         float)
@@ -69,24 +79,21 @@ def scenario_creator(scenario_name, num_servers=5, num_clients=15,
     as_int = not relax_integers
     b = LinearModelBuilder(scenario_name)
     x = b.add_vars("FacilityOpen", num_servers, lb=0.0, ub=1.0,
-                   integer=as_int)
-    for j in range(num_servers):
-        b.set_cost(x[j], fixed_cost[j])
-    y = {}
-    for i in range(num_clients):
-        for j in range(num_servers):
-            y[i, j] = b.add_var(f"Allocation[{i},{j}]", lb=0.0, ub=1.0,
-                                cost=-revenue[i, j], integer=as_int)
+                   cost=fixed_cost, integer=as_int)
+    # y[i, j]: client i served at site j, client-major
+    y = np.asarray(b.add_named_vars(
+        [f"Allocation[{i},{j}]" for i in range(num_clients)
+         for j in range(num_servers)],
+        lb=0.0, ub=1.0, cost=-revenue.ravel(), integer=as_int)).reshape(
+            num_clients, num_servers)
     dummy = b.add_vars("Dummy", num_servers, lb=0.0, cost=PENALTY)
 
     for j in range(num_servers):
-        coeffs = {y[i, j]: demand[i, j] for i in range(num_clients)}
-        coeffs[dummy[j]] = -1.0
-        coeffs[x[j]] = -capacity
-        b.add_le(coeffs, 0.0)
+        b.add_le((np.concatenate([y[:, j], [dummy[j], x[j]]]),
+                  np.concatenate([demand[:, j], [-1.0, -capacity]])), 0.0)
+    ones = np.ones(num_servers)
     for i in range(num_clients):
-        b.add_eq({y[i, j]: 1.0 for j in range(num_servers)},
-                 float(present[i]))
+        b.add_eq((y[i], ones), present[i])
 
     p = b.build()
     p.nodes = [ScenarioNode("ROOT", 1.0, 1, np.asarray(x, dtype=np.int32))]

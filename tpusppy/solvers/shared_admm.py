@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from ..obs import metrics as _metrics
 from . import aot as _aot
+from . import turns as _turns
 from .admm import (ADMMSettings, BatchSolution, BIG, _clean_bounds,
                    _done_mask, _explicit_inverse, _frozen_sweep_phases,
                    _plateau_update)
@@ -485,11 +486,30 @@ def _scale_shared(c, q2, A, cl, cu, lb, ub, D, E, cost, warm, dt):
     return qs, q2s, As, cls, cus, lbs, ubs, warm
 
 
-def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
-                       want_factors=False):
-    # TRACE-time counter (wrappers are jitted; this body runs only while
-    # XLA builds the program): one per adaptive shared-A program compiled
-    _metrics.inc("shared_admm.adaptive_programs")
+class _Scaled(NamedTuple):
+    """What every restart of one adaptive solve reads: the scaled problem
+    and the shared classes it was scaled with."""
+
+    qs: jax.Array
+    q2s: jax.Array
+    As: jax.Array
+    cls: jax.Array
+    cus: jax.Array
+    lbs: jax.Array
+    ubs: jax.Array
+    q2ref: jax.Array   # (n,) scaled q2 the shared K is built with
+    D: jax.Array
+    E: jax.Array
+    cost: jax.Array
+    eq: jax.Array
+    loose: jax.Array
+    eqx: jax.Array
+    glo: jax.Array
+    ghi: jax.Array
+
+
+def _shared_setup(c, q2, A, cl, cu, lb, ub, settings, warm):
+    """(scaled problem, restart carry) of one adaptive shared-A solve."""
     dt = settings.jdtype()
     c, q2, A, cl, cu, lb, ub, masks = _prep_shared(
         c, q2, A, cl, cu, lb, ub, settings)
@@ -506,17 +526,6 @@ def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
     qs, q2s, As, cls, cus, lbs, ubs, warm = _scale_shared(
         c, q2, A, cl, cu, lb, ub, D, E, cost, warm, dt)
     q2ref = jnp.mean(q2s, axis=0)
-
-    st = settings
-    eq, loose, eqx = masks.eq, masks.loose, masks.eqx
-
-    def rho_vec(base):
-        r = jnp.where(eq, base * st.rho_eq_scale, base)
-        return jnp.where(loose, st.rho_min, r)
-
-    def rho_x_vec(base):
-        return jnp.where(eqx, base * st.rho_eq_scale,
-                         jnp.full((n,), base, dt))
 
     if warm is None:
         x0 = jnp.zeros((S, n), dt)
@@ -542,53 +551,6 @@ def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
     glo = jnp.where(lp_like, 1e-4, 0.6)
     ghi = jnp.where(lp_like, 1e4, 1.8)
 
-    def restart(carry, _):
-        state, base, total, mult, multx = carry[:5]
-        rho_a = rho_vec(base)
-        rho_x = rho_x_vec(base)
-        if st.rho_row_adapt:
-            rho_a = jnp.minimum(rho_a * mult, st.rho_row_max)
-            rho_x = jnp.minimum(rho_x * multx, st.rho_row_max)
-        Kinv, K = _factor_shared(q2ref, As, rho_a, rho_x, st.sigma)
-        state = _core(qs, q2s, q2ref, As, cls, cus, lbs, ubs,
-                      state._replace(k=jnp.zeros((), jnp.int32),
-                                     best=jnp.asarray(jnp.inf, dt),
-                                     stall=jnp.zeros((), jnp.int32)),
-                      Kinv, K, rho_a, rho_x, glo, ghi, st, adaptive=True)
-        total = total + state.k
-        done = _done_mask(state.pri, state.dua, state.prinorm,
-                          state.duanorm, st)
-        eps_pri = st.eps_abs + st.eps_rel * jnp.maximum(state.prinorm, 1.0)
-        pri_rel = state.pri / jnp.maximum(state.prinorm, 1e-10)
-        dua_rel = state.dua / jnp.maximum(state.duanorm, 1e-10)
-        ratio = jnp.sqrt(
-            jnp.maximum(pri_rel, 1e-12) / jnp.maximum(dua_rel, 1e-12))
-        # shared base: adapt on the geometric-mean ratio of UNCONVERGED
-        # scenarios (converged ones would anchor the ratio at its stale
-        # value); per-scenario adaptation lives in-loop via gamma.
-        # Diverged scenarios (inf residuals from the in-loop guard) have a
-        # NaN ratio and are EXCLUDED — one exploding scenario must not
-        # poison the shared base for the whole batch.
-        ok = jnp.isfinite(ratio)
-        logr = jnp.where(done | ~ok, 0.0,
-                         jnp.log(jnp.clip(ratio, 0.1, 10.0)))
-        denom = jnp.maximum(jnp.sum(~done & ok), 1)
-        gmean = jnp.exp(jnp.sum(logr) / denom)
-        base = jnp.where(jnp.all(done), base,
-                         jnp.clip(base * gmean, st.rho_min, st.rho_max))
-        if st.rho_row_adapt:
-            stuck = (state.pri > 100.0 * eps_pri)[:, None]
-            gate = jnp.maximum(0.3 * state.pri, 10.0 * eps_pri)[:, None]
-            Ax = _mv(As, state.x)
-            viol = jnp.maximum(cls - Ax, Ax - cus)
-            hit = jnp.any(stuck & (viol > gate), axis=0)       # max over S
-            mult = jnp.where(hit, mult * st.rho_row_boost, mult)
-            violx = jnp.maximum(lbs - state.x, state.x - ubs)
-            hitx = jnp.any(stuck & (violx > gate), axis=0)
-            multx = jnp.where(hitx, multx * st.rho_row_boost, multx)
-        return (state, base, total, mult, multx,
-                rho_a, rho_x, Kinv, K), None
-
     # (Kinv, K) carry placeholders must match the factorization regime's
     # pytree structure (lax.scan carries are structure-invariant): dense
     # (n, n) pair for a dense A, (dense, None) for unstructured sparse,
@@ -602,18 +564,84 @@ def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
     else:
         zKinv = jnp.zeros((n, n), dt)
         zK = zKinv
-    carry0 = (state0, jnp.asarray(st.rho, dt), jnp.zeros((), jnp.int32),
+    carry0 = (state0, jnp.asarray(settings.rho, dt),
+              jnp.zeros((), jnp.int32),
               jnp.ones((m,), dt), jnp.ones((n,), dt),
               jnp.zeros((m,), dt), jnp.zeros((n,), dt), zKinv, zK)
-    (state, _, total, _, _, rho_a, rho_x, Kinv, K), _ = jax.lax.scan(
-        restart, carry0, None, length=st.restarts)
-    gamma = state.gamma
+    scaled = _Scaled(qs, q2s, As, cls, cus, lbs, ubs, q2ref, D, E, cost,
+                     masks.eq, masks.loose, masks.eqx, glo, ghi)
+    return scaled, carry0
 
-    def unscale(s):
-        return (s.x * D[None, :], s.z / E[None, :],
-                s.y * E[None, :] / cost, s.yx / D[None, :] / cost)
 
-    x, z, y, yx = unscale(state)
+def _shared_restart(sc: _Scaled, carry, st: ADMMSettings):
+    """One rho setting of the adaptive solve: factor, sweep, re-adapt."""
+    dt = st.jdtype()
+    n = sc.qs.shape[1]
+    qs, q2s, As = sc.qs, sc.q2s, sc.As
+    cls, cus, lbs, ubs = sc.cls, sc.cus, sc.lbs, sc.ubs
+
+    def rho_vec(base):
+        r = jnp.where(sc.eq, base * st.rho_eq_scale, base)
+        return jnp.where(sc.loose, st.rho_min, r)
+
+    def rho_x_vec(base):
+        return jnp.where(sc.eqx, base * st.rho_eq_scale,
+                         jnp.full((n,), base, dt))
+
+    state, base, total, mult, multx = carry[:5]
+    rho_a = rho_vec(base)
+    rho_x = rho_x_vec(base)
+    if st.rho_row_adapt:
+        rho_a = jnp.minimum(rho_a * mult, st.rho_row_max)
+        rho_x = jnp.minimum(rho_x * multx, st.rho_row_max)
+    Kinv, K = _factor_shared(sc.q2ref, As, rho_a, rho_x, st.sigma)
+    state = _core(qs, q2s, sc.q2ref, As, cls, cus, lbs, ubs,
+                  state._replace(k=jnp.zeros((), jnp.int32),
+                                 best=jnp.asarray(jnp.inf, dt),
+                                 stall=jnp.zeros((), jnp.int32)),
+                  Kinv, K, rho_a, rho_x, sc.glo, sc.ghi, st, adaptive=True)
+    total = total + state.k
+    done = _done_mask(state.pri, state.dua, state.prinorm,
+                      state.duanorm, st)
+    eps_pri = st.eps_abs + st.eps_rel * jnp.maximum(state.prinorm, 1.0)
+    pri_rel = state.pri / jnp.maximum(state.prinorm, 1e-10)
+    dua_rel = state.dua / jnp.maximum(state.duanorm, 1e-10)
+    ratio = jnp.sqrt(
+        jnp.maximum(pri_rel, 1e-12) / jnp.maximum(dua_rel, 1e-12))
+    # shared base: adapt on the geometric-mean ratio of UNCONVERGED
+    # scenarios (converged ones would anchor the ratio at its stale
+    # value); per-scenario adaptation lives in-loop via gamma.
+    # Diverged scenarios (inf residuals from the in-loop guard) have a
+    # NaN ratio and are EXCLUDED — one exploding scenario must not
+    # poison the shared base for the whole batch.
+    ok = jnp.isfinite(ratio)
+    logr = jnp.where(done | ~ok, 0.0,
+                     jnp.log(jnp.clip(ratio, 0.1, 10.0)))
+    denom = jnp.maximum(jnp.sum(~done & ok), 1)
+    gmean = jnp.exp(jnp.sum(logr) / denom)
+    base = jnp.where(jnp.all(done), base,
+                     jnp.clip(base * gmean, st.rho_min, st.rho_max))
+    if st.rho_row_adapt:
+        stuck = (state.pri > 100.0 * eps_pri)[:, None]
+        gate = jnp.maximum(0.3 * state.pri, 10.0 * eps_pri)[:, None]
+        Ax = _mv(As, state.x)
+        viol = jnp.maximum(cls - Ax, Ax - cus)
+        hit = jnp.any(stuck & (viol > gate), axis=0)       # max over S
+        mult = jnp.where(hit, mult * st.rho_row_boost, mult)
+        violx = jnp.maximum(lbs - state.x, state.x - ubs)
+        hitx = jnp.any(stuck & (violx > gate), axis=0)
+        multx = jnp.where(hitx, multx * st.rho_row_boost, multx)
+    return (state, base, total, mult, multx, rho_a, rho_x, Kinv, K)
+
+
+def _shared_finish(sc: _Scaled, carry, st: ADMMSettings, want_factors):
+    """The unscaled solution (and the last restart's factors)."""
+    state, _, total, _, _, rho_a, rho_x, Kinv, K = carry
+    S = sc.qs.shape[0]
+    D, E, cost = sc.D, sc.E, sc.cost
+    x, z, y, yx = (state.x * D[None, :], state.z / E[None, :],
+                   state.y * E[None, :] / cost,
+                   state.yx / D[None, :] / cost)
     sol = BatchSolution(
         x=x, z=z, y=y, yx=yx,
         pri_res=state.pri, dua_res=state.dua,
@@ -624,10 +652,22 @@ def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
     )
     if want_factors:
         return sol, SharedFactors(D=D, E=E, cost=cost, rho_a=rho_a,
-                                  rho_x=rho_x, gamma=gamma, Kinv=Kinv,
+                                  rho_x=rho_x, gamma=state.gamma, Kinv=Kinv,
                                   K=K if st.factors_keep_K else None,
-                                  q2ref=q2ref)
+                                  q2ref=sc.q2ref)
     return sol
+
+
+def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
+                       want_factors=False):
+    # TRACE-time counter (wrappers are jitted; this body runs only while
+    # XLA builds the program): one per adaptive shared-A program compiled
+    _metrics.inc("shared_admm.adaptive_programs")
+    sc, carry0 = _shared_setup(c, q2, A, cl, cu, lb, ub, settings, warm)
+    carry, _ = jax.lax.scan(
+        lambda carry, _: (_shared_restart(sc, carry, settings), None),
+        carry0, None, length=settings.restarts)
+    return _shared_finish(sc, carry, settings, want_factors)
 
 
 def _solve_shared_frozen_impl(c, q2, A, cl, cu, lb, ub,
@@ -735,3 +775,89 @@ def solve_shared_frozen(c, q2, A, cl, cu, lb, ub, factors: SharedFactors,
 solve_shared_frozen = _aot.cached_program(
     solve_shared_frozen, "shared.solve_frozen",
     static_names=("settings",))
+
+
+# ---------------------------------------------------------------------------
+# Device turns (solvers/turns.py): what a spoke of a wheel whose gate is
+# engaged calls in place of the three programs above.  An adaptive solve
+# goes restart by restart, each a program of its own taken in a turn (the
+# restart is the scan body of the one-program solve, so the iterates are
+# the same up to how XLA fuses them); a frozen solve is one piece.
+# Anybody else's call passes through to the one-program solve.
+# ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("settings",))
+def _setup_program(c, q2, A, cl, cu, lb, ub, settings, warm):
+    with jax.default_matmul_precision(settings.matmul_precision):
+        return _shared_setup(c, q2, A, cl, cu, lb, ub, settings, warm)
+
+
+@functools.partial(jax.jit, static_argnames=("settings",))
+def _restart_program(sc, carry, settings):
+    with jax.default_matmul_precision(settings.matmul_precision):
+        return _shared_restart(sc, carry, settings)
+
+
+@functools.partial(jax.jit, static_argnames=("settings", "want_factors"))
+def _finish_program(sc, carry, settings, want_factors):
+    with jax.default_matmul_precision(settings.matmul_precision):
+        return _shared_finish(sc, carry, settings, want_factors)
+
+
+_pieces: dict = {}     # (piece, shapes, settings, ...) -> executable
+
+
+def _piece(program, key, *args, **static):
+    """``program`` compiled for ``args``, once a key: on the host and
+    outside any turn, so that nobody waits at the gate for a compiler
+    and no piece's seconds hold a compile."""
+    exe = _pieces.get((program, key))
+    if exe is None:
+        exe = program.lower(*args, **static).compile()
+        _pieces[program, key] = exe
+    return exe
+
+
+def adaptive_in_turns(c, q2, A, cl, cu, lb, ub,
+                      settings: ADMMSettings = ADMMSettings(), warm=None,
+                      want_factors=False):
+    """:func:`solve_shared` (or :func:`solve_shared_factored` with
+    ``want_factors``), one restart a device turn where the caller is a
+    spoke of an engaged gate."""
+    if isinstance(A, SparseA) or not _turns.pieces():
+        fn = solve_shared_factored if want_factors else solve_shared
+        return fn(c, q2, A, cl, cu, lb, ub, settings=settings, warm=warm)
+    dt = settings.jdtype()
+    args = tuple(jnp.asarray(a, dt) for a in (c, q2, A, cl, cu, lb, ub))
+    if warm is not None:
+        warm = tuple(jnp.asarray(w, dt) for w in warm)
+    key = (args[0].shape, args[2].shape, settings)
+    setup = _piece(_setup_program, key + (warm is None,), *args,
+                   settings=settings, warm=warm)
+    shapes = _pieces.get(("shapes", key, warm is None))
+    if shapes is None:
+        shapes = _pieces["shapes", key, warm is None] = jax.eval_shape(
+            functools.partial(_setup_program, settings=settings), *args,
+            warm=warm)
+    restart = _piece(_restart_program, key, *shapes, settings=settings)
+    finish = _piece(_finish_program, key + (want_factors,), *shapes,
+                    settings=settings, want_factors=want_factors)
+    last = max(1, settings.restarts) - 1
+    sc = carry = out = None
+    for r in range(last + 1):
+        with _turns.chunk():
+            if r == 0:
+                sc, carry = setup(*args, warm=warm)
+            carry = restart(sc, carry)
+            if r == last:
+                out = finish(sc, carry)
+            jax.block_until_ready(carry[0].x if out is None else out)
+    return out
+
+
+def frozen_in_turn(*args, **kw):
+    """:func:`solve_shared_frozen` as one piece of a spoke's turn."""
+    with _turns.chunk() as held:
+        sol = solve_shared_frozen(*args, **kw)
+        if held:
+            jax.block_until_ready(sol.x)
+    return sol

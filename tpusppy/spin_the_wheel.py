@@ -25,6 +25,7 @@ import numpy as np
 from . import global_toc
 from .cylinders.spcommunicator import WindowFabric
 from .obs import trace as _trace
+from .solvers import turns as _turns
 
 
 class WheelSpinner:
@@ -183,8 +184,13 @@ class WheelSpinner:
         with _trace.phase("build"):
             hub_comm, hub_opt, spoke_comms, sup, ckpt_mgr = self._build(
                 t_build0)
-            threads, errors = self._start_spokes(spoke_comms, sup)
+            # the cylinders are threads on one device: they take it in
+            # turns once its programs are long (solvers/turns.py)
+            gate = (_turns.DeviceTurns() if _turns.in_order_device()
+                    else None)
+            threads, errors = self._start_spokes(spoke_comms, sup, gate)
         _trace.set_thread_track("hub")
+        _turns.join(gate, "hub")
         try:
             hub_comm.main()
         except BaseException:
@@ -192,6 +198,11 @@ class WheelSpinner:
             _trace.set_thread_track(None)
             hub_comm.send_terminate()
             raise
+        finally:
+            # nobody waits for a hub that left its loop
+            if gate is not None:
+                gate.close()
+            _turns.join(None, None)
         _trace.set_thread_track(None)
         # phase ``teardown``: hub main's return to run()'s return
         # (terminate, joins, finalize), back on the caller's track
@@ -249,7 +260,7 @@ class WheelSpinner:
         return hub_comm, hub_opt, spoke_comms, sup, ckpt_mgr
 
     @staticmethod
-    def _start_spokes(spoke_comms, sup):
+    def _start_spokes(spoke_comms, sup, gate=None):
         # Run spokes on threads, hub on the caller's (role dispatch analogue
         # of spin_the_wheel.py:119-127)
         threads = []
@@ -259,6 +270,7 @@ class WheelSpinner:
             # each cylinder thread is its own trace timeline — the
             # per-cylinder rows of the Perfetto view (doc/observability.md)
             _trace.set_thread_track(track)
+            _turns.join(gate, "spoke")
             try:
                 comm.main()
             except Exception as e:          # surface spoke crashes at join

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -24,6 +25,7 @@ from .obs import metrics as _metrics
 from .obs import trace as _trace
 from .spbase import SPBase
 from .solvers import admm, hostsync
+from .solvers import turns as _turns
 
 _BATCH_TOKENS = itertools.count(1)
 
@@ -152,8 +154,8 @@ def batch_solve_dispatch(b, q, q2, cl, cu, lb, ub, settings, warm=None,
     from .solvers import shared_admm
 
     if getattr(b, "A_shared", None) is not None:
-        return shared_admm.solve_shared(q, q2, b.A_shared, cl, cu, lb, ub,
-                                        settings=settings, warm=warm)
+        return shared_admm.adaptive_in_turns(
+            q, q2, b.A_shared, cl, cu, lb, ub, settings=settings, warm=warm)
     A = b.A if rows is None else b.A[rows]
     if tile > 1:
         A = np.repeat(A, tile, axis=0)
@@ -425,8 +427,11 @@ class SPOpt(SPBase):
         """
         if shared:
             from .solvers import shared_admm
-            frozen_fn = shared_admm.solve_shared_frozen
-            factored_fn = shared_admm.solve_shared_factored
+            # a spoke's solves take the device in turns with the hub's
+            # (solvers/turns.py); anybody else's pass straight through
+            frozen_fn = shared_admm.frozen_in_turn
+            factored_fn = functools.partial(
+                shared_admm.adaptive_in_turns, want_factors=True)
         else:
             frozen_fn = admm.solve_batch_frozen
             factored_fn = admm.solve_batch_factored
@@ -444,7 +449,7 @@ class SPOpt(SPBase):
             # dispatches (segmented's per-dispatch budget);
             # want_converged=False — the convergence vote rides the packed
             # measurement below instead of a separate done fetch
-            with _trace.span(None, "solve.frozen") as _sp:
+            with _turns.hub_step(), _trace.span(None, "solve.frozen") as _sp:
                 cand, _ = segmented.solve_frozen_segmented(
                     frozen_fn, args, slot["factors"], self.admm_settings,
                     warm=slot["warm"], want_converged=False)
@@ -496,7 +501,7 @@ class SPOpt(SPBase):
             if st_adpt.sweep_precision not in (None, "highest"):
                 st_adpt = dataclasses.replace(st_adpt,
                                               sweep_precision="highest")
-            with _trace.phase("refresh"):
+            with _turns.hub_step(), _trace.phase("refresh"):
                 sol, factors, _ = segmented.solve_factored_segmented(
                     frozen_fn, factored_fn, args, st_adpt,
                     warm=slot.get("warm") if warm else None, shared=shared,
@@ -960,7 +965,7 @@ class SPOpt(SPBase):
         # the PH prox objective, so every scenario is QP
         _, tol_qp = self._straggler_tols()
         bounds = bound_live is not None
-        with _trace.phase("megastep") as _sp:
+        with _turns.hub_step(), _trace.phase("megastep") as _sp:
             fn = self._megastep_fn(n_req, pack, bounds=bounds)
             if bounds:
                 state, packed = fn(
@@ -1151,7 +1156,7 @@ class SPOpt(SPBase):
         _, tol_qp = self._straggler_tols()
         shapes = [(idx.size, sub.num_vars) for idx, sub in b.buckets]
         bounds = bound_live is not None
-        with _trace.phase("megastep") as _sp:
+        with _turns.hub_step(), _trace.phase("megastep") as _sp:
             fnb = self._bucketed_megastep_fn(n_req, bounds=bounds)
             if bounds:
                 states, packed = fnb(

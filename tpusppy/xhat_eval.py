@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from .obs import metrics as _metrics
+from .obs import trace as _trace
 from .spopt import SPOpt
 
 
@@ -92,18 +94,22 @@ class Xhat_Eval(SPOpt):
         lb = np.array(lb, copy=True)
         ub = np.array(ub, copy=True)
         x = None
-        for _ in range(rounds):
-            sol = batch_solve_dispatch(b, b.c, b.q2, b.cl, b.cu, lb, ub,
-                                       settings=self.admm_settings)
-            x = np.asarray(sol.x)
-            self.local_x = x
-            self.pri_res = np.asarray(sol.pri_res)
-            self.dua_res = np.asarray(sol.dua_res)
-            nxt = self._dive_round(x, ints, lb, ub,
-                                   lambda B: np.ones(B, dtype=bool))
-            if nxt is None:
-                break
-            lb, ub = nxt
+        done = 0
+        with _trace.phase("dive") as ph:
+            for done in range(1, rounds + 1):
+                sol = batch_solve_dispatch(b, b.c, b.q2, b.cl, b.cu, lb, ub,
+                                           settings=self.admm_settings)
+                x = np.asarray(sol.x)
+                self.local_x = x
+                self.pri_res = np.asarray(sol.pri_res)
+                self.dua_res = np.asarray(sol.dua_res)
+                nxt = self._dive_round(x, ints, lb, ub,
+                                       lambda B: np.ones(B, dtype=bool))
+                if nxt is None:
+                    break
+                lb, ub = nxt
+            ph.add(rounds=done)
+        _metrics.inc("xhat.dive_rounds", done)
         return x
 
     def _retry_dive(self, lb0, ub0, bad):
@@ -137,35 +143,37 @@ class Xhat_Eval(SPOpt):
 
         xs = np.zeros((bad.size, b.num_vars))
         feas = np.zeros(bad.size, dtype=bool)
-        for c0 in range(0, bad.size, chunk):
-            sel = bad[c0:c0 + chunk]
-            tile = lambda a: np.repeat(a[sel], R, axis=0)
-            c_t, q2_t = tile(b.c), tile(b.q2)
-            cl_t, cu_t = tile(b.cl), tile(b.cu)
-            lb_t, ub_t = tile(lb0), tile(ub0)
-            x = None
-            for _ in range(rounds):
-                sol = batch_solve_dispatch(
-                    b, c_t, q2_t, cl_t, cu_t, lb_t, ub_t,
-                    settings=self.admm_settings, rows=sel, tile=R)
-                x = np.asarray(sol.x)
-                nxt = self._dive_round(x, ints, lb_t, ub_t,
-                                       lambda B: rng.rand(B) < 0.5)
-                if nxt is None:
-                    break
-                lb_t, ub_t = nxt
-            # best feasible replica per wedged scenario
-            objs = (np.einsum("bn,bn->b", c_t, x)
-                    + 0.5 * np.einsum("bn,bn->b", q2_t, x * x))
-            pri = np.asarray(sol.pri_res)
-            frac = np.where(ints[None, :], np.abs(x - np.round(x)), 0.0)
-            ok = (pri <= tol) & (frac.max(axis=1) < 1e-5)
-            objs = np.where(ok, objs, np.inf)
-            for i in range(sel.size):
-                grp = objs[i * R:(i + 1) * R]
-                j = int(np.argmin(grp))
-                feas[c0 + i] = np.isfinite(grp[j])
-                xs[c0 + i] = x[i * R + j]
+        _metrics.inc("xhat.retry_rows", bad.size * R)
+        with _trace.phase("retry_dive", rows=int(bad.size), replicas=R):
+            for c0 in range(0, bad.size, chunk):
+                sel = bad[c0:c0 + chunk]
+                tile = lambda a: np.repeat(a[sel], R, axis=0)
+                c_t, q2_t = tile(b.c), tile(b.q2)
+                cl_t, cu_t = tile(b.cl), tile(b.cu)
+                lb_t, ub_t = tile(lb0), tile(ub0)
+                x = None
+                for _ in range(rounds):
+                    sol = batch_solve_dispatch(
+                        b, c_t, q2_t, cl_t, cu_t, lb_t, ub_t,
+                        settings=self.admm_settings, rows=sel, tile=R)
+                    x = np.asarray(sol.x)
+                    nxt = self._dive_round(x, ints, lb_t, ub_t,
+                                           lambda B: rng.rand(B) < 0.5)
+                    if nxt is None:
+                        break
+                    lb_t, ub_t = nxt
+                # best feasible replica per wedged scenario
+                objs = (np.einsum("bn,bn->b", c_t, x)
+                        + 0.5 * np.einsum("bn,bn->b", q2_t, x * x))
+                pri = np.asarray(sol.pri_res)
+                frac = np.where(ints[None, :], np.abs(x - np.round(x)), 0.0)
+                ok = (pri <= tol) & (frac.max(axis=1) < 1e-5)
+                objs = np.where(ok, objs, np.inf)
+                for i in range(sel.size):
+                    grp = objs[i * R:(i + 1) * R]
+                    j = int(np.argmin(grp))
+                    feas[c0 + i] = np.isfinite(grp[j])
+                    xs[c0 + i] = x[i * R + j]
         return xs, feas
 
     def _host_milp(self, lb, ub, only=None):
@@ -186,14 +194,16 @@ class Xhat_Eval(SPOpt):
         pri = np.zeros(S)
         limit = float(self.options.get("xhat_mip_time_limit", 2.0))
         gap = float(self.options.get("xhat_mip_rel_gap", 1e-4))
-        for s in scens:
-            res = scipy_backend.solve_lp(
-                b.c[s], b.A[s], b.cl[s], b.cu[s], lb[s], ub[s],
-                is_int=b.is_int, mip_rel_gap=gap, time_limit=limit)
-            if res.feasible:
-                xs[s] = res.x
-            else:
-                pri[s] = np.inf
+        _metrics.inc("xhat.host_milp_rows", len(scens))
+        with _trace.phase("host_milp", rows=len(scens)):
+            for s in scens:
+                res = scipy_backend.solve_lp(
+                    b.c[s], b.A[s], b.cl[s], b.cu[s], lb[s], ub[s],
+                    is_int=b.is_int, mip_rel_gap=gap, time_limit=limit)
+                if res.feasible:
+                    xs[s] = res.x
+                else:
+                    pri[s] = np.inf
         self.local_x = xs
         self.pri_res = pri
         self.dua_res = np.zeros(S)
@@ -319,6 +329,7 @@ class Xhat_Eval(SPOpt):
                 bad = np.flatnonzero(
                     (np.asarray(self.pri_res) > tol)
                     | (frac.max(axis=1) > 1e-5))
+                _metrics.inc("xhat.dive_wedged_rows", bad.size)
                 if bad.size:
                     # batched randomized-rounding retries for wedged
                     # scenarios (device path)
