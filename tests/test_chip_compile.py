@@ -176,3 +176,36 @@ def test_wheel_megastep_compiles_at_the_served_farmer_shape(one_chip,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert 0 < total < 2 ** 32
+
+
+def test_sslp_frozen_solve_compiles_with_half_the_flops_on_lowrank_factors(
+        one_chip, chip32):
+    """The benchmark's sslp frozen solve (S=2000, n=520, m=60, float32, no
+    K in the factors, as every wheel sets it): XLA:TPU compiles it with the
+    diagonal-plus-rank-60 K^-1 that ``_factor_shared`` now returns at this
+    shape, and counts under half the operations of the same program handed
+    the (520, 520) explicit inverse."""
+    from tpusppy.solvers import shared_admm, structured_kkt
+
+    S, m, n = 2000, 60, 520
+    st = ADMMSettings(factors_keep_K=False, **F32)
+    sh = lambda *shape: _spec(shape, one_chip)
+    assert structured_kkt.lowrank_kinv(sh(m, n))
+
+    def flops(Kinv):
+        fac = shared_admm.SharedFactors(
+            D=sh(n), E=sh(m), cost=sh(), rho_a=sh(m), rho_x=sh(n),
+            gamma=sh(S), Kinv=Kinv, K=None, q2ref=sh(n))
+        compiled = shared_admm.solve_shared_frozen._jitted.lower(
+            sh(S, n), sh(S, n), sh(m, n), sh(S, m), sh(S, m), sh(S, n),
+            sh(S, n), fac, settings=st,
+            warm=(sh(S, n), sh(S, m), sh(S, m), sh(S, n))).compile()
+        assert not _has_mosaic_kernel(compiled)
+        return compiled.cost_analysis()["flops"]
+
+    lowrank = flops(structured_kkt.DiagLowRank(
+        dinv=sh(n), W=sh(m, n), N=sh(m, n)))
+    dense = flops(sh(n, n))
+    print("sslp frozen solve flops a check block: lowrank", lowrank,
+          "dense", dense)
+    assert 0 < lowrank < 0.5 * dense

@@ -48,8 +48,9 @@ from .admm import (ADMMSettings, BatchSolution, BIG, _clean_bounds,
                    _done_mask, _explicit_inverse, _frozen_sweep_phases,
                    _plateau_update)
 from .sparse import SparseA
-from .structured_kkt import (apply_kinv_like, factor_structured,
-                             zero_factors)
+from .structured_kkt import (apply_kinv_like, factor_lowrank,
+                             factor_structured, is_dense_kinv,
+                             lowrank_kinv, zero_factors, zero_lowrank)
 
 
 def _mv(A, x):
@@ -73,8 +74,11 @@ class SharedFactors(NamedTuple):
     rho_x: jax.Array   # (n,) variable-box penalties actually used last
     gamma: jax.Array   # (S,) per-scenario penalty scales actually used last
     Kinv: jax.Array    # (n, n) explicit inverse of the shared x-update
-                       # system, or a structured_kkt.BlockWoodbury operator
-                       # (sparse-A families with block/Woodbury structure)
+                       # system, or an operator of structured_kkt applied
+                       # by apply_kinv_like: BlockWoodbury (sparse-A
+                       # families with block/Woodbury structure) or
+                       # DiagLowRank (a dense A of few rows beside its
+                       # columns: lowrank_kinv)
     K: jax.Array       # (n, n) exact shared K for dense refinement, or None
                        # (factors_keep_K=False): refinement then runs
                        # matrix-free through the scaled shared A
@@ -122,8 +126,12 @@ def _factor_shared(q2ref, A, rho_a, rho_x, sigma):
     """(Kinv, K) of the SHARED K = diag(q2ref + rho_x) + sigma I + A'RA —
     one (n, n) system for the whole scenario batch.
 
-    Three regimes by matrix type:
-    - dense (m, n) array: dense K + explicit inverse (unchanged);
+    Four regimes, by the type and the shape of A (read at trace time):
+    - dense (m, n) array: dense K + explicit inverse;
+    - dense (m, n) array of few rows beside its columns
+      (:func:`structured_kkt.lowrank_kinv`): dense K for the refinement
+      as above, and K^-1 as diagonal plus rank m
+      (:class:`structured_kkt.DiagLowRank`), no (n, n) inverse;
     - :class:`SparseA` WITH attached block/Woodbury structure: the
       structured factorization (no (n, n) object at all; K is None and
       refinement runs matrix-free through the sparse A);
@@ -144,6 +152,8 @@ def _factor_shared(q2ref, A, rho_a, rho_x, sigma):
     K = jnp.einsum("mn,m,mk->nk", A, rho_a, A)
     K = K + jnp.eye(n, dtype=A.dtype) * sigma
     K = K + jnp.diag(q2ref + rho_x)
+    if lowrank_kinv(A):
+        return factor_lowrank(A, q2ref + rho_x + sigma, rho_a), K
     return _explicit_inverse(K[None])[0], K
 
 
@@ -258,8 +268,7 @@ def _core(q, q2s, q2ref, A, cl, cu, lb, ub, state, Kinv, K, rho_a, rho_x,
     from .structured_kkt import BlockWoodbury, kinv_apply
     bs_sh = None
     if (allow_pallas and not adaptive and not sparse and K is not None
-            and not isinstance(Kinv, BlockWoodbury)
-            and st.use_pallas is not False):
+            and is_dense_kinv(Kinv) and st.use_pallas is not False):
         S_all, n_all = q.shape
         bs_sh = pallas_kernels.usable_shared(S_all, A.shape[0], n_all)
     # sparse/structured engines: fused ELL sweep kernel (frozen path).
@@ -553,8 +562,9 @@ def _shared_setup(c, q2, A, cl, cu, lb, ub, settings, warm):
 
     # (Kinv, K) carry placeholders must match the factorization regime's
     # pytree structure (lax.scan carries are structure-invariant): dense
-    # (n, n) pair for a dense A, (dense, None) for unstructured sparse,
-    # (BlockWoodbury, None) for the structured path
+    # (n, n) pair for a dense A, (DiagLowRank, dense) for a dense A of few
+    # rows, (dense, None) for unstructured sparse, (BlockWoodbury, None)
+    # for the structured path
     if isinstance(As, SparseA):
         if As.structure is not None:
             zKinv = zero_factors(As.structure, n, dt)
@@ -562,8 +572,8 @@ def _shared_setup(c, q2, A, cl, cu, lb, ub, settings, warm):
             zKinv = jnp.zeros((n, n), dt)
         zK = None
     else:
-        zKinv = jnp.zeros((n, n), dt)
-        zK = zKinv
+        zK = jnp.zeros((n, n), dt)
+        zKinv = zero_lowrank(m, n, dt) if lowrank_kinv(As) else zK
     carry0 = (state0, jnp.asarray(settings.rho, dt),
               jnp.zeros((), jnp.int32),
               jnp.ones((m,), dt), jnp.ones((n,), dt),

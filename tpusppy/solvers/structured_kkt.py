@@ -71,6 +71,65 @@ class BlockWoodbury(NamedTuple):
     Cinv: jax.Array    # (r, r) inverse Woodbury cap
 
 
+class DiagLowRank(NamedTuple):
+    """K^-1 of K = diag(d) + A' R A for a DENSE shared A of few rows
+    beside its columns (:func:`lowrank_kinv`), by the Woodbury identity
+
+        K^-1 = diag(1/d) - W' N,   W = A diag(1/d),   N = (R^-1 + W A')^-1 W:
+
+    two thin products an apply, ``2 m n`` a row where the explicit
+    inverse costs ``n n``.  The dense-A stand-in for the ``Kinv`` array
+    inside :class:`~tpusppy.solvers.shared_admm.SharedFactors`, as
+    :class:`BlockWoodbury` is the sparse-A one."""
+
+    dinv: jax.Array    # (n,) 1 / d
+    W: jax.Array       # (m, n) A diag(1/d)
+    N: jax.Array       # (m, n) (R^-1 + W A')^-1 W
+
+
+#: The MXU contracts and emits tiles of this edge: a thin product pays
+#: for m rounded up to it.
+_MXU_TILE = 128
+
+
+def lowrank_kinv(A) -> bool:
+    """Whether the shared engine applies K^-1 as :class:`DiagLowRank` for
+    this shared constraint matrix: the ONE place the regime is chosen,
+    from the type and the shape of ``A`` alone (``_factor_shared`` asks at
+    trace time, spopt's ``refresh.lowrank_kinv`` reads the factors it
+    made).  An apply is two (S, n) x (n, m) products with m padded to the
+    MXU's tile, ``2 roundup(m, 128) n`` a row, against ``n n`` for the
+    explicit inverse: the operator is taken where that is at most half
+    (sslp 10 x 50: 256 against 520; PERF.md section 6, PR 33, has a chip
+    reading either side).  A :class:`SparseA` keeps its own regimes."""
+    if isinstance(A, SparseA):
+        return False
+    m, n = A.shape
+    return 4 * (-(-m // _MXU_TILE) * _MXU_TILE) <= n
+
+
+def factor_lowrank(A, dvec, rho_a) -> DiagLowRank:
+    """Factor K = diag(dvec) + A' diag(rho_a) A for a dense, Ruiz-SCALED
+    (m, n) ``A``; the (m, m) cap goes through the inverse routine the
+    dense regime uses.  ``dvec`` = q2ref + rho_x + sigma is positive by
+    construction (q2ref >= 0 for a convex objective, rho_x >= rho_min > 0,
+    sigma > 0), so the division is safe."""
+    from .admm import _explicit_inverse
+
+    dinv = 1.0 / dvec
+    W = A * dinv[None, :]
+    C = W @ A.T
+    C = 0.5 * (C + C.T) + jnp.diag(1.0 / rho_a)
+    return DiagLowRank(dinv=dinv, W=W, N=_explicit_inverse(C[None])[0] @ W)
+
+
+def zero_lowrank(m: int, n: int, dt) -> DiagLowRank:
+    """Shape-matching all-zeros :class:`DiagLowRank`: the restart scan's
+    carry initializer (see :func:`zero_factors`)."""
+    return DiagLowRank(dinv=jnp.zeros((n,), dt), W=jnp.zeros((m, n), dt),
+                       N=jnp.zeros((m, n), dt))
+
+
 def _bapply(binv: tuple, bvars: tuple, b, prec=None):
     """B^-1 b for b (..., n): gather per bucket, batched block matmul,
     scatter back.  Blocks partition the variables, so scatters never
@@ -162,10 +221,23 @@ def kinv_apply(bw: BlockWoodbury, b, prec=None):
     return t - _bapply(bw.binv, bw.bvars, w, prec)
 
 
+def is_dense_kinv(Kinv) -> bool:
+    """The (n, n) explicit-inverse array, not an operator."""
+    return not isinstance(Kinv, (BlockWoodbury, DiagLowRank))
+
+
 def apply_kinv_like(Kinv, b, prec=None):
-    """Uniform K^-1 application: dense (n, n) array or BlockWoodbury."""
+    """Uniform K^-1 application: dense (n, n) array, BlockWoodbury or
+    DiagLowRank."""
     if isinstance(Kinv, BlockWoodbury):
         return kinv_apply(Kinv, b, prec)
+    if isinstance(Kinv, DiagLowRank):
+        if prec is None:
+            return b * Kinv.dinv - (b @ Kinv.W.T) @ Kinv.N
+        from . import precision
+        u = precision.contract("...n,mn->...m", b, Kinv.W, prec)
+        return b * Kinv.dinv - precision.contract(
+            "...m,mn->...n", u, Kinv.N, prec)
     if prec is None:
         return b @ Kinv
     from . import precision

@@ -427,6 +427,7 @@ class SPOpt(SPBase):
         """
         if shared:
             from .solvers import shared_admm
+            from .solvers.structured_kkt import DiagLowRank
             # a spoke's solves take the device in turns with the hub's
             # (solvers/turns.py); anybody else's pass straight through
             frozen_fn = shared_admm.frozen_in_turn
@@ -513,6 +514,10 @@ class SPOpt(SPBase):
             if not shared and admm.lanes_linalg(st_adpt, *args[2].shape):
                 # this refresh's polish ran on pallas_kernels.lanes_solve
                 _metrics.inc("refresh.lanes_linalg")
+            if shared and isinstance(factors.Kinv, DiagLowRank):
+                # this refresh's factors apply K^-1 as diagonal plus
+                # low rank (structured_kkt.lowrank_kinv)
+                _metrics.inc("refresh.lowrank_kinv")
             # full-precision residual floor of this family at this
             # operating point — the mixed-precision guard's reference
             slot["ref_worst"] = float(
