@@ -254,13 +254,13 @@ def device_state_check(opt, platform):
 
 def kernel_choice(batch):
     """Which sweep the phase's shapes get on the TPU: a Pallas block size
-    from ``usable``/``usable_shared``, or None = the XLA path."""
+    from ``usable`` (the dense engine), or None = the XLA path (the shared-A
+    engine always)."""
     from tpusppy.solvers import pallas_kernels as pk
 
     S, n, m = batch.num_scenarios, batch.num_vars, batch.num_rows
     shared = getattr(batch, "A_shared", None) is not None
-    bs = (pk.usable_shared(S, m, n, platform="tpu") if shared
-          else pk.usable(S, m, n, platform="tpu"))
+    bs = None if shared else pk.usable(S, m, n, platform="tpu")
     return {"S": S, "n": n, "m": m, "A": "shared" if shared else "per-scen",
             "pallas_block": bs, "sweep": "pallas" if bs else "xla"}
 

@@ -6,8 +6,10 @@ device and compiles it: what Mosaic or XLA:TPU would refuse on the chip
 (unaligned blocks, scoped-VMEM overflow, i64 block indices) is refused
 here, at no chip time.  Shapes are the ones ``chip_smoke.py`` runs: the
 ``serve`` phase's farmer (S=1000, crops_multiplier=4) for the per-scenario
-sweep kernel, the polish's elimination kernel and the wheel megastep, and
-the served ``uc_lite`` family for the shared-A kernel.
+sweep kernel, the polish's elimination kernel and the wheel megastep.  One
+test lowers every family's frozen solve and says which sweep it holds: the
+dense engine's kernel by that engine's own rule, XLA's for the shared-A
+engine.
 
 Rules this file keeps (the driver runs the suite under ``-n 6``): nothing
 chip-related happens at import or collection; the topology is described
@@ -84,6 +86,11 @@ def _spec(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
+def _on_chip(tree, sharding):
+    """Shapes of ``tree``'s leaves, placed on the described device."""
+    return jax.tree.map(lambda a: _spec(a.shape, sharding, a.dtype), tree)
+
+
 def _has_mosaic_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
@@ -120,31 +127,71 @@ def test_lanes_solve_compiles_at_the_served_farmer_polish_shape(one_chip,
     assert _has_mosaic_kernel(compiled)
 
 
-def test_fused_sweeps_shared_compiles_at_the_served_uc_lite_shape(
-        one_chip, chip32):
-    from tpusppy.ir import ScenarioBatch
-    from tpusppy.models import uc_lite
+SWEEP_FAMILIES = {
+    # case: (model, creator kwargs, S).  The dense engine's two farmer
+    # shapes (cm4 at S=1000 is the cells'), then every family whose A the
+    # ingest finds shared (tests/test_shared_by_value.py), at the S its
+    # example, its cell or chip_smoke runs.
+    "farmer_cm1": ("farmer", {"crops_multiplier": 1}, 3),
+    "farmer_cm4": ("farmer", {"crops_multiplier": FARMER_MULT}, FARMER_S),
+    "sslp_5_15": ("sslp", {}, 3),          # the creator's default: A 20 x 85
+    "sslp_10_50": ("sslp", {"num_servers": 10, "num_clients": 50}, 2000),
+    "sizes": ("sizes", {}, 3),
+    "netdes": ("netdes", {}, 3),
+    "gbd": ("gbd", {}, 3),
+    "hydro": ("hydro", {}, 9),
+    "usar": ("usar", {}, 3),
+    "uc_lite": ("uc_lite", {}, 100),
+}
 
-    S = 100
+
+@pytest.mark.parametrize("family", sorted(SWEEP_FAMILIES))
+def test_sweep_implementation_by_family(family, one_chip, chip32):
+    """The frozen solve ``SPOpt._solve_amortized`` calls, lowered for the
+    chip in float32 at the family's shape, with the factors as a wheel sets
+    them (``factors_keep_K=False``) and as the default keeps them: a
+    shared-A family's sweep is XLA's under both, the dense engine's holds
+    the Pallas kernel wherever ``admm._admm_core``'s rule picks a block."""
+    import functools
+    import importlib
+
+    from tpusppy.ir import ScenarioBatch
+    from tpusppy.solvers import admm, shared_admm
+
+    model, kw, S = SWEEP_FAMILIES[family]
+    module = importlib.import_module("tpusppy.models." + model)
+    built = S if S <= 9 else 2      # shapes only: (m, n) do not depend on S
+    if model == "farmer":
+        kw = dict(kw, num_scens=built)
     b = ScenarioBatch.from_problems(
-        [uc_lite.scenario_creator(nm, num_scens=2)
-         for nm in uc_lite.scenario_names_creator(2)])
-    assert b.A_shared is not None
+        [module.scenario_creator(nm, **kw)
+         for nm in module.scenario_names_creator(built)])
+    shared = b.A_shared is not None
+    assert shared == (model != "farmer")
     m, n = b.num_rows, b.num_vars
-    bs = pk.usable_shared(S, m, n, platform="tpu")
-    # sublane-dim blocks: the whole batch or a multiple of 8
-    assert bs is not None and (bs == S or bs % 8 == 0)
     sh = lambda *shape: _spec(shape, one_chip)
-    args = (sh(S, n), sh(m, n), sh(n, n), sh(n, n),
-            sh(S, m), sh(S, m), sh(S, n), sh(S, n),
-            sh(1, m), sh(1, n), sh(S, n), sh(1, 1), sh(S, 1),
-            sh(S, n), sh(S, m), sh(S, n), sh(S, m), sh(S, n), sh(S, m))
-    st = ADMMSettings(**F32)
-    compiled = pk.fused_sweeps_shared.lower(
-        *args, n_sweeps=max(1, st.check_every), n_refine=st.solve_refine,
-        n_extra=2, sigma=float(st.sigma), alpha=float(st.alpha),
-        bs=bs).compile()
-    assert _has_mosaic_kernel(compiled)
+    args = (sh(S, n), sh(S, n), sh(m, n) if shared else sh(S, m, n),
+            sh(S, m), sh(S, m), sh(S, n), sh(S, n))
+    warm = (sh(S, n), sh(S, m), sh(S, m), sh(S, n))
+    if shared:
+        factored, frozen = (shared_admm.solve_shared_factored,
+                            shared_admm.solve_shared_frozen)
+        expect = False
+    else:
+        factored, frozen = admm.solve_batch_factored, admm.solve_batch_frozen
+        bs = pk.usable(S, m, n)     # the call _admm_core makes under "auto"
+        expect = bs is not None and not 512 < bs < S
+        if family == "farmer_cm4":
+            assert expect                             # the cells' shape
+    for keep_K in (False, True):
+        st = ADMMSettings(factors_keep_K=keep_K, **F32)
+        _, factors = jax.eval_shape(
+            functools.partial(factored._jitted, settings=st), *args)
+        if shared:
+            assert (factors.K is not None) == keep_K
+        text = frozen._jitted.lower(*args, _on_chip(factors, one_chip),
+                                    settings=st, warm=warm).as_text()
+        assert ("tpu_custom_call" in text) == expect, (family, keep_K)
 
 
 def test_wheel_megastep_compiles_at_the_served_farmer_shape(one_chip,
@@ -162,8 +209,7 @@ def test_wheel_megastep_compiles_at_the_served_farmer_shape(one_chip,
     assert state.x.dtype == jnp.float32
     refresh, _ = sharded.make_ph_step_pair(idx, settings, None)
     _, _, factors = jax.eval_shape(refresh, state, arr, 1.0)
-    on_chip = lambda tree: jax.tree.map(
-        lambda a: _spec(a.shape, one_chip, a.dtype), tree)
+    on_chip = lambda tree: _on_chip(tree, one_chip)
     mega = sharded.make_wheel_megastep(idx, settings, None, n_iters=15,
                                        donate=True)
     compiled = mega._jitted.lower(

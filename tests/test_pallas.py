@@ -202,234 +202,6 @@ def test_fused_sweeps_default_precision_matches_emulation():
                                    err_msg=name)
 
 
-@pytest.mark.parametrize("mode", ["highest", "high", "default"])
-def test_fused_sweeps_shared_matches_xla(mode):
-    """Shared-A kernel against the shared_admm._core block() semantics at
-    every precision mode (interpret mode; operand-level bf16 splits make
-    the comparison exact up to summation order)."""
-    import jax.numpy as jnp
-
-    from tpusppy.solvers import precision
-
-    rng = np.random.RandomState(3)
-    S, m, n = 16, 9, 5
-    sigma, alpha = 1e-6, 1.6
-    n_sweeps, n_refine, n_extra = 3, 2, 2
-
-    A = rng.randn(m, n)
-    q = rng.randn(S, n)
-    cl = -np.abs(rng.randn(S, m)) - 0.5
-    cu = np.abs(rng.randn(S, m)) + 0.5
-    lb = -np.ones((S, n)) * 2
-    ub = np.ones((S, n)) * 2
-    rho_a = np.full(m, 0.7)
-    rho_x = np.full(n, 0.4)
-    K = (A.T * rho_a) @ A + sigma * np.eye(n) + np.diag(rho_x)
-    Kinv = np.linalg.inv(K)
-    gamma = 0.5 + rng.rand(S, 1)
-    dq2 = 0.1 * np.abs(rng.randn(S, n))
-    x = rng.randn(S, n) * 0.1
-    z = np.clip(rng.randn(S, m), cl, cu)
-    zx = np.clip(x, lb, ub)
-    y = rng.randn(S, m) * 0.1
-    yx = rng.randn(S, n) * 0.1
-    Ax = x @ A.T
-
-    C = lambda spec, a, b, md: precision.contract(
-        spec, jnp.asarray(a), jnp.asarray(b), md, platform="cpu")
-    g = jnp.asarray(gamma)
-    rho_a_s = g * rho_a[None, :]
-    rho_x_s = g * rho_x[None, :]
-    sigma_s = g * sigma
-    rx, rz, rzx, ry, ryx, rAx = (jnp.asarray(v)
-                                 for v in (x, z, zx, y, yx, Ax))
-    for _ in range(n_sweeps):
-        rhs = (sigma_s * rx - q + C("sm,mn->sn", rho_a_s * rz - ry, A, mode)
-               + (rho_x_s * rzx - ryx))
-        xt = C("...n,nk->...k", rhs / g, Kinv, mode)
-        for _ in range(n_refine + n_extra):   # dq2 != 0: extra passes run
-            r = rhs - (g * C("sn,nk->sk", xt, K, "highest") + dq2 * xt)
-            xt = xt + C("...n,nk->...k", r / g, Kinv, mode)
-        Axt = C("sn,mn->sm", xt, A, mode)
-        x_new = alpha * xt + (1 - alpha) * rx
-        Ax_new = alpha * Axt + (1 - alpha) * rAx
-        za = alpha * Axt + (1 - alpha) * rz + ry / rho_a_s
-        z_new = jnp.clip(za, cl, cu)
-        y_new = ry + rho_a_s * (alpha * Axt + (1 - alpha) * rz - z_new)
-        zxa = alpha * xt + (1 - alpha) * rzx + ryx / rho_x_s
-        zx_new = jnp.clip(zxa, lb, ub)
-        yx_new = ryx + rho_x_s * (alpha * xt + (1 - alpha) * rzx - zx_new)
-        rx, rz, rzx, ry, ryx, rAx = (x_new, z_new, zx_new, y_new, yx_new,
-                                     Ax_new)
-
-    has = jnp.ones((1, 1))
-    outs = pallas_kernels.fused_sweeps_shared(
-        q, A, Kinv, K, cl, cu, lb, ub, rho_a[None, :], rho_x[None, :],
-        dq2, has, gamma, x, z, zx, y, yx, Ax,
-        n_sweeps=n_sweeps, n_refine=n_refine, n_extra=n_extra, sigma=sigma,
-        alpha=alpha, bs=8, precision=mode, interpret=True)
-    # low modes: the emulation accumulates in f32 (the MXU accumulator)
-    # while the x64 interpret-mode kernel accumulates identical bf16
-    # products in f64 — ~1e-7 per contraction, amplified by the
-    # relaxation/refinement feedback to ~1e-5; still 1-2 orders below the
-    # operand rounding the modes themselves introduce.  "highest" has no
-    # rounding and stays tight.
-    rtol, atol = ((1e-10, 1e-12) if mode == "highest" else (1e-4, 1e-5))
-    for got, ref, name in zip(outs, (rx, rz, rzx, ry, ryx, rAx),
-                              ["x", "z", "zx", "y", "yx", "Ax"]):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=rtol, atol=atol, err_msg=name)
-
-
-def test_usable_shared_gating():
-    assert pallas_kernels.usable_shared(100, 20, 10, platform="cpu") is None
-    bs = pallas_kernels.usable_shared(1000, 200, 150, platform="tpu")
-    assert bs is not None and (bs == 1000 or bs % 8 == 0)
-    # reference-scale UC (n=16008): matrices alone dwarf VMEM — declines
-    assert pallas_kernels.usable_shared(
-        1000, 12408, 16008, platform="tpu") is None
-
-
-@pytest.mark.parametrize("mode", ["highest", "high", "default"])
-def test_fused_sweeps_sparse_matches_xla(mode):
-    """SPARSE/structured-engine kernel (padded-ELL matvecs, matrix-free
-    defect, lowered Kinv applies) against the shared_admm._core sparse
-    block() semantics: exact constraint matvecs — only the Kinv applies
-    run at the mode, the split the XLA sparse path uses — at every
-    precision mode (interpret mode)."""
-    import jax.numpy as jnp
-
-    from tpusppy.solvers import precision
-    from tpusppy.solvers.sparse import SparseA
-
-    rng = np.random.RandomState(11)
-    S, m, n = 12, 10, 6
-    sigma, alpha = 1e-6, 1.6
-    n_sweeps, n_refine, n_extra = 3, 2, 2
-
-    A = np.where(rng.rand(m, n) < 0.35, rng.randn(m, n), 0.0)
-    A[0, 0] = 1.3                       # no empty row 0 (ELL pad slot)
-    sp = SparseA.from_dense(A, jnp.float64, ell=True)
-    assert sp.ell is not None
-    q = rng.randn(S, n)
-    cl = -np.abs(rng.randn(S, m)) - 0.5
-    cu = np.abs(rng.randn(S, m)) + 0.5
-    lb = -np.ones((S, n)) * 2
-    ub = np.ones((S, n)) * 2
-    rho_a = np.full(m, 0.7)
-    rho_x = np.full(n, 0.4)
-    K = (A.T * rho_a) @ A + sigma * np.eye(n) + np.diag(rho_x)
-    Kinv = np.linalg.inv(K)
-    diagK = (rho_x + sigma)[None, :]    # q2ref = 0 in this family
-    gamma = 0.5 + rng.rand(S, 1)
-    dq2 = 0.1 * np.abs(rng.randn(S, n))
-    x = rng.randn(S, n) * 0.1
-    z = np.clip(rng.randn(S, m), cl, cu)
-    zx = np.clip(x, lb, ub)
-    y = rng.randn(S, m) * 0.1
-    yx = rng.randn(S, n) * 0.1
-    Ax = x @ A.T
-
-    # XLA reference: EXACT matvecs (the sparse engine's contract), Kinv
-    # applies at the mode, matrix-free full-precision defect
-    C = lambda a, b, md: precision.contract(
-        "...n,nk->...k", jnp.asarray(a), jnp.asarray(b), md,
-        platform="cpu")
-    g = jnp.asarray(gamma)
-    rho_a_s = g * rho_a[None, :]
-    rho_x_s = g * rho_x[None, :]
-    sigma_s = g * sigma
-    rx, rz, rzx, ry, ryx, rAx = (jnp.asarray(v)
-                                 for v in (x, z, zx, y, yx, Ax))
-    for _ in range(n_sweeps):
-        rhs = (sigma_s * rx - q + (rho_a_s * rz - ry) @ A
-               + (rho_x_s * rzx - ryx))
-        xt = C(rhs / g, Kinv, mode)
-        for _ in range(n_refine + n_extra):   # dq2 != 0: extra passes run
-            Kx = xt * diagK + ((xt @ A.T) * rho_a[None, :]) @ A
-            r = rhs - (g * Kx + dq2 * xt)
-            xt = xt + C(r / g, Kinv, mode)
-        Axt = xt @ A.T
-        x_new = alpha * xt + (1 - alpha) * rx
-        Ax_new = alpha * Axt + (1 - alpha) * rAx
-        za = alpha * Axt + (1 - alpha) * rz + ry / rho_a_s
-        z_new = jnp.clip(za, cl, cu)
-        y_new = ry + rho_a_s * (alpha * Axt + (1 - alpha) * rz - z_new)
-        zxa = alpha * xt + (1 - alpha) * rzx + ryx / rho_x_s
-        zx_new = jnp.clip(zxa, lb, ub)
-        yx_new = ryx + rho_x_s * (alpha * xt + (1 - alpha) * rzx - zx_new)
-        rx, rz, rzx, ry, ryx, rAx = (x_new, z_new, zx_new, y_new, yx_new,
-                                     Ax_new)
-
-    has = jnp.ones((1, 1))
-    outs = pallas_kernels.fused_sweeps_sparse(
-        q, sp.ell.rowcols, sp.ell.rowvals, sp.ell.colrows, sp.ell.colvals,
-        Kinv, diagK, cl, cu, lb, ub, rho_a[None, :], rho_x[None, :],
-        dq2, has, gamma, x, z, zx, y, yx, Ax,
-        n_sweeps=n_sweeps, n_refine=n_refine, n_extra=n_extra, sigma=sigma,
-        alpha=alpha, bs=8, precision=mode, interpret=True)
-    rtol, atol = ((1e-10, 1e-12) if mode == "highest" else (1e-4, 1e-5))
-    for got, ref, name in zip(outs, (rx, rz, rzx, ry, ryx, rAx),
-                              ["x", "z", "zx", "y", "yx", "Ax"]):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=rtol, atol=atol, err_msg=name)
-
-
-def test_usable_sparse_gating(monkeypatch):
-    """The sparse kernel engages only on TPU with the explicit opt-in
-    (its lane-axis gathers are unvalidated against Mosaic), small ELL
-    widths, and VMEM-fitting operands."""
-    monkeypatch.delenv("TPUSPPY_PALLAS_SPARSE", raising=False)
-    assert pallas_kernels.usable_sparse(100, 20, 10, 4, 4,
-                                        platform="cpu") is None
-    assert pallas_kernels.usable_sparse(100, 20, 10, 4, 4,
-                                        platform="tpu") is None
-    monkeypatch.setenv("TPUSPPY_PALLAS_SPARSE", "1")
-    bs = pallas_kernels.usable_sparse(100, 20, 10, 4, 4, platform="tpu")
-    assert bs == 100
-    # wide ELL rows decline (the kernel unrolls kr+kc steps per matvec)
-    assert pallas_kernels.usable_sparse(100, 20, 10, 128, 4,
-                                        platform="tpu") is None
-    # reference-scale n: the densified Kinv alone dwarfs VMEM — declines
-    assert pallas_kernels.usable_sparse(1000, 12408, 16008, 8, 8,
-                                        platform="tpu") is None
-
-
-def test_sparse_ell_roundtrip_and_scaling():
-    """SparseA carries its ELL twin through scale()/astype(); padded
-    slots stay inert zeros."""
-    import jax.numpy as jnp
-
-    from tpusppy.solvers.sparse import SparseA
-
-    rng = np.random.RandomState(5)
-    m, n = 12, 8
-    A = np.where(rng.rand(m, n) < 0.3, rng.randn(m, n), 0.0)
-    sp = SparseA.from_dense(A, jnp.float64, ell=True)
-    assert sp.ell is not None
-    E = rng.rand(m) + 0.5
-    D = rng.rand(n) + 0.5
-    sps = sp.scale(jnp.asarray(E), jnp.asarray(D))
-    As = E[:, None] * A * D[None, :]
-    # ELL row form reconstructs the scaled matrix exactly
-    dense = np.zeros((m, n))
-    rc = np.asarray(sps.ell.rowcols)
-    rv = np.asarray(sps.ell.rowvals)
-    for i in range(m):
-        for jj in range(rc.shape[1]):
-            dense[i, rc[i, jj]] += rv[i, jj]
-    np.testing.assert_allclose(dense, As, rtol=1e-12, atol=1e-14)
-    # column form too
-    dense2 = np.zeros((m, n))
-    cr = np.asarray(sps.ell.colrows)
-    cv = np.asarray(sps.ell.colvals)
-    for j in range(n):
-        for jj in range(cr.shape[1]):
-            dense2[cr[j, jj], j] += cv[j, jj]
-    np.testing.assert_allclose(dense2, As, rtol=1e-12, atol=1e-14)
-    assert sp.astype(jnp.float32).ell.rowvals.dtype == jnp.float32
-
-
 # --------------------------------------------------------------------------
 # lanes_solve: batched elimination, scenario on the lanes
 # --------------------------------------------------------------------------

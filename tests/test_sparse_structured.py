@@ -52,6 +52,34 @@ def test_sparse_matvec_ops():
     assert float(np.asarray(sp2.row_absmax())[3]) == 0.0
 
 
+def test_sparsea_pytree_keeps_its_children_under_scale_and_astype():
+    """COO triplets, the CSC permutation and the attached structure: the
+    one form of the matrix.  ``scale`` and ``astype`` touch the values only,
+    so every program traced on one accepts the others."""
+    import jax
+
+    A, *_ = _block_lp()
+    m, n = A.shape
+    rng = np.random.default_rng(2)
+    E, D = rng.random(m) + 0.5, rng.random(n) + 0.5
+    sp = SparseA.from_dense(A, jnp.float64, structure=True, min_blocks=2)
+    assert sp.structure is not None
+    children, shape = sp.tree_flatten()
+    assert len(children) == 5 and shape == (m, n)
+    scaled = sp.scale(jnp.asarray(E), jnp.asarray(D))
+    low = sp.astype(jnp.float32)
+    treedef = jax.tree_util.tree_structure(sp)
+    for other in (scaled, low, SparseA.tree_unflatten(shape, children)):
+        assert jax.tree_util.tree_structure(other) == treedef
+        assert other.rows is sp.rows and other.cols is sp.cols
+        assert other.perm_csc is sp.perm_csc
+        assert other.structure is sp.structure
+    assert low.dtype == jnp.float32 and scaled.dtype == jnp.float64
+    assert np.allclose(np.asarray(scaled.todense()),
+                       E[:, None] * A * D[None, :])
+    assert np.allclose(np.asarray(low.todense()), A, atol=1e-6)
+
+
 def test_structured_kinv_parity():
     A, *_ = _block_lp()
     rng = np.random.default_rng(1)

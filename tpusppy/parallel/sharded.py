@@ -253,11 +253,6 @@ def _gather_per_scenario(xbar_nk, nid_sk):
 def _solver_fns_for(st: ADMMSettings, mesh, axis):
     """(shared_refresh, shared_frozen, dense_refresh, dense_frozen) for one
     settings variant; dense fns are shard_mapped when on a mesh."""
-    # the fused shared-A Pallas kernel cannot ride jit auto-partitioning
-    # (a pallas_call is opaque to the partitioner): permit it only when the
-    # shared engine's program spans a single device
-    shared_pallas_ok = mesh is None or len(mesh.devices.flat) == 1
-
     def shared_refresh(q, q2, A, cl, cu, lb, ub, x, z, y, yx):
         with jax.default_matmul_precision(st.matmul_precision):
             return shared_admm._solve_shared_impl(
@@ -267,8 +262,7 @@ def _solver_fns_for(st: ADMMSettings, mesh, axis):
     def shared_frozen(q, q2, A, cl, cu, lb, ub, x, z, y, yx, factors):
         with jax.default_matmul_precision(st.matmul_precision):
             return shared_admm._solve_shared_frozen_impl(
-                q, q2, A, cl, cu, lb, ub, factors, (x, z, y, yx), st,
-                allow_pallas=shared_pallas_ok)
+                q, q2, A, cl, cu, lb, ub, factors, (x, z, y, yx), st)
 
     def local_refresh(q, q2, A, cl, cu, lb, ub, x, z, y, yx):
         with jax.default_matmul_precision(st.matmul_precision):

@@ -1,11 +1,21 @@
-"""Pallas TPU kernel: fused ADMM sweep block.
+"""Pallas TPU kernels of the dense per-scenario engine (:mod:`.admm`).
+
+Two kernels, each with the gate that sizes its block, both scenario-on-lanes:
+
+- :func:`fused_sweeps` (gate :func:`usable`, block :func:`sweep_block_size`):
+  the fused ADMM sweep block;
+- :func:`lanes_solve` (gate :func:`usable_solve`): the batched dense
+  elimination behind the refresh solve's polish.
+
+The shared-A engine (:mod:`.shared_admm`) has no kernel here: its sweeps are
+(S, n) x (n, m) MXU matmuls that XLA:TPU already fuses, in every regime.
 
 The ADMM inner loop is bandwidth-bound: every sweep re-reads the (S, n, n)
 K-inverse/K pair and the (S, m, n) constraint matrix from HBM (three to five
-matrix passes per sweep).  This kernel runs ``n_sweeps`` sweeps over a block
-of scenarios with all matrices resident in VMEM, so HBM sees each matrix once
-per kernel call instead of once per sweep — the hot-op fusion the build brief
-calls for (SURVEY §7 step 2; the XLA einsum path remains the fallback for
+matrix passes per sweep).  The sweep kernel runs ``n_sweeps`` sweeps over a
+block of scenarios with all matrices resident in VMEM, so HBM sees each matrix
+once per kernel call instead of once per sweep — the hot-op fusion the build
+brief calls for (SURVEY §7 step 2; the XLA einsum path remains the fallback for
 CPU, dense-P, and shapes that exceed the VMEM budget).
 
 All contractions are per-scenario matvecs with tiny n/m (tens), so the VPU
@@ -19,7 +29,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -331,414 +340,3 @@ def usable_solve(S, N, R, platform=None, dtype=jnp.float32) -> int | None:
         return None
     per_scen = (N * N + 2 * N * R) * 4
     return 128 if 128 * per_scen <= _VMEM_BUDGET else None
-
-
-# --------------------------------------------------------------------------
-# Fused shared-A sweep kernel (the frozen shared-engine fast path)
-# --------------------------------------------------------------------------
-#
-# The shared-A engine (solvers/shared_admm) keeps ONE (m, n) constraint
-# matrix and ONE (n, n) KKT inverse for the whole scenario batch; its sweep
-# contractions are genuine (Sb, k) @ (k, j) MXU matmuls — exactly where
-# lowered matmul precision pays (1/3/6 bf16 passes per f32 multiply-add).
-# This kernel runs a whole ``check_every`` sweep block per call with the
-# shared matrices VMEM-resident (constant index_map: Mosaic keeps revisited
-# blocks in place) and the scenario block on the SUBLANE axis, and applies
-# the precision mode with explicit bf16 operand splits — identical
-# semantics under Mosaic and the interpreter, so the CPU parity tests pin
-# it to the XLA mixed-precision sweep (solvers/precision.py emulation).
-
-
-def _prep_mat(M, mode):
-    """(M1, M2) bf16 expansion of a matrix for ``mode`` ("highest": the
-    matrix itself, no split).  Splits go THROUGH f32 — exactly the
-    rounding chain of precision.contract's emulation (and a no-op on the
-    f32 arrays real TPU runs carry), so interpret-mode parity with the
-    XLA mixed-precision path is exact up to summation order."""
-    if mode == "highest":
-        return (M, None)
-    Mf = M.astype(jnp.float32)
-    M1 = Mf.astype(jnp.bfloat16)
-    if mode == "default":
-        return (M1, None)
-    return (M1, (Mf - M1.astype(jnp.float32)).astype(jnp.bfloat16))
-
-
-def _pdot(u, Msplit, mode, dt, transpose=False):
-    """u @ M (or u @ M.T) at ``mode``; u is rounded/split per call, M is
-    pre-split by :func:`_prep_mat`."""
-    dn = (((1,), (1 if transpose else 0,)), ((), ()))
-    d = functools.partial(jax.lax.dot_general, dimension_numbers=dn,
-                          preferred_element_type=dt)
-    M1, M2 = Msplit
-    if mode == "highest":
-        return d(u, M1, precision=jax.lax.Precision.HIGHEST)
-    uf = u.astype(jnp.float32)
-    u1 = uf.astype(jnp.bfloat16)
-    if mode == "default":
-        return d(u1, M1)
-    u2 = (uf - u1.astype(jnp.float32)).astype(jnp.bfloat16)
-    return d(u1, M1) + d(u1, M2) + d(u2, M1)
-
-
-def _shared_sweeps_kernel(q_ref, A_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
-                          lb_ref, ub_ref, rho_a_ref, rho_x_ref, dq2_ref,
-                          has_ref, gamma_ref, x_ref, z_ref, zx_ref, y_ref,
-                          yx_ref, Ax_ref, x_out, z_out, zx_out, y_out,
-                          yx_out, Ax_out, *, n_sweeps, n_refine, n_extra,
-                          sigma, alpha, precision):
-    """One ``n_sweeps`` block of the shared-A frozen sweep (the exact
-    semantics of ``shared_admm._core``'s block(): per-scenario gamma
-    scaling, dq2 refinement against the exact f32 K with the lax.cond
-    extra passes reproduced as a global-``has`` select)."""
-    dt = K_ref.dtype
-    A = _prep_mat(A_ref[:], precision)          # (m, n)
-    Kinv = _prep_mat(Kinv_ref[:], precision)    # (n, n)
-    K = K_ref[:]                                # exact, defect operand
-    q = q_ref[:]                                # (Sb, n)
-    cl, cu, lb, ub = cl_ref[:], cu_ref[:], lb_ref[:], ub_ref[:]
-    g = gamma_ref[:]                            # (Sb, 1)
-    has = has_ref[0, 0]                         # global any(dq2 != 0)
-    dq2 = dq2_ref[:]                            # (Sb, n)
-    sigma_s = g * sigma
-    rho_a_s = g * rho_a_ref[:]                  # (Sb, m)
-    rho_x_s = g * rho_x_ref[:]                  # (Sb, n)
-    x, z, zx, y, yx, Ax = (x_ref[:], z_ref[:], zx_ref[:], y_ref[:],
-                           yx_ref[:], Ax_ref[:])
-
-    def kdefect(rhs, xt):
-        # exact per-scenario system defect at full f32 (the refinement's
-        # accuracy anchor — never lowered)
-        Kx = jax.lax.dot_general(
-            xt, K, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST, preferred_element_type=dt)
-        return rhs - (g * Kx + dq2 * xt)
-
-    def body(_, carry):
-        x, z, zx, y, yx, Ax = carry
-        rhs = (sigma_s * x - q + _pdot(rho_a_s * z - y, A, precision, dt)
-               + (rho_x_s * zx - yx))
-        xt = _pdot(rhs / g, Kinv, precision, dt)
-        for _ in range(n_refine):
-            xt = xt + _pdot(kdefect(rhs, xt) / g, Kinv, precision, dt)
-        for _ in range(n_extra):
-            xt2 = xt + _pdot(kdefect(rhs, xt) / g, Kinv, precision, dt)
-            xt = jnp.where(has > 0, xt2, xt)
-        Axt = _pdot(xt, A, precision, dt, transpose=True)
-        x_new = alpha * xt + (1 - alpha) * x
-        Ax_new = alpha * Axt + (1 - alpha) * Ax
-
-        za_arg = alpha * Axt + (1 - alpha) * z + y / rho_a_s
-        z_new = jnp.clip(za_arg, cl, cu)
-        y_new = y + rho_a_s * (alpha * Axt + (1 - alpha) * z - z_new)
-
-        zx_arg = alpha * xt + (1 - alpha) * zx + yx / rho_x_s
-        zx_new = jnp.clip(zx_arg, lb, ub)
-        yx_new = yx + rho_x_s * (alpha * xt + (1 - alpha) * zx - zx_new)
-        return x_new, z_new, zx_new, y_new, yx_new, Ax_new
-
-    x, z, zx, y, yx, Ax = jax.lax.fori_loop(
-        0, n_sweeps, body, (x, z, zx, y, yx, Ax))
-    x_out[:] = x
-    z_out[:] = z
-    zx_out[:] = zx
-    y_out[:] = y
-    yx_out[:] = yx
-    Ax_out[:] = Ax
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_sweeps", "n_refine", "n_extra",
-                                    "sigma", "alpha", "bs", "precision",
-                                    "interpret"))
-def fused_sweeps_shared(q, A, Kinv, K, cl, cu, lb, ub, rho_a, rho_x, dq2,
-                        has_dq2, gamma, x, z, zx, y, yx, Ax, n_sweeps,
-                        n_refine, n_extra, sigma, alpha, bs,
-                        precision="highest", interpret=False):
-    """``n_sweeps`` shared-A frozen sweeps per call, scenario-blocked on
-    the sublane axis.  Shapes: A/Kinv/K shared ((m,n)/(n,n)/(n,n)); rho_a
-    (1, m), rho_x (1, n); per-scenario state/bounds (S, m)/(S, n); gamma
-    (S, 1); dq2 (S, n); has_dq2 (1, 1) — the traced global
-    ``any(dq2 != 0)`` flag that reproduces the XLA path's lax.cond.
-    Returns (x, z, zx, y, yx, Ax)."""
-    S, n = q.shape
-    m = cl.shape[1]
-    grid = ((S + bs - 1) // bs,)
-
-    def shared2(d0, d1):
-        return pl.BlockSpec((d0, d1), lambda i: (0, 0),
-                            memory_space=pltpu.VMEM)
-
-    def scen(d1):
-        return pl.BlockSpec((bs, d1), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-
-    kern = functools.partial(_shared_sweeps_kernel, n_sweeps=n_sweeps,
-                             n_refine=n_refine, n_extra=n_extra,
-                             sigma=sigma, alpha=alpha, precision=precision)
-    dt = K.dtype
-    out_shape = [
-        jax.ShapeDtypeStruct((S, n), dt),   # x
-        jax.ShapeDtypeStruct((S, m), dt),   # z
-        jax.ShapeDtypeStruct((S, n), dt),   # zx
-        jax.ShapeDtypeStruct((S, m), dt),   # y
-        jax.ShapeDtypeStruct((S, n), dt),   # yx
-        jax.ShapeDtypeStruct((S, m), dt),   # Ax
-    ]
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            scen(n),             # q
-            shared2(m, n),       # A
-            shared2(n, n),       # Kinv
-            shared2(n, n),       # K
-            scen(m), scen(m),    # cl cu
-            scen(n), scen(n),    # lb ub
-            shared2(1, m),       # rho_a
-            shared2(1, n),       # rho_x
-            scen(n),             # dq2
-            shared2(1, 1),       # has_dq2
-            scen(1),             # gamma
-            scen(n), scen(m), scen(n), scen(m), scen(n),  # x z zx y yx
-            scen(m),             # Ax
-        ],
-        out_specs=[scen(n), scen(m), scen(n), scen(m), scen(n), scen(m)],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(q, A, Kinv, K, cl, cu, lb, ub, rho_a, rho_x, dq2, has_dq2, gamma,
-      x, z, zx, y, yx, Ax)
-
-
-# --------------------------------------------------------------------------
-# Fused SPARSE/structured-KKT shared-A sweep kernel
-# --------------------------------------------------------------------------
-#
-# Extends the fused-sweep coverage to the SparseA engines (gather/
-# segment-sum matvecs, dense-Kinv or block/Woodbury x-update) so those
-# paths can participate in the fused body (megastep scans included).  The
-# constraint matvecs run in padded-ELL form (:class:`~tpusppy.solvers.
-# sparse.EllA`): kr/kc static multiply-accumulate steps per matvec, each a
-# full-width gather of the scenario block — matching the XLA engine's
-# "sparse matvecs are exact VPU work" contract (only the Kinv applies run
-# at the lowered precision mode; the refinement defect is matrix-free
-# through the ELL arrays at full precision, exactly the
-# ``shared_admm._solve_shared_K`` split).  The structured-KKT engine
-# participates through a DENSIFIED (n, n) K^-1 operand: at kernel-eligible
-# sizes (the shared matrices must fit VMEM) the BlockWoodbury memory
-# saving is irrelevant, so the caller materializes ``kinv_apply(bw, I)``
-# once per refresh and the kernel stays one code path.
-
-
-def _ell_mv(cols, vals, x, k):
-    """A x in ELL row form: out[:, i] = sum_j vals[i, j] * x[:, cols[i, j]]
-    (k static; padded slots are col 0 / val 0 — inert)."""
-    acc = jnp.take(x, cols[:, 0], axis=1) * vals[:, 0][None, :]
-    for j in range(1, k):
-        acc = acc + jnp.take(x, cols[:, j], axis=1) * vals[:, j][None, :]
-    return acc
-
-
-def _sparse_sweeps_kernel(q_ref, rc_ref, rv_ref, cr_ref, cv_ref, Kinv_ref,
-                          diagK_ref, cl_ref, cu_ref, lb_ref, ub_ref,
-                          rho_a_ref, rho_x_ref, dq2_ref, has_ref,
-                          gamma_ref, x_ref, z_ref, zx_ref, y_ref, yx_ref,
-                          Ax_ref, x_out, z_out, zx_out, y_out, yx_out,
-                          Ax_out, *, n_sweeps, n_refine, n_extra, sigma,
-                          alpha, precision):
-    """One ``n_sweeps`` block of the sparse shared-A frozen sweep — the
-    exact semantics of ``shared_admm._core``'s block() on a SparseA:
-    per-scenario gamma scaling, EXACT ELL matvecs, lowered Kinv applies,
-    matrix-free dq2 refinement defect with the lax.cond extra passes
-    reproduced as a global-``has`` select."""
-    dt = Kinv_ref.dtype
-    rc, rv = rc_ref[:], rv_ref[:]           # (m, kr)
-    cr, cv = cr_ref[:], cv_ref[:]           # (n, kc)
-    kr, kc = rc.shape[1], cr.shape[1]
-    Kinv = _prep_mat(Kinv_ref[:], precision)
-    diagK = diagK_ref[:]                    # (1, n)
-    q = q_ref[:]
-    cl, cu, lb, ub = cl_ref[:], cu_ref[:], lb_ref[:], ub_ref[:]
-    g = gamma_ref[:]                        # (Sb, 1)
-    has = has_ref[0, 0]
-    dq2 = dq2_ref[:]
-    rho_a = rho_a_ref[:]                    # (1, m) shared, unscaled
-    rho_a_s = g * rho_a
-    rho_x_s = g * rho_x_ref[:]
-    sigma_s = g * sigma
-    x, z, zx, y, yx, Ax = (x_ref[:], z_ref[:], zx_ref[:], y_ref[:],
-                           yx_ref[:], Ax_ref[:])
-
-    def mv(v):                              # A v: (Sb, n) -> (Sb, m)
-        return _ell_mv(rc, rv, v, kr)
-
-    def rmv(v):                             # A' v: (Sb, m) -> (Sb, n)
-        return _ell_mv(cr, cv, v, kc)
-
-    def kdefect(rhs, xt):
-        # exact per-scenario system defect, matrix-free through the ELL
-        # arrays at full precision (the refinement's accuracy anchor)
-        Kx = xt * diagK + rmv(mv(xt) * rho_a)
-        return rhs - (g * Kx + dq2 * xt)
-
-    def body(_, carry):
-        x, z, zx, y, yx, Ax = carry
-        rhs = (sigma_s * x - q + rmv(rho_a_s * z - y)
-               + (rho_x_s * zx - yx))
-        xt = _pdot(rhs / g, Kinv, precision, dt)
-        for _ in range(n_refine):
-            xt = xt + _pdot(kdefect(rhs, xt) / g, Kinv, precision, dt)
-        for _ in range(n_extra):
-            xt2 = xt + _pdot(kdefect(rhs, xt) / g, Kinv, precision, dt)
-            xt = jnp.where(has > 0, xt2, xt)
-        Axt = mv(xt)
-        x_new = alpha * xt + (1 - alpha) * x
-        Ax_new = alpha * Axt + (1 - alpha) * Ax
-
-        za_arg = alpha * Axt + (1 - alpha) * z + y / rho_a_s
-        z_new = jnp.clip(za_arg, cl, cu)
-        y_new = y + rho_a_s * (alpha * Axt + (1 - alpha) * z - z_new)
-
-        zx_arg = alpha * xt + (1 - alpha) * zx + yx / rho_x_s
-        zx_new = jnp.clip(zx_arg, lb, ub)
-        yx_new = yx + rho_x_s * (alpha * xt + (1 - alpha) * zx - zx_new)
-        return x_new, z_new, zx_new, y_new, yx_new, Ax_new
-
-    x, z, zx, y, yx, Ax = jax.lax.fori_loop(
-        0, n_sweeps, body, (x, z, zx, y, yx, Ax))
-    x_out[:] = x
-    z_out[:] = z
-    zx_out[:] = zx
-    y_out[:] = y
-    yx_out[:] = yx
-    Ax_out[:] = Ax
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_sweeps", "n_refine", "n_extra",
-                                    "sigma", "alpha", "bs", "precision",
-                                    "interpret"))
-def fused_sweeps_sparse(q, rowcols, rowvals, colrows, colvals, Kinv, diagK,
-                        cl, cu, lb, ub, rho_a, rho_x, dq2, has_dq2, gamma,
-                        x, z, zx, y, yx, Ax, n_sweeps, n_refine, n_extra,
-                        sigma, alpha, bs, precision="highest",
-                        interpret=False):
-    """``n_sweeps`` sparse shared-A frozen sweeps per call, scenario-
-    blocked on the sublane axis.  Shapes: ELL arrays (m, kr)/(n, kc)
-    shared; ``Kinv`` (n, n) — the dense shared inverse, or the densified
-    BlockWoodbury apply for the structured-KKT engine; ``diagK`` (1, n) =
-    q2ref + rho_x + sigma (the matrix-free defect diagonal); ``rho_a``
-    (1, m) UNSCALED shared row penalties; everything else as
-    :func:`fused_sweeps_shared`.  Returns (x, z, zx, y, yx, Ax)."""
-    S, n = q.shape
-    m = cl.shape[1]
-    kr = rowcols.shape[1]
-    kc = colrows.shape[1]
-    grid = ((S + bs - 1) // bs,)
-
-    def shared2(d0, d1):
-        return pl.BlockSpec((d0, d1), lambda i: (0, 0),
-                            memory_space=pltpu.VMEM)
-
-    def scen(d1):
-        return pl.BlockSpec((bs, d1), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-
-    kern = functools.partial(_sparse_sweeps_kernel, n_sweeps=n_sweeps,
-                             n_refine=n_refine, n_extra=n_extra,
-                             sigma=sigma, alpha=alpha, precision=precision)
-    dt = Kinv.dtype
-    out_shape = [
-        jax.ShapeDtypeStruct((S, n), dt),   # x
-        jax.ShapeDtypeStruct((S, m), dt),   # z
-        jax.ShapeDtypeStruct((S, n), dt),   # zx
-        jax.ShapeDtypeStruct((S, m), dt),   # y
-        jax.ShapeDtypeStruct((S, n), dt),   # yx
-        jax.ShapeDtypeStruct((S, m), dt),   # Ax
-    ]
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            scen(n),                  # q
-            shared2(m, kr), shared2(m, kr),   # rowcols rowvals
-            shared2(n, kc), shared2(n, kc),   # colrows colvals
-            shared2(n, n),            # Kinv
-            shared2(1, n),            # diagK
-            scen(m), scen(m),         # cl cu
-            scen(n), scen(n),         # lb ub
-            shared2(1, m),            # rho_a
-            shared2(1, n),            # rho_x
-            scen(n),                  # dq2
-            shared2(1, 1),            # has_dq2
-            scen(1),                  # gamma
-            scen(n), scen(m), scen(n), scen(m), scen(n),  # x z zx y yx
-            scen(m),                  # Ax
-        ],
-        out_specs=[scen(n), scen(m), scen(n), scen(m), scen(n), scen(m)],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(q, rowcols, rowvals, colrows, colvals, Kinv, diagK, cl, cu, lb, ub,
-      rho_a, rho_x, dq2, has_dq2, gamma, x, z, zx, y, yx, Ax)
-
-
-def sparse_kernel_possible(platform=None) -> bool:
-    """Could :func:`fused_sweeps_sparse` EVER engage in this process:
-    TPU backend + the experimental
-    ``TPUSPPY_PALLAS_SPARSE=1`` opt-in.  The ONE engagement gate —
-    ``SparseA.from_dense``'s ``ell="auto"`` asks it before paying for the
-    ELL twin build, and :func:`usable_sparse` layers the per-shape VMEM
-    budget on top."""
-    import os
-
-    platform = platform or jax.default_backend()
-    return (platform == "tpu"
-            and os.environ.get("TPUSPPY_PALLAS_SPARSE") == "1")
-
-
-def usable_sparse(S, m, n, kr, kc, platform=None, itemsize=4) -> int | None:
-    """Scenario block size if the fused sparse kernel applies, else None.
-
-    EXPERIMENTAL on real TPU: the ELL matvec's lane-axis gathers
-    (``jnp.take`` inside the kernel) are not validated against every
-    Mosaic version, so the kernel additionally requires the
-    ``TPUSPPY_PALLAS_SPARSE=1`` opt-in there; interpret-mode tests pin
-    the semantics platform-independently.  The shared operands (densified
-    Kinv + ELL arrays) must fit VMEM alongside one scenario block."""
-    if not sparse_kernel_possible(platform):
-        return None
-    from .sparse import ELL_MAX_K
-    if max(kr, kc) > ELL_MAX_K:
-        return None
-    mat = n * n * itemsize + (m * kr + n * kc) * (itemsize + 4) \
-        + n * itemsize
-    if mat > _VMEM_BUDGET // 2:
-        return None
-    per_scen = (8 * n + 6 * m + 2) * itemsize
-    bs = (_VMEM_BUDGET - mat) // max(per_scen, 1)
-    if bs >= S:
-        return int(S)
-    bs = (bs // 8) * 8
-    return int(bs) if bs >= 8 else None
-
-
-def usable_shared(S, m, n, platform=None, itemsize=4) -> int | None:
-    """Scenario block size if the fused shared-A kernel applies, else None.
-
-    The shared matrices (A + Kinv + K) must fit VMEM alongside one
-    scenario block's state; the block rides the SUBLANE axis (multiples
-    of 8 for f32).  Reference-scale UC (n=16008) exceeds the matrix
-    budget by orders of magnitude and correctly declines — the kernel is
-    the small/medium-n shared-family fast path."""
-    platform = platform or jax.default_backend()
-    if platform != "tpu":
-        return None
-    mat = (m * n + 2 * n * n) * itemsize
-    if mat > _VMEM_BUDGET // 2:
-        return None
-    per_scen = (6 * n + 6 * m + 2) * itemsize
-    bs = (_VMEM_BUDGET - mat) // max(per_scen, 1)
-    if bs >= S:
-        return int(S)
-    bs = (bs // 8) * 8
-    return int(bs) if bs >= 8 else None

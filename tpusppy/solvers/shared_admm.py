@@ -49,8 +49,8 @@ from .admm import (ADMMSettings, BatchSolution, BIG, _clean_bounds,
                    _plateau_update)
 from .sparse import SparseA
 from .structured_kkt import (apply_kinv_like, factor_lowrank,
-                             factor_structured, is_dense_kinv,
-                             lowrank_kinv, zero_factors, zero_lowrank)
+                             factor_structured, lowrank_kinv, zero_factors,
+                             zero_lowrank)
 
 
 def _mv(A, x):
@@ -206,8 +206,7 @@ class _IterState(NamedTuple):
 
 
 def _core(q, q2s, q2ref, A, cl, cu, lb, ub, state, Kinv, K, rho_a, rho_x,
-          glo, ghi, st: ADMMSettings, adaptive=False, prec=None,
-          allow_pallas=False):
+          glo, ghi, st: ADMMSettings, adaptive=False, prec=None):
     """Inner ADMM sweep at a fixed shared rho profile with IN-LOOP
     per-scenario gamma adaptation.
 
@@ -222,10 +221,7 @@ def _core(q, q2s, q2ref, A, cl, cu, lb, ub, state, Kinv, K, rho_a, rho_x,
 
     ``prec``: None keeps the legacy program; a mode string runs the sweep
     matvecs at lowered matmul precision with defect/residual bookkeeping
-    pinned at full f32 (solvers/precision.py).  ``allow_pallas``: permit
-    the fused shared-A Pallas sweep kernel (frozen path only; callers on
-    a multi-device auto-partitioned mesh must pass False — a pallas_call
-    cannot be auto-partitioned).
+    pinned at full f32 (solvers/precision.py).
     """
     sparse = isinstance(A, SparseA)
     if prec is None or sparse:
@@ -261,72 +257,12 @@ def _core(q, q2s, q2ref, A, cl, cu, lb, ub, state, Kinv, K, rho_a, rho_x,
                           + rmv_hi(A, mv_hi(A, x) * rho_a[None, :]))
     alpha = st.alpha
 
-    # fused shared-A Pallas sweep kernel (frozen path): the whole
-    # check_every block runs with A/Kinv/K VMEM-resident and genuine MXU
-    # dot_generals at the sweep precision — see pallas_kernels
-    from . import pallas_kernels
-    from .structured_kkt import BlockWoodbury, kinv_apply
-    bs_sh = None
-    if (allow_pallas and not adaptive and not sparse and K is not None
-            and is_dense_kinv(Kinv) and st.use_pallas is not False):
-        S_all, n_all = q.shape
-        bs_sh = pallas_kernels.usable_shared(S_all, A.shape[0], n_all)
-    # sparse/structured engines: fused ELL sweep kernel (frozen path).
-    # The structured BlockWoodbury operator participates via a densified
-    # (n, n) K^-1 built ONCE per program — at kernel-eligible sizes the
-    # shared matrices must fit VMEM anyway, so the structured memory
-    # saving is moot and one kernel covers both engines.
-    bs_sp = None
-    Kinv_dense = diagK_sp = None
-    if (allow_pallas and not adaptive and sparse
-            and st.use_pallas is not False
-            and getattr(A, "ell", None) is not None):
-        S_all, n_all = q.shape
-        bs_sp = pallas_kernels.usable_sparse(
-            S_all, A.shape[0], n_all, A.ell.rowcols.shape[1],
-            A.ell.colrows.shape[1])
-        if bs_sp is not None:
-            # NOTE: the densification sits outside the sweep while_loop
-            # but INSIDE the solve program, so it re-runs once per
-            # dispatch (n Woodbury applies) even though Kinv only changes
-            # at refresh — acceptable while the kernel is the
-            # TPUSPPY_PALLAS_SPARSE opt-in (n is VMEM-small there);
-            # promoting the dense twin into SharedFactors is the fix if
-            # this path graduates to default-on.
-            Kinv_dense = (kinv_apply(Kinv, jnp.eye(n_all, dtype=q.dtype))
-                          if isinstance(Kinv, BlockWoodbury) else Kinv)
-            diagK_sp = (q2ref + rho_x + st.sigma)[None, :]
-    kernel_prec = "highest" if prec is None else prec
-
     def block(x, z, zx, y, yx, Ax, gamma):
         g = gamma[:, None]
         sigma_s = g * st.sigma           # (S, 1): scaled prox parameter
         rho_a_s = g * rho_a[None, :]     # (S, m)
         rho_x_s = g * rho_x[None, :]     # (S, n)
         dq2 = q2s - g * q2ref[None, :]
-
-        if bs_sp is not None:
-            has = jnp.any(dq2 != 0).astype(x.dtype).reshape(1, 1)
-            return pallas_kernels.fused_sweeps_sparse(
-                q, A.ell.rowcols, A.ell.rowvals, A.ell.colrows,
-                A.ell.colvals, Kinv_dense, diagK_sp, cl, cu, lb, ub,
-                rho_a[None, :], rho_x[None, :], dq2, has, g,
-                x, z, zx, y, yx, Ax,
-                n_sweeps=max(1, st.check_every),
-                n_refine=st.solve_refine, n_extra=2,
-                sigma=float(st.sigma), alpha=float(alpha), bs=bs_sp,
-                precision=kernel_prec)
-
-        if bs_sh is not None:
-            has = jnp.any(dq2 != 0).astype(x.dtype).reshape(1, 1)
-            return pallas_kernels.fused_sweeps_shared(
-                q, A, Kinv, K, cl, cu, lb, ub,
-                rho_a[None, :], rho_x[None, :], dq2, has, g,
-                x, z, zx, y, yx, Ax,
-                n_sweeps=max(1, st.check_every),
-                n_refine=st.solve_refine, n_extra=2,
-                sigma=float(st.sigma), alpha=float(alpha), bs=bs_sh,
-                precision=kernel_prec)
 
         for _ in range(max(1, st.check_every)):
             rhs = (sigma_s * x - q + rmv_lo(A, rho_a_s * z - y)
@@ -681,8 +617,7 @@ def _solve_shared_impl(c, q2, A, cl, cu, lb, ub, settings, warm,
 
 
 def _solve_shared_frozen_impl(c, q2, A, cl, cu, lb, ub,
-                              factors: SharedFactors, warm, settings,
-                              allow_pallas=False):
+                              factors: SharedFactors, warm, settings):
     """Sweep-only shared solve reusing a refresh's :class:`SharedFactors`.
     Valid while (A, bounds structure) are unchanged; per-scenario q2 drift is
     absorbed by the refinement against K + diag(dq2).
@@ -690,9 +625,7 @@ def _solve_shared_frozen_impl(c, q2, A, cl, cu, lb, ub,
     ``settings.sweep_precision`` routes this solve through the
     mixed-precision fast path: a lowered-precision sweep phase (f32-pinned
     residual bookkeeping) followed, when not eps-converged, by a bounded
-    full-precision refinement phase on the same factors.  ``allow_pallas``
-    permits the fused shared-A Pallas kernel (single-controller callers
-    only — a pallas_call cannot be auto-partitioned over a mesh)."""
+    full-precision refinement phase on the same factors."""
     # TRACE-time counter: one per frozen shared-A program compiled
     _metrics.inc("shared_admm.frozen_programs")
     dt = settings.jdtype()
@@ -724,8 +657,7 @@ def _solve_shared_frozen_impl(c, q2, A, cl, cu, lb, ub,
     def run_core(st0, st, prec):
         return _core(qs, q2s, factors.q2ref, As, cls, cus, lbs, ubs, st0,
                      factors.Kinv, factors.K, factors.rho_a,
-                     factors.rho_x, glo, ghi, st, prec=prec,
-                     allow_pallas=allow_pallas)
+                     factors.rho_x, glo, ghi, st, prec=prec)
 
     state = _frozen_sweep_phases(run_core, state0, settings, dt)
     x, z, y, yx = (state.x * D[None, :], state.z / E[None, :],
@@ -775,11 +707,10 @@ solve_shared_factored = _aot.cached_program(
 def solve_shared_frozen(c, q2, A, cl, cu, lb, ub, factors: SharedFactors,
                         settings: ADMMSettings = ADMMSettings(),
                         warm=None) -> BatchSolution:
-    """Jitted frozen-factor shared-A solve (single-controller host path:
-    the fused shared-A Pallas kernel is permitted)."""
+    """Jitted frozen-factor shared-A solve."""
     with jax.default_matmul_precision(settings.matmul_precision):
         return _solve_shared_frozen_impl(c, q2, A, cl, cu, lb, ub, factors,
-                                         warm, settings, allow_pallas=True)
+                                         warm, settings)
 
 
 solve_shared_frozen = _aot.cached_program(

@@ -38,66 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-class EllA(NamedTuple):
-    """Padded-ELL twin of a :class:`SparseA` — the Pallas-friendly layout.
-
-    Row form (the forward matvec): ``rowcols``/``rowvals`` are (m, kr)
-    with each row's nonzero column ids/values left-packed; padding slots
-    carry column 0 with value 0 (inert in the multiply-accumulate).
-    Column form (the transpose matvec): ``colrows``/``colvals`` are
-    (n, kc) likewise.  kr/kc are the max per-row/per-column nonzero
-    counts — the fused sparse sweep kernel
-    (:func:`tpusppy.solvers.pallas_kernels.fused_sweeps_sparse`) loops
-    them as static trace-time constants, so the build gate
-    (:data:`ELL_MAX_K`) keeps them small."""
-
-    rowcols: jax.Array   # (m, kr) int32
-    rowvals: jax.Array   # (m, kr)
-    colrows: jax.Array   # (n, kc) int32
-    colvals: jax.Array   # (n, kc)
-
-
-# per-row/per-column nonzero cap for building the ELL twin: the fused
-# sparse kernel unrolls kr + kc multiply-accumulate steps per matvec, so
-# wide rows (reference-UC power balance spans hundreds of columns) must
-# decline — those families keep the gather/segment-sum XLA path
-ELL_MAX_K = 64
-
-
-def _build_ell(rows, cols, vals, m, n, max_k=ELL_MAX_K):
-    """Host-side ELL construction from COO (None when a row or column
-    exceeds ``max_k`` nonzeros).  Fully vectorized — the TPU opt-in
-    shapes this feeds have 1e5+ nonzeros, where a per-nonzero Python
-    loop would cost seconds per build."""
-    row_counts = np.bincount(rows, minlength=m)
-    col_counts = np.bincount(cols, minlength=n)
-    kr = int(row_counts.max()) if rows.size else 1
-    kc = int(col_counts.max()) if cols.size else 1
-    if kr > max_k or kc > max_k:
-        return None
-    kr, kc = max(kr, 1), max(kc, 1)
-
-    def pack(keys, others, vals_, counts, rows_out, k):
-        """Left-pack (keys -> slots) via a stable sort: slot index =
-        position within the key's sorted run."""
-        order = np.argsort(keys, kind="stable")
-        ks, os_, vs = keys[order], others[order], vals_[order]
-        starts = np.zeros(counts.size + 1, np.int64)
-        np.cumsum(counts, out=starts[1:])
-        slot = np.arange(ks.size) - starts[ks]
-        idx_out = np.zeros((rows_out, k), np.int32)
-        val_out = np.zeros((rows_out, k))
-        idx_out[ks, slot] = os_
-        val_out[ks, slot] = vs
-        return idx_out, val_out
-
-    rowcols, rowvals = pack(np.asarray(rows), np.asarray(cols),
-                            np.asarray(vals), row_counts, m, kr)
-    colrows, colvals = pack(np.asarray(cols), np.asarray(rows),
-                            np.asarray(vals), col_counts, n, kc)
-    return rowcols, rowvals, colrows, colvals
-
-
 @jax.tree_util.register_pytree_node_class
 class SparseA:
     """Shared (m, n) sparse matrix, batched-matvec ready, jit-compatible.
@@ -105,12 +45,9 @@ class SparseA:
     Arrays (pytree children): COO triplets sorted in CSR order plus a
     CSC-order permutation for the transpose matvec.  ``shape`` is static
     aux data (participates in the jit cache key, never traced).
-    ``ell`` optionally carries the padded-ELL twin (:class:`EllA`) for
-    the fused sparse Pallas sweep kernel.
     """
 
-    def __init__(self, rows, cols, vals, perm_csc, shape, structure=None,
-                 ell=None):
+    def __init__(self, rows, cols, vals, perm_csc, shape, structure=None):
         self.rows = rows
         self.cols = cols
         self.vals = vals
@@ -120,33 +57,23 @@ class SparseA:
         # block/Woodbury split of this matrix's KKT system, attached at
         # build time so jitted factor programs can use it
         self.structure = structure
-        self.ell = ell
 
     # -- pytree protocol --------------------------------------------------
     def tree_flatten(self):
         return ((self.rows, self.cols, self.vals, self.perm_csc,
-                 self.structure, self.ell), self.shape)
+                 self.structure), self.shape)
 
     @classmethod
     def tree_unflatten(cls, shape, children):
-        rows, cols, vals, perm_csc, structure, ell = children
-        return cls(rows, cols, vals, perm_csc, shape, structure, ell)
+        rows, cols, vals, perm_csc, structure = children
+        return cls(rows, cols, vals, perm_csc, shape, structure)
 
     # -- construction -----------------------------------------------------
     @classmethod
-    def from_dense(cls, A, dtype=None, structure: bool = False,
-                   ell: bool | str = "auto", **detect_kw):
+    def from_dense(cls, A, dtype=None, structure: bool = False, **detect_kw):
         """Build from a dense ndarray; ``structure=True`` additionally
         runs :func:`detect_structure` and attaches the device-side index
-        arrays when a usable block/Woodbury split exists.
-
-        ``ell``: build the padded-ELL twin for the fused sparse Pallas
-        kernel.  "auto" (default) builds it only where the kernel could
-        ever engage (``pallas_kernels.sparse_kernel_possible``: Pallas +
-        TPU backend + the ``TPUSPPY_PALLAS_SPARSE=1`` opt-in): the twin
-        costs two O(nnz) host passes plus a second device copy of the
-        values, pure waste on paths that can never use it.  True forces
-        the build (interpret-mode tests); False never builds."""
+        arrays when a usable block/Woodbury split exists."""
         A = np.asarray(A)
         m, n = A.shape
         rows, cols = np.nonzero(A)
@@ -166,20 +93,10 @@ class SparseA:
                     # instead of warning on every upload in non-x64
                     # processes
                     else jnp.asarray(vals))
-        if ell == "auto":
-            from . import pallas_kernels
-
-            ell = pallas_kernels.sparse_kernel_possible()
-        ell_dev = None
-        built = _build_ell(rows, cols, vals, m, n) if ell else None
-        if built is not None:
-            rc, rv, cr, cv = built
-            ell_dev = EllA(jnp.asarray(rc), jnp.asarray(rv, vals_dev.dtype),
-                           jnp.asarray(cr), jnp.asarray(cv, vals_dev.dtype))
         return cls(jnp.asarray(rows, jnp.int32),
                    jnp.asarray(cols, jnp.int32),
                    vals_dev,
-                   jnp.asarray(perm_csc), (m, n), struct_arrays, ell_dev)
+                   jnp.asarray(perm_csc), (m, n), struct_arrays)
 
     @property
     def nnz(self):
@@ -196,27 +113,16 @@ class SparseA:
         return self.vals.dtype
 
     def astype(self, dt):
-        ell = None
-        if self.ell is not None:
-            ell = EllA(self.ell.rowcols, self.ell.rowvals.astype(dt),
-                       self.ell.colrows, self.ell.colvals.astype(dt))
         return SparseA(self.rows, self.cols, self.vals.astype(dt),
-                       self.perm_csc, self.shape, self.structure, ell)
+                       self.perm_csc, self.shape, self.structure)
 
     def scale(self, E, D):
         """diag(E) @ A @ diag(D) — the Ruiz application; zero-copy on the
         index arrays (the attached structure is sparsity-pattern-only and
-        survives scaling; the ELL twin scales its padded values — inert
-        zero slots stay zero)."""
+        survives scaling)."""
         vals = self.vals * E[self.rows] * D[self.cols]
-        ell = None
-        if self.ell is not None:
-            ell = EllA(self.ell.rowcols,
-                       self.ell.rowvals * E[:, None] * D[self.ell.rowcols],
-                       self.ell.colrows,
-                       self.ell.colvals * E[self.ell.colrows] * D[:, None])
         return SparseA(self.rows, self.cols, vals, self.perm_csc,
-                       self.shape, self.structure, ell)
+                       self.shape, self.structure)
 
     # -- matvecs ----------------------------------------------------------
     def matvec(self, x):
