@@ -13,7 +13,16 @@ solve spends its whole budget (``max_iter`` sweeps): its time over its
 sweeps is the time of one sweep.  Two objectives: the Lagrangian spoke's
 (an LP, ``dq2 = 0``: three applies a sweep) and the hub's (prox rho 1 on the
 nonants: five).  At the benchmark's own size the adaptive solve
-(``solve_shared_factored``, the regime the rule picks) is timed too.
+(``solve_shared_factored``, the regime the rule picks) is timed too, and
+given per sweep like the frozen one: run on two commits side by side, the
+adaptive column says what the refinement's ``K x`` costs a sweep in the
+rule's regime (a dense ``K`` product or two thin ones: PERF.md section 6,
+PR 35), the frozen ``lowrank`` column the same for a wheel's frozen
+solves.  One row whose penalty scale gamma has left 1
+makes ``dq2 != 0`` and the whole batch pays two extra refinement passes a
+sweep: ``factored_gamma_moved`` counts such rows after the adaptive solve,
+and the prox problem's frozen solve is timed with gamma set to 1 in every
+row and with one row at 1.25 as well (gamma still adapts inside a solve).
 
 Each is timed warm, ``block_until_ready``, median of ``--reps``.
 
@@ -100,10 +109,20 @@ def main():
         for name, prob in (("lagrangian_W0", lagr), ("hub_prox", hub)):
             sol, f = shared_admm.solve_shared_factored(*prob, settings=st)
             if (servers, clients) == SIZES[0]:
-                row[f"{name}.factored_ms"] = median_ms(
-                    lambda: shared_admm.solve_shared_factored(
-                        *prob, settings=st, warm=sol.raw)[0], args.reps)
+                run = lambda: shared_admm.solve_shared_factored(
+                    *prob, settings=st, warm=sol.raw)[0]
+                ms = median_ms(run, args.reps)
+                sweeps = max(int(run().iters[0]), 1)   # over all restarts
+                row[f"{name}.factored_ms"] = ms
+                row[f"{name}.factored_sweeps"] = sweeps
+                row[f"{name}.factored_us_per_sweep"] = 1e3 * ms / sweeps
                 row[f"{name}.factored_regime"] = type(f.Kinv).__name__
+                row[f"{name}.factored_keeps_K"] = f.K is not None
+                # rows whose penalty scale the solve moved: one is enough
+                # for dq2 != 0, and so for the two extra refinement passes
+                # of every sweep of the whole batch (_solve_shared_K)
+                row[f"{name}.factored_gamma_moved"] = int(
+                    jnp.sum(f.gamma != 1))
             with jax.default_matmul_precision(st.matmul_precision):
                 As = A * f.E[:, None] * f.D[None, :]
                 d = f.q2ref + f.rho_x + st.sigma
@@ -113,8 +132,16 @@ def main():
                     "lowrank": structured_kkt.factor_lowrank(
                         As, d, f.rho_a)}
             xs = {}
-            for tag, Kinv in kinvs.items():
-                fac = f._replace(Kinv=Kinv, K=None)
+            facs = {tag: f._replace(Kinv=Kinv, K=None)
+                    for tag, Kinv in kinvs.items()}
+            if (servers, clients) == SIZES[0] and name == "hub_prox":
+                # the same sweep with and without its extra passes,
+                # whatever the adaptive solve did to gamma
+                one = jnp.ones_like(f.gamma)
+                facs["lowrank_gamma_1"] = facs["lowrank"]._replace(gamma=one)
+                facs["lowrank_gamma_moved"] = facs["lowrank"]._replace(
+                    gamma=one.at[0].set(1.25))
+            for tag, fac in facs.items():
                 run = lambda fac=fac: shared_admm.solve_shared_frozen(
                     *prob, fac, settings=st, warm=sol.raw)
                 ms = median_ms(run, args.reps)

@@ -156,7 +156,7 @@ def test_sweep_implementation_by_family(family, one_chip, chip32):
     import importlib
 
     from tpusppy.ir import ScenarioBatch
-    from tpusppy.solvers import admm, shared_admm
+    from tpusppy.solvers import admm, shared_admm, structured_kkt
 
     model, kw, S = SWEEP_FAMILIES[family]
     module = importlib.import_module("tpusppy.models." + model)
@@ -188,7 +188,12 @@ def test_sweep_implementation_by_family(family, one_chip, chip32):
         _, factors = jax.eval_shape(
             functools.partial(factored._jitted, settings=st), *args)
         if shared:
-            assert (factors.K is not None) == keep_K
+            # the low-rank operator comes with no K under either setting
+            lowrank = structured_kkt.lowrank_kinv(args[2])
+            assert lowrank == (family == "sslp_10_50")
+            assert lowrank == isinstance(factors.Kinv,
+                                         structured_kkt.DiagLowRank)
+            assert (factors.K is not None) == (keep_K and not lowrank)
         text = frozen._jitted.lower(*args, _on_chip(factors, one_chip),
                                     settings=st, warm=warm).as_text()
         assert ("tpu_custom_call" in text) == expect, (family, keep_K)
@@ -255,3 +260,73 @@ def test_sslp_frozen_solve_compiles_with_half_the_flops_on_lowrank_factors(
     print("sslp frozen solve flops a check block: lowrank", lowrank,
           "dense", dense)
     assert 0 < lowrank < 0.5 * dense
+
+
+def _sslp_adaptive_shapes(one_chip, S=2000, m=60, n=520):
+    """(solve arguments, warm start) of the benchmark's sslp batch as
+    shapes on the described chip."""
+    sh = lambda *shape: _spec(shape, one_chip)
+    return ((sh(S, n), sh(S, n), sh(m, n), sh(S, m), sh(S, m), sh(S, n),
+             sh(S, n)), (sh(S, n), sh(S, m), sh(S, m), sh(S, n)))
+
+
+@pytest.mark.parametrize("keep_K", [False, True])
+def test_sslp_restart_piece_compiles_without_an_n_by_n_operand(
+        keep_K, one_chip, chip32):
+    """One restart of the benchmark's sslp adaptive solve (the piece a
+    spoke takes a turn, and the scan body of the hub's refresh; S=2000,
+    n=520, m=60, float32): no (520, 520) array anywhere in the compiled
+    program under either ``factors_keep_K``, and under 0.6 of the
+    operations XLA:TPU counted while ``_factor_shared`` built the dense K
+    for the refinement (2.4097e10 a restart, under either setting, at the
+    commit before: 1000 sweeps' ``while`` body counted once, so a check
+    block of 4 sweeps and the factorization)."""
+    import functools
+
+    from tpusppy.solvers import shared_admm
+
+    st = ADMMSettings(factors_keep_K=keep_K, **F32)
+    args, warm = _sslp_adaptive_shapes(one_chip)
+    shapes = jax.eval_shape(
+        functools.partial(shared_admm._setup_program, settings=st), *args,
+        warm=warm)
+    compiled = shared_admm._restart_program.lower(
+        *_on_chip(shapes, one_chip), settings=st).compile()
+    assert "f32[520,520]" not in compiled.as_text()
+    flops = compiled.cost_analysis()["flops"]
+    print("sslp restart piece flops, keep_K", keep_K, flops)
+    assert 0 < flops < 0.6 * 2.4097e10
+
+
+@pytest.mark.parametrize("S, m, n, dense_K", [(2000, 60, 520, False),
+                                              (3, 20, 85, True)],
+                         ids=["sslp_10_50", "sslp_5_15"])
+def test_dense_K_refine_programs_counts_the_dense_regime_only(
+        S, m, n, dense_K, one_chip, chip32):
+    """``shared_admm.dense_K_refine_programs`` (trace time, one a program
+    whose refinement multiplies by the dense K): 0 after every program of
+    sslp 10 x 50 has traced under either ``factors_keep_K`` (adaptive,
+    frozen on its factors, the restart piece), above 0 for a dense-regime
+    family that keeps K."""
+    import functools
+
+    from tpusppy.obs import metrics
+    from tpusppy.solvers import shared_admm
+
+    jax.clear_caches()      # a trace another test left counts nothing here
+    for keep_K in (False, True):
+        st = ADMMSettings(factors_keep_K=keep_K, **F32)
+        args, warm = _sslp_adaptive_shapes(one_chip, S, m, n)
+        _, factors = jax.eval_shape(     # traces the adaptive program
+            functools.partial(shared_admm.solve_shared_factored._jitted,
+                              settings=st), *args)
+        shared_admm.solve_shared_frozen._jitted.lower(
+            *args, _on_chip(factors, one_chip), settings=st, warm=warm)
+        shapes = jax.eval_shape(
+            functools.partial(shared_admm._setup_program, settings=st),
+            *args, warm=warm)
+        shared_admm._restart_program.lower(*_on_chip(shapes, one_chip),
+                                           settings=st)
+    assert metrics.value("shared_admm.adaptive_programs") > 0
+    assert (metrics.value("shared_admm.dense_K_refine_programs") > 0) \
+        == dense_K

@@ -241,6 +241,8 @@ def test_lowrank_solves_agree_with_the_dense_regime(sslp8):
     """``solve_shared``, ``solve_shared_factored`` then
     ``solve_shared_frozen`` on sslp in float64: the rule's regime against
     the dense one, sweep for sweep."""
+    import jax.numpy as jnp
+
     from tpusppy.solvers import structured_kkt as sk
 
     b = sslp8
@@ -265,11 +267,18 @@ def test_lowrank_solves_agree_with_the_dense_regime(sslp8):
         warm=sol.raw)
     np.testing.assert_allclose(np.asarray(froz.x), np.asarray(twin.x),
                                atol=1e-6)
-    # factors that keep K refine against it densely: the same answer
-    st_k = ADMMSettings(**kw)
-    sol_k, froz_k, f_k = _three_solves(b, st_k, dense=False)
-    assert isinstance(f_k.Kinv, sk.DiagLowRank) and f_k.K.shape == (520, 520)
-    np.testing.assert_allclose(np.asarray(froz_k.x), np.asarray(froz.x),
+    # the operator comes with no K, whatever factors_keep_K says ...
+    sol_k, froz_k, f_k = _three_solves(b, ADMMSettings(**kw), dense=False)
+    assert isinstance(f_k.Kinv, sk.DiagLowRank) and f_k.K is None
+    np.testing.assert_array_equal(np.asarray(froz_k.x), np.asarray(froz.x))
+    # ... and factors handed the dense K refine against it with one
+    # (n, n) product, as the adaptive solve did before: the same answer
+    As = jnp.asarray(b.A_shared) * f.E[:, None] * f.D[None, :]
+    K = (jnp.einsum("mn,m,mk->nk", As, f.rho_a, As)
+         + jnp.diag(f.q2ref + f.rho_x + st.sigma))
+    froz_K = shared_admm.solve_shared_frozen(
+        *_args(b, *_prox(b)), f._replace(K=K), settings=st, warm=sol.raw)
+    np.testing.assert_allclose(np.asarray(froz_K.x), np.asarray(froz.x),
                                atol=1e-6)
 
 
@@ -442,3 +451,48 @@ def test_aot_keys_the_frozen_program_on_the_factors_pytree(sslp8, tmp_path):
     assert metrics.value("aot.misses") == 2 and metrics.value("aot.hits") == 0
     np.testing.assert_allclose(xs[0], xs[1], atol=1e-6)
     np.testing.assert_array_equal(xs[0], xs[2])
+
+
+@pytest.mark.parametrize("regime", ["dense", "lowrank", "sparse",
+                                    "structured"])
+@pytest.mark.parametrize("keep_K", [False, True])
+def test_factors_hold_a_K_in_the_dense_regime_only(regime, keep_K):
+    """What ``solve_shared_factored`` hands back by regime (sslp 5 x 15
+    dense and as triplets, sslp 10 x 50, uc_lite with its block structure
+    attached): the (n, n) ``K`` only beside the explicit inverse of a dense
+    ``A`` and only where ``factors_keep_K`` asks; an operator comes with
+    none under either setting, and the restart scan's carry agrees."""
+    import functools
+
+    import jax
+
+    from tpusppy.ir import ScenarioBatch
+    from tpusppy.models import sslp, uc_lite
+    from tpusppy.solvers import structured_kkt as sk
+    from tpusppy.solvers.sparse import SparseA
+
+    mod, kw = {"dense": (sslp, {}), "sparse": (sslp, {}),
+               "lowrank": (sslp, SSLP_BENCH),
+               "structured": (uc_lite, {"num_gens": 12, "horizon": 8,
+                                        "num_scens": 3,
+                                        "relax_integers": True})}[regime]
+    b = ScenarioBatch.from_problems(
+        [mod.scenario_creator(nm, **kw)
+         for nm in mod.scenario_names_creator(3)])
+    A = np.asarray(b.A_shared)
+    assert sk.lowrank_kinv(A) == (regime == "lowrank")
+    if regime in ("sparse", "structured"):
+        A = SparseA.from_dense(A, structure=regime == "structured",
+                               min_blocks=2)
+        assert (A.structure is not None) == (regime == "structured")
+    st = ADMMSettings(max_iter=8, restarts=2, factors_keep_K=keep_K)
+    _, f = jax.eval_shape(
+        functools.partial(shared_admm.solve_shared_factored._jitted,
+                          settings=st), b.c, b.q2, A, b.cl, b.cu, b.lb, b.ub)
+    n = b.num_vars
+    assert (f.K is not None) == (keep_K and regime == "dense")
+    assert sk.is_dense_kinv(f.Kinv) == (regime in ("dense", "sparse"))
+    assert isinstance(f.Kinv, sk.DiagLowRank) == (regime == "lowrank")
+    assert isinstance(f.Kinv, sk.BlockWoodbury) == (regime == "structured")
+    assert n * n not in [int(np.prod(leaf.shape)) for leaf in
+                         jax.tree.leaves(f)] or regime in ("dense", "sparse")

@@ -79,9 +79,11 @@ class SharedFactors(NamedTuple):
                        # families with block/Woodbury structure) or
                        # DiagLowRank (a dense A of few rows beside its
                        # columns: lowrank_kinv)
-    K: jax.Array       # (n, n) exact shared K for dense refinement, or None
-                       # (factors_keep_K=False): refinement then runs
-                       # matrix-free through the scaled shared A
+    K: jax.Array       # (n, n) exact shared K for dense refinement, or None:
+                       # refinement then runs matrix-free through the
+                       # scaled shared A.  None where factors_keep_K=False
+                       # and wherever Kinv is an operator (BlockWoodbury,
+                       # DiagLowRank), whatever that setting says
     q2ref: jax.Array   # (n,) scaled q2 the K was built with
 
 
@@ -129,9 +131,10 @@ def _factor_shared(q2ref, A, rho_a, rho_x, sigma):
     Four regimes, by the type and the shape of A (read at trace time):
     - dense (m, n) array: dense K + explicit inverse;
     - dense (m, n) array of few rows beside its columns
-      (:func:`structured_kkt.lowrank_kinv`): dense K for the refinement
-      as above, and K^-1 as diagonal plus rank m
-      (:class:`structured_kkt.DiagLowRank`), no (n, n) inverse;
+      (:func:`structured_kkt.lowrank_kinv`): K^-1 as diagonal plus rank m
+      (:class:`structured_kkt.DiagLowRank`) and no (n, n) object at all:
+      K is None, and the refinement reaches K x through the m rows of A
+      (``_core``'s matrix-free ``Kmul``);
     - :class:`SparseA` WITH attached block/Woodbury structure: the
       structured factorization (no (n, n) object at all; K is None and
       refinement runs matrix-free through the sparse A);
@@ -149,11 +152,11 @@ def _factor_shared(q2ref, A, rho_a, rho_x, sigma):
         K = K + jnp.eye(n, dtype=Ad.dtype) * sigma
         K = K + jnp.diag(q2ref + rho_x)
         return _explicit_inverse(K[None])[0], None
+    if lowrank_kinv(A):
+        return factor_lowrank(A, q2ref + rho_x + sigma, rho_a), None
     K = jnp.einsum("mn,m,mk->nk", A, rho_a, A)
     K = K + jnp.eye(n, dtype=A.dtype) * sigma
     K = K + jnp.diag(q2ref + rho_x)
-    if lowrank_kinv(A):
-        return factor_lowrank(A, q2ref + rho_x + sigma, rho_a), K
     return _explicit_inverse(K[None])[0], K
 
 
@@ -245,6 +248,9 @@ def _core(q, q2s, q2ref, A, cl, cu, lb, ub, state, Kinv, K, rho_a, rho_x,
     # on one chip).  Pinned full-precision under a low sweep mode: the
     # defect is the refinement's accuracy anchor.
     if K is not None:
+        # TRACE-time counter: one per compiled program whose refinement
+        # multiplies by the dense (n, n) K
+        _metrics.inc("shared_admm.dense_K_refine_programs")
         if prec is None:
             Kmul = lambda x: x @ K
         else:
@@ -498,18 +504,19 @@ def _shared_setup(c, q2, A, cl, cu, lb, ub, settings, warm):
 
     # (Kinv, K) carry placeholders must match the factorization regime's
     # pytree structure (lax.scan carries are structure-invariant): dense
-    # (n, n) pair for a dense A, (DiagLowRank, dense) for a dense A of few
+    # (n, n) pair for a dense A, (DiagLowRank, None) for a dense A of few
     # rows, (dense, None) for unstructured sparse, (BlockWoodbury, None)
     # for the structured path
+    zK = None
     if isinstance(As, SparseA):
         if As.structure is not None:
             zKinv = zero_factors(As.structure, n, dt)
         else:
             zKinv = jnp.zeros((n, n), dt)
-        zK = None
+    elif lowrank_kinv(As):
+        zKinv = zero_lowrank(m, n, dt)
     else:
-        zK = jnp.zeros((n, n), dt)
-        zKinv = zero_lowrank(m, n, dt) if lowrank_kinv(As) else zK
+        zKinv = zK = jnp.zeros((n, n), dt)
     carry0 = (state0, jnp.asarray(settings.rho, dt),
               jnp.zeros((), jnp.int32),
               jnp.ones((m,), dt), jnp.ones((n,), dt),

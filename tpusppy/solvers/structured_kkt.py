@@ -80,7 +80,9 @@ class DiagLowRank(NamedTuple):
     two thin products an apply, ``2 m n`` a row where the explicit
     inverse costs ``n n``.  The dense-A stand-in for the ``Kinv`` array
     inside :class:`~tpusppy.solvers.shared_admm.SharedFactors`, as
-    :class:`BlockWoodbury` is the sparse-A one."""
+    :class:`BlockWoodbury` is the sparse-A one; like it, it comes with no
+    (n, n) ``K`` (``shared_admm._factor_shared``): the refinement's exact
+    ``K x`` is ``x d + (R (x A')) A``, two thin products more."""
 
     dinv: jax.Array    # (n,) 1 / d
     W: jax.Array       # (m, n) A diag(1/d)
