@@ -18,8 +18,9 @@ Each is timed warm, ``block_until_ready``, median of ``--reps`` (20), cold
 (no warm start, the program's full sweep budget) and warm-started from its
 own raw iterate (few sweeps: what is not sweeps).  The dense pieces are
 also timed alone: ``jnp.linalg.solve`` on the polish's (S, n+m, n+m) saddle
-system and the Cholesky inverse of the (S, n, n) K, and
-``pallas_kernels.lanes_solve`` on both where it applies.
+system and the Cholesky inverse of the (S, n, n) K (the last restart's),
+``pallas_kernels.lanes_solve`` on both where it applies, ``max |K Kinv - I|``
+of either inverse, and ``admm._explicit_inverse`` as the program calls it.
 
 Usage (the chip): python scripts/refresh_split.py [--scens 1000] [--reps 20]
 """
@@ -150,7 +151,9 @@ def main():
     pieces = {}
     with jax.default_matmul_precision(st.matmul_precision):
         solve = jax.jit(lambda M, r: jnp.linalg.solve(M, r[..., None])[..., 0])
-        inv = jax.jit(admm._explicit_inverse)
+        # XLA's Cholesky path by name: ``_explicit_inverse`` itself takes
+        # the kernel at this shape since PR 43
+        inv = jax.jit(admm._explicit_inverse_oneshot)
         pieces[f"xla_lu_solve_{N}_ms"] = median_ms(
             lambda: solve(M, rhs), args.reps)
         pieces[f"xla_chol_inverse_{n}_ms"] = median_ms(
@@ -174,6 +177,19 @@ def main():
             got = lanes(mat, r)
             pieces[tag + "_vs_xla_rel"] = float(
                 jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+            if r.shape[2] > 1:
+                # max |K Kinv - I| of either inverse, the product in float64
+                # on the host
+                K64 = np.asarray(mat, np.float64)
+                for side, Kinv in (("xla", ref), ("lanes", got)):
+                    pieces[f"{tag}_{side}_residual"] = float(np.abs(
+                        K64 @ np.asarray(Kinv, np.float64) - np.eye(n)).max())
+        # the inverse as the program takes it (transposes included)
+        prog = jax.jit(lambda K: admm._explicit_inverse(K, st))
+        pieces[f"program_inverse_{n}_ms"] = median_ms(lambda: prog(K),
+                                                      args.reps)
+        pieces[f"program_inverse_{n}_on_kernel"] = admm.lanes_inverse(
+            st, S, m, n)
     print(json.dumps({"pieces": pieces}), flush=True)
 
 

@@ -232,11 +232,11 @@ class TestBlockedExplicitInverse:
         assert abs(obj - ref.obj) <= 1e-5 * max(1.0, abs(ref.obj))
 
 
-@pytest.fixture
-def lanes_interpreted(monkeypatch):
-    """Answer ``usable_solve`` as the TPU would and run ``lanes_solve``
-    through the Pallas interpreter: the tests steer the program's choice,
-    the program has no option for it."""
+def _steer_lanes(monkeypatch, take):
+    """Answer ``usable_solve`` as the TPU would for the right-hand-side
+    counts ``take(R)`` accepts and run ``lanes_solve`` through the Pallas
+    interpreter: the tests steer the program's choice, the program has no
+    option for it."""
     import functools
 
     from tpusppy.solvers import pallas_kernels as pk
@@ -244,12 +244,45 @@ def lanes_interpreted(monkeypatch):
     usable, solve = pk.usable_solve, pk.lanes_solve
     monkeypatch.setattr(
         pk, "usable_solve",
-        lambda S, N, R, platform=None, **kw: usable(S, N, R, "tpu", **kw))
+        lambda S, N, R, platform=None, **kw: usable(
+            S, N, R, "tpu" if take(R) else platform, **kw))
     monkeypatch.setattr(pk, "lanes_solve",
                         functools.partial(solve, interpret=True))
 
 
+@pytest.fixture
+def lanes_interpreted(monkeypatch):
+    """The polish's saddle systems (one right-hand side) on the kernel; the
+    inverses of K stay XLA's, so that the ADMM iterate is the same float."""
+    _steer_lanes(monkeypatch, lambda R: R == 1)
+
+
+@pytest.fixture
+def lanes_inverse_interpreted(monkeypatch):
+    """Both uses of the kernel, as the TPU runs a qualifying batch."""
+    _steer_lanes(monkeypatch, lambda R: True)
+
+
 F32 = ADMMSettings(dtype="float32", eps_abs=1e-5, eps_rel=1e-5)
+
+
+def _two_refreshes(S, use_pallas):
+    """Two refresh solves of a float32 farmer PH of S scenarios; the
+    metrics registry they counted in."""
+    from tpusppy.obs import metrics
+    from tpusppy.opt.ph import PH
+
+    ph = PH({"defaultPHrho": 1.0, "PHIterLimit": 1,
+             "solver_refresh_every": 1,
+             "solver_options": dict(
+                 dtype="float32", eps_abs=1e-5, eps_rel=1e-5,
+                 use_pallas=use_pallas, megastep=1)},
+            farmer.scenario_names_creator(S), farmer.scenario_creator,
+            scenario_creator_kwargs={"num_scens": S})
+    ph.solve_loop()
+    ph.solve_loop()
+    assert metrics.value("phase.main.refresh.count") == 2
+    return metrics
 
 
 class TestPolishOnLanesSolve:
@@ -339,17 +372,99 @@ class TestPolishOnLanesSolve:
     def test_refresh_counter(self, lanes_interpreted, use_pallas, counted):
         """``refresh.lanes_linalg`` counts once per refresh when the
         selector engages, never under ``use_pallas=False``."""
-        from tpusppy.obs import metrics
-        from tpusppy.opt.ph import PH
+        metrics = _two_refreshes(self.S, use_pallas)
+        assert metrics.value("refresh.lanes_linalg") == counted
+        # the host's twin of the inverse's choice said no (the CPU)
+        assert metrics.value("refresh.lanes_inverse") == 0
 
-        ph = PH({"defaultPHrho": 1.0, "PHIterLimit": 1,
-                 "solver_refresh_every": 1,
-                 "solver_options": dict(
-                     dtype="float32", eps_abs=1e-5, eps_rel=1e-5,
-                     use_pallas=use_pallas, megastep=1)},
-                farmer.scenario_names_creator(self.S), farmer.scenario_creator,
-                scenario_creator_kwargs={"num_scens": self.S})
-        ph.solve_loop()
-        ph.solve_loop()
-        assert metrics.value("phase.main.refresh.count") == 2
+
+class TestInverseOnLanesSolve:
+    """``_explicit_inverse`` of the dense engine's K on the batched
+    elimination kernel (interpreted) against XLA's Cholesky inverse: the
+    gate, the counter, and the adaptive solve around it."""
+
+    S = 128
+
+    @pytest.mark.parametrize("S, n, dtype, use_pallas, expect", [
+        (1000, 44, "float32", "auto", 128),     # farmer x4: the cells' shape
+        (1000, 44, "float32", True, 128),
+        (128, 11, "float32", "auto", 128),
+        (1000, 45, "float32", "auto", 128),     # the budget's last size
+        (1000, 46, "float32", "auto", None),    # past the VMEM budget
+        (64, 44, "float32", "auto", None),      # lanes not filled
+        (1, 44, "float32", "auto", None),       # what the shared engine hands
+        (1000, 44, "float64", "auto", None),
+        (1000, 44, "float32", False, None),
+    ])
+    def test_gate(self, lanes_inverse_interpreted, S, n, dtype, use_pallas,
+                  expect):
+        """``usable_solve`` with R = N decides, at trace time and in the
+        host's twin alike; ``use_pallas=False`` turns it off."""
+        import jax
+        import jax.numpy as jnp
+
+        from tpusppy.solvers import admm
+
+        st = ADMMSettings(dtype=dtype, use_pallas=use_pallas)
+        assert admm._lanes_bs(st, S, n, st.jdtype(), R=n) == expect
+        assert admm.lanes_inverse(st, S, 28, n) == (expect is not None)
+        jaxpr = jax.make_jaxpr(lambda K: admm._explicit_inverse(K, st))(
+            jax.ShapeDtypeStruct((S, n, n), jnp.dtype(dtype)))
+        assert ("pallas_call" in str(jaxpr)) == (expect is not None)
+
+    def test_no_settings_follows_usable_solve(self, lanes_inverse_interpreted):
+        """A caller with no settings (``ipm``, the shared engine's batch of
+        1) gets the gate alone."""
+        import jax
+        import jax.numpy as jnp
+
+        from tpusppy.solvers import admm
+
+        for shape, took in (((1000, 44, 44), True), ((1, 44, 44), False),
+                            ((1, 520, 520), False)):
+            jaxpr = jax.make_jaxpr(admm._explicit_inverse)(
+                jax.ShapeDtypeStruct(shape, jnp.float32))
+            assert ("pallas_call" in str(jaxpr)) == took, shape
+
+    def test_adaptive_solve_agrees(self, request):
+        """The refresh solve's program with its four inverses on the
+        kernel: the refinement against the exact K makes either inverse the
+        same operator: the same scenarios take the polish and end at the
+        same vertex (1e-6), and a row that keeps its ADMM iterate (float32
+        spends its whole budget at a primal residual near 2e-3) agrees in
+        the objective to 1e-4."""
+        import jax
+
+        from tpusppy.solvers import admm
+
+        b = ScenarioBatch.from_problems(
+            [farmer.scenario_creator(nm, num_scens=self.S)
+             for nm in farmer.scenario_names_creator(self.S)])
+        args = (b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub)
+        fn = lambda: admm._solve_impl(*args, F32, None)
+        xla = jax.jit(fn)()
+        request.getfixturevalue("lanes_inverse_interpreted")
+        lanes = jax.jit(lambda: fn())()
+        took = lambda s: np.any(np.asarray(s.x) != np.asarray(s.raw[0]),
+                                axis=1)
+        assert 0 < took(xla).sum() < self.S
+        np.testing.assert_array_equal(took(xla), took(lanes))
+        close = TestPolishOnLanesSolve.close
+        obj = lambda s: b.objective(np.asarray(s.x, np.float64))
+        t = took(xla)
+        close(obj(xla)[t], obj(lanes)[t], np.abs(obj(xla))[t], 1e-6)
+        close(obj(xla), obj(lanes), np.abs(obj(xla)), 1e-4)
+        close(xla.pri_res, lanes.pri_res,
+              np.abs(np.asarray(xla.z)).max(axis=1), 1e-4)
+
+    @pytest.mark.parametrize("use_pallas, counted", [("auto", 2), (True, 2),
+                                                     (False, 0)])
+    def test_refresh_counter(self, lanes_inverse_interpreted, use_pallas,
+                             counted):
+        """``refresh.lanes_inverse`` counts once per refresh when the
+        host's twin of the trace-time choice says yes, never under
+        ``use_pallas=False`` (and never on the CPU:
+        ``TestPolishOnLanesSolve.test_refresh_counter``)."""
+        metrics = _two_refreshes(self.S, use_pallas)
+        assert metrics.value("refresh.lanes_inverse") == counted
         assert metrics.value("refresh.lanes_linalg") == counted

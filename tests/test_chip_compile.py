@@ -6,7 +6,8 @@ device and compiles it: what Mosaic or XLA:TPU would refuse on the chip
 (unaligned blocks, scoped-VMEM overflow, i64 block indices) is refused
 here, at no chip time.  Shapes are the ones ``chip_smoke.py`` runs: the
 ``serve`` phase's farmer (S=1000, crops_multiplier=4) for the per-scenario
-sweep kernel, the polish's elimination kernel and the wheel megastep.  One
+sweep kernel, the elimination kernel (the polish's saddle systems and the
+inverse of K) and the wheel megastep.  One
 test lowers every family's frozen solve and says which sweep it holds: the
 dense engine's kernel by that engine's own rule, XLA's for the shared-A
 engine.
@@ -125,6 +126,43 @@ def test_lanes_solve_compiles_at_the_served_farmer_polish_shape(one_chip,
         _spec((N, N, S), one_chip), _spec((N, 1, S), one_chip),
         bs=bs).compile()
     assert _has_mosaic_kernel(compiled)
+
+
+def test_explicit_inverse_compiles_on_the_kernel_at_farmers_K(one_chip,
+                                                             chip32):
+    """The dense engine's (1000, 44, 44) K, the identity on the right
+    (R = N): the block the gate picks fits Mosaic's VMEM, and the lowered
+    ``_explicit_inverse`` holds the kernel and no Cholesky."""
+    from tpusppy.solvers import admm
+
+    b = _farmer_batch(2)
+    S, n = FARMER_S, b.num_vars
+    assert pk.usable_solve(S, n, n) == 128
+    lowered = jax.jit(admm._explicit_inverse).lower(_spec((S, n, n),
+                                                          one_chip))
+    assert "cholesky" not in lowered.as_text()
+    assert _has_mosaic_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 60, 60),        # sslp's Woodbury cap (structured_kkt.factor_lowrank)
+    (1, 520, 520),      # a dense-regime shared K (shared_admm._factor_shared)
+    (1, 44, 44),
+    (64, 44, 44),       # lanes not filled
+    (1000, 46, 46),     # past the kernel's VMEM budget
+    (1000, 88, 88),     # the padded window
+], ids=lambda s: "x".join(map(str, s)))
+def test_explicit_inverse_declines_the_kernel(shape, one_chip, chip32):
+    """What the shared-A engine and ``structured_kkt`` hand
+    ``_explicit_inverse`` is a batch of 1: with the platform the chip's, it
+    lowers to XLA's Cholesky path and no ``pallas_call``, as does a dense
+    batch short of 128 or past n = 45."""
+    from tpusppy.solvers import admm
+
+    text = jax.jit(admm._explicit_inverse).lower(
+        _spec(shape, one_chip)).as_text()
+    assert "tpu_custom_call" not in text
+    assert "cholesky" in text
 
 
 SWEEP_FAMILIES = {

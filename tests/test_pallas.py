@@ -312,6 +312,47 @@ def test_lanes_solve_on_the_polish_saddle_systems(crops_multiplier, S):
     _assert_as_good_as_xla(M, rhs)
 
 
+def test_lanes_inverse_of_farmers_K_agrees_with_the_cholesky_inverse():
+    """The refresh solve's own K (farmer x4, n = 44, float32, the rho of the
+    last restart) at S = 256, inverted by the kernel against the identity
+    and by ``admm._explicit_inverse_oneshot``: ``K Kinv`` is the identity to
+    2e-6 either way (cond K is 1e3 after the Ruiz scaling), the two agree
+    to 2e-6 of the inverse's largest entry, and a solve through either
+    with the program's two refinement passes stands 5e-7 from float64's."""
+    import jax.numpy as jnp
+
+    from tpusppy.ir import ScenarioBatch
+    from tpusppy.models import farmer
+    from tpusppy.solvers import admm
+
+    S = 256
+    b = ScenarioBatch.from_problems(
+        [farmer.scenario_creator(nm, num_scens=S, crops_multiplier=4)
+         for nm in farmer.scenario_names_creator(S)])
+    st = admm.ADMMSettings(dtype="float32", eps_abs=1e-5, eps_rel=1e-5)
+    _, factors = admm.solve_batch_factored(
+        b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub, settings=st)
+    K = factors.K
+    n = b.num_vars
+    assert K.shape == (S, n, n) == (S, 44, 44) and K.dtype == jnp.float32
+    chol = admm._explicit_inverse_oneshot(K)
+    lanes = jnp.asarray(_lanes(
+        K, np.broadcast_to(np.eye(n, dtype=np.float32), K.shape)))
+    assert lanes.dtype == jnp.float32
+    K64 = np.asarray(K, np.float64)
+    scale = np.abs(np.linalg.inv(K64)).max()
+    for Kinv in (chol, lanes):
+        assert np.abs(K64 @ np.asarray(Kinv, np.float64)
+                      - np.eye(n)).max() < 2e-6
+    assert float(jnp.max(jnp.abs(lanes - chol))) < 2e-6 * scale
+    rhs = np.random.RandomState(0).randn(S, n).astype(np.float32)
+    ref = np.linalg.solve(K64, rhs.astype(np.float64)[..., None])[..., 0]
+    for Kinv in (chol, lanes):
+        x = admm._chol_solve((Kinv, K), jnp.asarray(rhs),
+                             refine=st.solve_refine)
+        assert np.abs(np.asarray(x) - ref).max() < 5e-7 * np.abs(ref).max()
+
+
 def test_lanes_solve_singular_system_is_nonfinite_on_its_lane_only():
     rng = np.random.RandomState(3)
     S, N = 128, 12

@@ -252,16 +252,18 @@ def _ruiz(A, q2, iters):
     return D, E
 
 
-def _lanes_bs(st: "ADMMSettings", S, N, dt):
+def _lanes_bs(st: "ADMMSettings | None", S, N, dt, R=1):
     """Block size when ``pallas_kernels.lanes_solve`` takes a batch of S
-    (N, N) systems with one right-hand side each, else None (the XLA
-    path).  ``use_pallas=False`` turns the kernel off as it turns the sweep
-    kernel off; "auto" and True follow ``usable_solve`` (TPU, float32, a
-    batch of 128 or more, the VMEM budget)."""
-    if st.use_pallas is False:
+    (N, N) systems with R right-hand sides each (1: the polish's saddle
+    systems; N: an inverse), else None (the XLA path).
+    ``use_pallas=False`` turns the kernel off as it turns the sweep kernel
+    off; "auto", True and a caller with no settings (``st=None``) follow
+    ``usable_solve`` (TPU, float32, a batch of 128 or more, the VMEM
+    budget)."""
+    if st is not None and st.use_pallas is False:
         return None
     from . import pallas_kernels
-    return pallas_kernels.usable_solve(S, N, 1, dtype=dt)
+    return pallas_kernels.usable_solve(S, N, R, dtype=dt)
 
 
 def lanes_linalg(st: "ADMMSettings", S, m, n) -> bool:
@@ -273,27 +275,35 @@ def lanes_linalg(st: "ADMMSettings", S, m, n) -> bool:
                 and _lanes_bs(st, S, n + m, st.jdtype()) is not None)
 
 
-def _factor(q2, A, rho_a, rho_x, sigma, P=None):
-    """Cholesky of K = P + diag(q2) + sigma I + A' diag(rho_a) A + diag(rho_x).
+def lanes_inverse(st: "ADMMSettings", S, m, n) -> bool:
+    """Whether an adaptive (refresh) solve of an (S, m, n) dense batch
+    inverts its K's on ``lanes_solve``: the host's twin of the choice
+    ``_explicit_inverse`` makes while tracing (spopt counts
+    ``refresh.lanes_inverse`` by it)."""
+    return _lanes_bs(st, S, n, st.jdtype(), R=n) is not None
+
+
+def _factor(q2, A, rho_a, rho_x, st: "ADMMSettings", P=None):
+    """Inverse of K = P + diag(q2) + sigma I + A' diag(rho_a) A + diag(rho_x).
 
     ``P`` is an optional dense (S, n, n) quadratic term (FWPH's simplex QP and
     other column-space problems need one); the diagonal-only path stays the
-    default.  Returns (L, K); K is kept for iterative refinement of the
-    triangular solves — essential in float32, where cond(K) ~ 1/sigma *
+    default.  Returns (Kinv, K); K is kept for iterative refinement of the
+    applies of Kinv — essential in float32, where cond(K) ~ 1/sigma *
     rho_eq_scale otherwise stalls ADMM around 1e-2 residuals.
     """
     n = A.shape[-1]
     K = jnp.einsum("smn,sm,smk->snk", A, rho_a, A)
-    K = K + jnp.eye(n, dtype=A.dtype)[None] * sigma
+    K = K + jnp.eye(n, dtype=A.dtype)[None] * st.sigma
     K = K + jax.vmap(jnp.diag)(q2 + rho_x)
     if P is not None:
         K = K + P
-    # Explicit inverse via Cholesky: triangular substitution is SEQUENTIAL on
-    # TPU (length-n dependency chain per solve), so the hot loop applies K^-1
-    # as one MXU matmul per solve instead.  Iterative refinement against the
-    # exact K (kept alongside) recovers the digits the explicit inverse
+    # Explicit inverse: triangular substitution is SEQUENTIAL on TPU
+    # (length-n dependency chain per solve), so the hot loop applies K^-1
+    # as one matrix product per solve instead.  Iterative refinement against
+    # the exact K (kept alongside) recovers the digits the explicit inverse
     # loses — cheaper than two triangular sweeps per inner iteration.
-    return _explicit_inverse(K), K
+    return _explicit_inverse(K, st), K
 
 
 # Matrices larger than 2 * this go through the recursive Schur inversion,
@@ -310,9 +320,21 @@ def _factor(q2, A, rho_a, rho_x, sigma, P=None):
 _EXPLICIT_INV_LEAF_N = 2048
 
 
-def _explicit_inverse(K):
-    """K^-1 of an SPD batch via recursive blocked Schur inversion.
+def _explicit_inverse(K, st=None):
+    """K^-1 of an SPD batch.
 
+    A batch that ``pallas_kernels.usable_solve`` takes (TPU, float32, 128
+    or more matrices, n <= 45 by its VMEM budget; ``st.use_pallas`` not
+    False) is inverted by ``lanes_solve`` against the identity: Gaussian
+    elimination with partial pivoting, the batch on the lanes.  XLA:TPU's
+    Cholesky and two triangular solves walk the n columns one dependent
+    step at a time over arrays laid out batch-outermost: 6.0 ms where the
+    kernel takes 0.95 at farmer's (1000, 44, 44) (PERF.md section 7).  Both
+    are backward stable on an SPD matrix, and every consumer refines
+    against the exact K.  A batch of 1 (what the shared-A engine hands
+    over) never qualifies.
+
+    Otherwise recursive blocked Schur inversion:
     inv([[A, B], [B', C]]) = [[Ai + W Si W', -W Si], [-Si W', Si]] with
     Ai = inv(A), W = Ai B, Si = inv(C - B' Ai B); Schur complements of SPD
     are SPD, so the recursion is well posed.  Base cases (n <= 2 * leaf =
@@ -321,6 +343,14 @@ def _explicit_inverse(K):
     tiling.
     """
     n = K.shape[-1]
+    bs = _lanes_bs(st, K.shape[0], n, K.dtype, R=n) if K.ndim == 3 else None
+    if bs is not None:
+        from . import pallas_kernels
+        eye = jnp.broadcast_to(jnp.eye(n, dtype=K.dtype)[:, :, None],
+                               (n, n, K.shape[0]))
+        return jnp.transpose(
+            pallas_kernels.lanes_solve(jnp.transpose(K, (1, 2, 0)), eye,
+                                       bs=bs), (2, 0, 1))
     leaf = _EXPLICIT_INV_LEAF_N
     if n <= 2 * leaf:
         # XLA:TPU's blocked TriangularSolve lowering has a broken window
@@ -331,13 +361,8 @@ def _explicit_inverse(K):
         # (unblocked path) and n>=128 (128-wide diag blocks) compile fine.
         # Embed K into a 128x128 identity-extended SPD and slice back.
         # TPU-only (trace-time check): other backends' lowerings are fine
-        # and would just pay ~3x the flops for the padding.
-        # Every dense batch still reaches this lowering: the refresh
-        # solve's four inverses are 4-8% of its program (PERF.md section
-        # 5), so only the polish's LUs moved to pallas_kernels.lanes_solve.
-        # That kernel against the identity would take batches of 128 or
-        # more up to n = 45 (its VMEM budget): 64 < n < 128 pads here
-        # either way.
+        # and would just pay ~3x the flops for the padding.  (The lanes
+        # kernel above stops at n = 45, so this window pads either way.)
         if 64 < n < 128 and jax.default_backend() == "tpu":
             pad = 128 - n
             eye_pad = jnp.eye(128, dtype=K.dtype)[n:, :]
@@ -681,7 +706,7 @@ def _solve_scaled(q, q2, A, cl, cu, lb, ub, warm, masks, st: ADMMSettings,
         if st.rho_row_adapt:
             rho_a = jnp.minimum(rho_a * mult, st.rho_row_max)
             rho_x = jnp.minimum(rho_x * multx, st.rho_row_max)
-        LK = _factor(q2, A, rho_a, rho_x, st.sigma, P)
+        LK = _factor(q2, A, rho_a, rho_x, st, P)
         state = _admm_core(
             q, q2, A, cl, cu, lb, ub,
             state._replace(k=jnp.zeros((), jnp.int32),
@@ -871,7 +896,7 @@ def _polish(state: _IterState, q, q2, A, cl, cu, lb, ub, masks,
         K = K + jax.vmap(jnp.diag)(q2 + w_var)
         if P is not None:
             K = K + P
-        Kinv = _explicit_inverse(K)
+        Kinv = _explicit_inverse(K, st)
         ra = row_act.astype(dt)
         va = var_act.astype(dt)
         nu = jnp.zeros_like(row_b)

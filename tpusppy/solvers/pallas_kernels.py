@@ -5,7 +5,9 @@ Two kernels, each with the gate that sizes its block, both scenario-on-lanes:
 - :func:`fused_sweeps` (gate :func:`usable`, block :func:`sweep_block_size`):
   the fused ADMM sweep block;
 - :func:`lanes_solve` (gate :func:`usable_solve`): the batched dense
-  elimination behind the refresh solve's polish.
+  elimination behind the refresh solve's polish (one right-hand side a
+  saddle system) and behind its explicit inverses of K (the identity on
+  the right).
 
 The shared-A engine (:mod:`.shared_admm`) has no kernel here: its sweeps are
 (S, n) x (n, m) MXU matmuls that XLA:TPU already fuses, in every regime.
@@ -224,9 +226,11 @@ def usable(S, m, n, platform=None, P=None, precision="highest") -> int | None:
 #
 # XLA:TPU expands LuDecomposition / Cholesky / TriangularSolve on a batch of
 # small matrices into one dependent step per column over arrays laid out
-# batch-outermost: the refresh solve's nine (n+m)-sized polish LUs cost far
-# more than their few 1e8 flops (PERF.md section 5).  This kernel does the
-# same arithmetic in the layout of ``fused_sweeps``: a block of
+# batch-outermost: the refresh solve's nine (n+m)-sized polish LUs and its
+# four n-sized inverses cost far more than their few 1e8 flops (PERF.md
+# section 5).  This kernel does the same arithmetic (an LU with partial
+# pivoting; for the SPD K's, where Cholesky stood, as backward stable) in
+# the layout of ``fused_sweeps``: a block of
 # scenarios' systems stays in VMEM from the first pivot to the last
 # back-substitution, every step one full-width VPU pass with the scenario on
 # the lanes, a grid over blocks.

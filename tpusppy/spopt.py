@@ -511,9 +511,13 @@ class SPOpt(SPBase):
                 slot["sig"] = sig
                 slot["age"] = 1
                 meas = self._fetch_measure(sol)
-            if not shared and admm.lanes_linalg(st_adpt, *args[2].shape):
-                # this refresh's polish ran on pallas_kernels.lanes_solve
-                _metrics.inc("refresh.lanes_linalg")
+            if not shared:
+                if admm.lanes_linalg(st_adpt, *args[2].shape):
+                    # this refresh's polish ran on pallas_kernels.lanes_solve
+                    _metrics.inc("refresh.lanes_linalg")
+                if admm.lanes_inverse(st_adpt, *args[2].shape):
+                    # and its four K's were inverted on the same kernel
+                    _metrics.inc("refresh.lanes_inverse")
             if shared and isinstance(factors.Kinv, DiagLowRank):
                 # this refresh's factors apply K^-1 as diagonal plus
                 # low rank (structured_kkt.lowrank_kinv)
