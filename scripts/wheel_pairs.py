@@ -10,7 +10,9 @@ line's numbers it prints the hub iterations in the window, the window's
 seconds, the seconds between the hub's last boundary and the window's close
 (the wheel's own ending and its tear-down are inside a window that the wheel
 ends itself), the per-layer metrics that read the program's counters, the
-compile seconds inside the window and whether an inner bound ever arrived.
+compile seconds inside the window, whether an inner bound ever arrived, and
+the window's outcome counters and phases (``solve.*``, ``xhat.*``,
+``phase.*``).
 
 Runs go parent, change, change, parent, ...: each seed once a side, the first
 run of a side its cold one (each checkout keeps its own ``.jax_cache``).
@@ -88,6 +90,10 @@ def one(root, workload, seed, seconds):
                       if k.startswith("phase.")
                       and k.endswith(".refresh.count")},
         "checks": {r["name"]: r["value"] for r in rows},
+        # what the solves spent and how they ended, beside the phases'
+        # seconds (PERF.md section 6, PR 44): the window's deltas
+        "counters": {k: v for k, v in sorted(obs["counters"].items())
+                     if v and k.startswith(("solve.", "xhat.", "phase."))},
     }
     obs["workload"] = wl
     for name in COUNTER_METRICS:

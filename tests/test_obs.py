@@ -733,3 +733,272 @@ def test_rescue_rows_counts_on_a_tiny_wheel_whose_rescue_fires():
                 "phase.main.teardown"):
         assert d[key + ".count"] >= 1 and d[key + ".secs"] > 0, key
     assert d["phase.hub.rescue.secs"] <= d["phase.hub.iter0.secs"]
+
+
+# ---------------------------------------------------------------------------
+# outcomes: what a solve spent and how it ended (registry only)
+# ---------------------------------------------------------------------------
+
+def test_outcome_counters_land_under_the_calling_threads_cylinder():
+    def attempts(k, accepted):
+        def go():
+            for _ in range(k):
+                trace.outcome("frozen", count=1, sweeps=250, budget=1000,
+                              accepted=accepted)
+        return go
+
+    with metrics.window() as win:
+        threads = [_on_track("hub", attempts(3, 1)),
+                   _on_track("spoke1:LagrangianOuterBound", attempts(2, 0))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        trace.outcome("refresh", rows=4, cold=1)            # this thread
+    d = win.deltas()
+    got = {k: v for k, v in d.items() if k.startswith("solve.") and v}
+    assert got == {
+        "solve.hub.frozen.count": 3, "solve.hub.frozen.sweeps": 750,
+        "solve.hub.frozen.budget": 3000, "solve.hub.frozen.accepted": 3,
+        "solve.spoke1.frozen.count": 2, "solve.spoke1.frozen.sweeps": 500,
+        "solve.spoke1.frozen.budget": 2000,
+        "solve.main.refresh.rows": 4, "solve.main.refresh.cold": 1}
+    # a field passed as 0 has its counter all the same: a dump shows it
+    assert d["solve.spoke1.frozen.accepted"] == 0
+    # registry only: no ring event, with the ring on either
+    trace.enable()
+    trace.outcome("frozen", count=1)
+    assert trace.events() == []
+
+
+def test_outcome_off_path_cost_is_pinned():
+    """One solve's whole record at S=1000: the two counts over the rows'
+    masks and a call with every field of the ``frozen`` kind.  Best of
+    five batches, as for the phase; the pin catches a call that rebuilds
+    its counter names or takes the registry's lock to find them."""
+    assert not trace.enabled()
+    S = 1000
+    done = np.arange(S) % 7 != 0
+    in_tol = np.arange(S) % 5 != 0
+    n, best = 5_000, float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            trace.outcome(
+                "frozen", count=1, sweeps=1000, budget=1000, rows=S,
+                rows_done=int(np.count_nonzero(done)),
+                rows_in_tol=int(np.count_nonzero(in_tol)), accepted=0)
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 20e-6, f"outcome() too slow: {best * 1e9:.0f}ns"
+    assert metrics.value("solve.main.frozen.count") == 5 * n
+    assert metrics.value("solve.main.frozen.rows_done") == \
+        5 * n * np.count_nonzero(done)
+
+
+def _tiny_solution(engine):
+    """A (4, n) batch of either engine, its sweeps cut short."""
+    from tpusppy.ir import ScenarioBatch
+    from tpusppy.models import farmer, sslp
+    from tpusppy.solvers import admm, shared_admm
+
+    st = admm.ADMMSettings(max_iter=20, restarts=1, polish=False)
+    if engine == "dense":
+        names = farmer.scenario_names_creator(4)
+        b = ScenarioBatch.from_problems(
+            [farmer.scenario_creator(nm, num_scens=4) for nm in names])
+        assert getattr(b, "A_shared", None) is None
+        return admm.solve_batch(b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub,
+                                settings=st)
+    names = sslp.scenario_names_creator(4)
+    b = ScenarioBatch.from_problems(
+        [sslp.scenario_creator(nm) for nm in names])
+    assert b.A_shared is not None
+    return shared_admm.solve_shared(b.c, b.q2, b.A_shared, b.cl, b.cu, b.lb,
+                                    b.ub, settings=st)
+
+
+@pytest.mark.parametrize("engine", ["dense", "shared"])
+@pytest.mark.parametrize("vote", [None, (0, 0, 0, 0), (1, 0, 1, 1),
+                                  (1, 1, 1, 1)],
+                         ids=["own", "none", "some", "all"])
+def test_measure_pack_round_trips_n_done(engine, vote):
+    """The packed measurement carries the program's count of rows done
+    beside its vote, for the dense and the shared engine's solutions:
+    the solve's own vote, and each way a vote can fall."""
+    import jax.numpy as jnp
+
+    from tpusppy.solvers import admm
+
+    sol = _tiny_solution(engine)
+    if vote is not None:
+        sol = sol._replace(done=jnp.asarray(vote, bool))
+    S, n = sol.x.shape
+    vec = np.asarray(admm.measure_pack(sol))
+    assert vec.shape == (S * (n + 2) + 3,)
+    meas = admm.measure_unpack(vec, S, n)
+    done = np.asarray(sol.done)
+    assert meas["n_done"] == int(done.sum())
+    assert meas["all_done"] == bool(done.all())
+    assert (meas["n_done"] == S) == meas["all_done"]
+    np.testing.assert_array_equal(meas["x"], np.asarray(sol.x))
+    np.testing.assert_array_equal(meas["pri"], np.asarray(sol.pri_res))
+    np.testing.assert_array_equal(meas["dua"], np.asarray(sol.dua_res))
+    assert meas["iters"] == int(np.asarray(sol.iters).max()) == 20
+
+
+_REASONS = ("cold", "signature", "age", "declined")
+
+
+def _solve_kinds(d):
+    """{(cylinder, kind): {field: value}} of a window's ``solve.*`` deltas,
+    for the kinds the window counted (the registry keeps other tests'
+    counters, zeroed).  How often a kind happened stands under ``n``: a
+    frozen attempt counts itself, a refresh solve is one of its four
+    reasons and one run of the phase ``refresh``, a megastep iteration is
+    one of ``dispatch.mega_iterations`` (the hub's, or ``main``'s where a
+    hub runs alone)."""
+    out = {}
+    for k, v in d.items():
+        parts = k.split(".")
+        if parts[0] == "solve" and len(parts) == 4:
+            out.setdefault((parts[1], parts[2]), {})[parts[3]] = v
+    for (cyl, kind), f in out.items():
+        f["n"] = (f["count"] if kind == "frozen"
+                  else sum(f[r] for r in _REASONS) if kind == "refresh"
+                  else d.get("dispatch.mega_iterations", 0) if f["budget"]
+                  else 0)
+    return {key: f for key, f in out.items() if f["n"]}
+
+
+@pytest.mark.parametrize("mega", [0, 1], ids=["megastep", "legacy"])
+def test_hub_outcomes_and_one_fetch_a_solve(mega):
+    """A hub alone, 20 iterations: every solve and every window leaves its
+    outcome, and fetches what it fetched before the outcomes were there:
+    one packed vector a solve, one a window, one at the end."""
+    from tpusppy.models import farmer
+    from tpusppy.opt.ph import PH
+
+    options = {"defaultPHrho": 1.0, "PHIterLimit": 20, "convthresh": -1.0,
+               "display_progress": False, "solver_refresh_every": 4,
+               "solver_options": {"megastep": mega}}
+    with metrics.window() as win:
+        ph = PH(options, farmer.scenario_names_creator(3),
+                farmer.scenario_creator,
+                scenario_creator_kwargs={"num_scens": 3})
+        ph.ph_main()
+    d = win.deltas()
+    kinds = _solve_kinds(d)
+    assert set(kinds) == ({("main", "refresh"), ("main", "mega")} if mega == 0
+                          else {("main", "refresh"), ("main", "frozen")})
+    ref = kinds["main", "refresh"]
+    fro = kinds.get(("main", "frozen"), {"n": 0, "accepted": 0})
+    meg = kinds.get(("main", "mega"), {"n": 0, "budget": 0})
+    # the four reasons are the refresh solves, one run of the phase each
+    assert ref["n"] == d["phase.main.refresh.count"] >= 2
+    assert fro["n"] == d.get("phase.main.frozen.count", 0)
+    assert meg["budget"] == 1000 * d["dispatch.mega_iterations"]
+    # Iter0 and twenty iterations, each solved once, or once more where a
+    # frozen attempt was thrown away
+    assert meg["n"] + fro["accepted"] + ref["n"] == 21
+    assert fro["n"] == fro["accepted"] + ref["declined"]
+    assert ref["cold"] == 1                          # Iter0
+    assert ref["signature"] == 1                     # the prox term arrives
+    assert d["host_sync.count"] == (fro["n"] + ref["n"]
+                                    + d["dispatch.megasteps"] + 1)
+    assert d.get("rescue.rows", 0) == 0
+    # the numbers the parent's program fetched on this run (PR 43)
+    assert d["host_sync.count"] == (13 if mega == 0 else 23)
+
+
+def test_a_segmented_solve_reports_no_sweeps(monkeypatch):
+    """A shape that ``segmented`` splits into several dispatches fetches
+    its LAST dispatch's iteration counter: such a solve leaves its rows,
+    its reason and whether it was kept, and neither sweeps nor a budget to
+    hold them against."""
+    from tpusppy.models import farmer
+    from tpusppy.opt.ph import PH
+    from tpusppy.solvers import segmented
+
+    # a model throughput of one flop a second: every cap lands on its floor
+    monkeypatch.setattr(segmented, "_DISPATCH_EFF_FLOPS", 1.0)
+    monkeypatch.setattr(segmented, "_DISPATCH_EFF_FLOPS_DENSE", 1.0)
+    options = {"defaultPHrho": 1.0, "PHIterLimit": 6, "convthresh": -1.0,
+               "display_progress": False, "solver_refresh_every": 4,
+               "solver_options": {"megastep": 1}}
+    with metrics.window() as win:
+        PH(options, farmer.scenario_names_creator(3), farmer.scenario_creator,
+           scenario_creator_kwargs={"num_scens": 3}).ph_main()
+    d = win.deltas()
+    assert d["dispatch.segmented_solves"] == (
+        d["phase.main.refresh.count"] + d["phase.main.frozen.count"])
+    kinds = _solve_kinds(d)
+    ref, fro = kinds["main", "refresh"], kinds["main", "frozen"]
+    assert ref["n"] + fro["accepted"] == 7 and fro["n"] >= 1
+    for f in (ref, fro):
+        assert f["rows"] == 3 * f["n"] and f["rows_done"] <= f["rows"]
+        assert f.get("sweeps", 0) == f.get("budget", 0) == 0
+
+
+def test_wheel_outcomes_add_up_per_cylinder():
+    """PH hub + Lagrangian + XhatShuffle on a three-scenario farmer: what
+    each cylinder's solves say of themselves adds up."""
+    from tpusppy.cylinders import (LagrangianOuterBound, PHHub,
+                                   XhatShuffleInnerBound)
+    from tpusppy.models import farmer
+    from tpusppy.opt.ph import PH
+    from tpusppy.phbase import PHBase
+    from tpusppy.spin_the_wheel import WheelSpinner
+    from tpusppy.xhat_eval import Xhat_Eval
+
+    def opt_kwargs():
+        return {"options": {"defaultPHrho": 1.0, "PHIterLimit": 24,
+                            "convthresh": -1.0, "solver_refresh_every": 4,
+                            "xhat_looper_options": {"scen_limit": 3}},
+                "all_scenario_names": farmer.scenario_names_creator(3),
+                "scenario_creator": farmer.scenario_creator,
+                "scenario_creator_kwargs": {"num_scens": 3}}
+
+    hub = {"hub_class": PHHub,
+           "hub_kwargs": {"options": {"rel_gap": 1e-9, "linger_secs": 5.0}},
+           "opt_class": PH, "opt_kwargs": opt_kwargs()}
+    spokes = [{"spoke_class": LagrangianOuterBound, "spoke_kwargs": {},
+               "opt_class": PHBase, "opt_kwargs": opt_kwargs()},
+              {"spoke_class": XhatShuffleInnerBound, "spoke_kwargs": {},
+               "opt_class": Xhat_Eval, "opt_kwargs": opt_kwargs()}]
+    with metrics.window() as win:
+        WheelSpinner(hub, spokes).run()
+    d = win.deltas()
+    kinds = _solve_kinds(d)
+    cylinders = {c for c, _ in kinds}
+    assert {"hub", "spoke1", "spoke2"} <= cylinders <= {
+        "hub", "spoke1", "spoke2", "main"}
+    for (cyl, kind), f in kinds.items():
+        assert 0 <= f["sweeps"] <= f["budget"], (cyl, kind, f)
+        if kind != "mega":
+            assert 0 <= f["rows_done"] <= f["rows"] == 3 * f["n"]
+            assert 0 <= f["rows_in_tol"] <= f["rows"]
+            # a solve, a phase: the seconds beside the outcome (the four
+            # reasons sum to the refresh solves)
+            assert f["n"] == d[f"phase.{cyl}.{kind}.count"]
+    for cyl in cylinders:
+        fro = kinds.get((cyl, "frozen"), {"n": 0, "accepted": 0})
+        ref = kinds.get((cyl, "refresh"))
+        if ref is None:
+            assert fro["n"] == fro["accepted"]
+            continue
+        assert fro["n"] == fro["accepted"] + ref["declined"], cyl
+    meg = kinds["hub", "mega"]
+    assert meg["n"] == d["dispatch.mega_iterations"] > 0
+    assert meg["budget"] == 1000 * meg["n"]
+    assert meg["all_done"] <= meg["n"]
+    assert meg["rejected_sweeps"] <= 1000 * d.get(
+        "megastep.rejected_iterations", 0)
+    # XhatShuffle evaluates cold; the Lagrangian re-solves warm
+    assert kinds["spoke2", "refresh"]["cold"] >= 1
+    # candidates: each priced or refused
+    assert d["xhat.candidates"] >= 1
+    refused = d.get("xhat.infeasible", 0)
+    assert refused <= d["xhat.candidates"]
+    assert 1 <= d["xhat.improved"] <= d["xhat.candidates"] - refused
+    assert 1 <= d["hub.inner_bound_updates"] <= d["xhat.improved"]

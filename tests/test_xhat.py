@@ -64,6 +64,30 @@ class TestXhatEval:
         assert ev._fixed_lb is None  # restore_nonants ran
 
 
+    def test_candidates_are_counted_priced_or_refused(self):
+        """Three candidates, one of which plants 1200 acres on 500:
+        ``xhat.candidates`` is the refused ones plus the priced ones, and
+        the refused one is refused by every row."""
+        from tpusppy.obs import metrics
+
+        ev = make_eval(3)
+        cands = ([170.0, 80.0, 250.0], [400.0, 400.0, 400.0],
+                 [100.0, 100.0, 300.0])
+        with metrics.window() as win:
+            prices = [ev.evaluate(np.array(c)) for c in cands]
+        assert [np.isfinite(z) for z in prices] == [True, False, True]
+        assert prices[0] == pytest.approx(EF3, rel=1e-4)
+        d = win.deltas()
+        assert d["xhat.candidates"] == 3
+        assert d["xhat.infeasible"] == 1
+        assert d["xhat.candidates"] == d["xhat.infeasible"] + sum(
+            map(np.isfinite, prices))
+        assert d["xhat.infeasible_rows"] == d["xhat.infeasible_of_rows"] == 3
+        # a single scenario's price goes through no gate of the batch
+        ev.evaluate_one(np.array(cands[1]), 0)
+        assert metrics.value("xhat.candidates") == 3
+
+
 class TestDonorCache:
     def test_two_stage_single_donor(self):
         ev = make_eval(3)

@@ -17,6 +17,7 @@ import time
 
 import numpy as np
 
+from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..resilience import faults as _faults
 from ..resilience import supervisor as _supervisor
@@ -281,6 +282,7 @@ class InnerBoundNonantSpoke(_BoundNonantSpoke):
             self.best_inner_bound = float(candidate_inner_bound)
             self.bound = self.best_inner_bound
             self._cache_best_solution()
+        _metrics.inc("xhat.improved")
         return True
 
     def best_snapshot(self):

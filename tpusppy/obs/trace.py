@@ -22,6 +22,9 @@ profiler's trace (``jax.profiler.TraceAnnotation``, so the phase lands on
 the clock of the device's operations), the always-on registry
 (``phase.<cylinder>.<name>.{secs,count}``) and, when enabled, this ring.
 This is the only file of the program that names ``jax.profiler``.
+What a solve spent under those seconds and how it ended goes through
+:func:`outcome`: sums in the registry alone
+(``solve.<cylinder>.<kind>.<field>``).
 
 Enablement: ``TPUSPPY_TRACE=<path>`` in the environment turns tracing on
 at import and registers an atexit flush of ``<path>`` (Perfetto JSON)
@@ -325,6 +328,35 @@ def phase(name: str, **payload):
     ``.add()`` as for :func:`span`.  Not for fine-grained sites: those
     keep :func:`span` and its free disabled path."""
     return _Phase(name, payload)
+
+
+# ---------------------------------------------------------------------------
+# Outcomes: what a solve spent and how it ended (registry only).
+# ---------------------------------------------------------------------------
+_outcome_sites: dict = {}    # (track, kind) -> {field: counter}
+
+
+def outcome(kind: str, **fields):
+    """Add each of ``fields`` to the registry counter
+    ``solve.<cylinder>.<kind>.<field>``, the cylinder being the calling
+    thread's as :func:`phase` resolves it.  Where a phase says how long,
+    this says how much and how it ended (sweeps, budget, rows done,
+    accepted); every field is a sum.  Always on, registry only: no ring
+    event, no annotation.  A field passed as 0 still makes its counter,
+    so a dump shows the zero."""
+    track = getattr(_tls, "track", None) or "main"
+    # unlocked, as _phase_site: racing threads are handed the one counter
+    site = _outcome_sites.get((track, kind))
+    if site is None:
+        site = _outcome_sites[(track, kind)] = {}
+    for field, n in fields.items():
+        ctr = site.get(field)
+        if ctr is None:
+            cyl = track.split(":", 1)[0]
+            ctr = site[field] = _metrics.counter(
+                f"solve.{cyl}.{kind}.{field}")
+        if n:
+            ctr.inc(n)
 
 
 def record_span(track: str | None, name: str, t0: float, dur: float,

@@ -1279,7 +1279,9 @@ def precision_guard_trips(sol: BatchSolution, settings: ADMMSettings,
 def measure_pack(sol: BatchSolution):
     """Everything the host wheel iteration reads from one solve, as ONE
     flat device vector: ``[pri_res (S) | dua_res (S) | iters_max |
-    all_done | x.ravel (S*n)]``.
+    all_done | n_done | x.ravel (S*n)]`` (``n_done``: how many rows the
+    program's own stopping test passed, of which ``all_done`` is the
+    case ``n_done == S``).
 
     The amortized solve loop used to fetch ``x``, ``pri_res`` and
     ``dua_res`` separately (plus a ``stop_stats`` fetch when the
@@ -1295,24 +1297,29 @@ def measure_pack(sol: BatchSolution):
         sol.dua_res.astype(dt),
         sol.iters.max().astype(dt)[None],
         jnp.all(sol.done).astype(dt)[None],
+        jnp.sum(sol.done).astype(dt)[None],
         sol.x.astype(dt).reshape(-1),
     ])
 
 
-measure_pack = _aot.cached_program(measure_pack, "admm.measure_pack")
+# key_extra: the vector's layout, which the call's signature does not show
+measure_pack = _aot.cached_program(
+    measure_pack, "admm.measure_pack",
+    key_extra=("pri|dua|iters|all_done|n_done|x",))
 
 
 def measure_unpack(vec, S, n):
     """Split a fetched :func:`measure_pack` vector; returns a dict with
-    ``pri`` (S,), ``dua`` (S,), ``iters`` (int), ``all_done`` (bool) and
-    ``x`` (S, n)."""
+    ``pri`` (S,), ``dua`` (S,), ``iters`` (int), ``all_done`` (bool),
+    ``n_done`` (int) and ``x`` (S, n)."""
     vec = np.asarray(vec)
     return {
         "pri": vec[:S],
         "dua": vec[S:2 * S],
         "iters": int(vec[2 * S]),
         "all_done": bool(vec[2 * S + 1]),
-        "x": vec[2 * S + 2:].reshape(S, n),
+        "n_done": int(vec[2 * S + 2]),
+        "x": vec[2 * S + 3:].reshape(S, n),
     }
 
 

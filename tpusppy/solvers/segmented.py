@@ -59,6 +59,13 @@ def _frozen_refine_iters(st):
     return max(0, int(st.precision_refine_iters))
 
 
+def frozen_budget(st) -> int:
+    """Sweeps ONE frozen solve may spend: the ``max_iter`` that
+    :func:`solve_frozen_segmented` is sized to, plus the refinement
+    phase a lowered sweep mode appends."""
+    return int(st.max_iter) + _frozen_refine_iters(st)
+
+
 def _frozen_iter_secs(st, t_sweep):
     """Worst-case seconds of ONE frozen iteration: the full ``max_iter``
     sweep budget at the (possibly lowered) sweep precision, plus the
@@ -635,6 +642,28 @@ def _continue_frozen(frozen_fn, args, factors, sol, st_f, seg_f, budget,
         check_incoming=check_incoming, seg_flops=seg_flops)
 
 
+def _is_shared(args):
+    return getattr(args[2], "ndim", None) == 2
+
+
+def _caps(args, settings, shared):
+    """:func:`dispatch_segments` for a solve's ``args``."""
+    S, n, m = _shapes(args, shared)
+    return S, n, m, dispatch_segments(S, n, m, settings,
+                                      factor_batch=1 if shared else S,
+                                      sparse_factor=_sparse_factor(args))
+
+
+def one_dispatch(args, settings, adaptive=False) -> bool:
+    """Whether :func:`solve_frozen_segmented` (``adaptive``:
+    :func:`solve_factored_segmented`) runs these shapes as ONE dispatch:
+    only then is the solution's iteration counter the whole solve's (a
+    segmented solve's is its last dispatch's)."""
+    *_, (seg_r, seg_f) = _caps(args, settings, _is_shared(args))
+    return seg_f >= settings.max_iter and (
+        not adaptive or seg_r >= settings.max_iter)
+
+
 def solve_factored_segmented(frozen_fn, factored_fn, args, settings,
                              warm=None, shared=False, want_converged=True):
     """Adaptive solve + factors, segmented when the shapes demand it.
@@ -653,10 +682,8 @@ def solve_factored_segmented(frozen_fn, factored_fn, args, settings,
     Multi-controller callers drive the jitted sharded step with a
     deterministic schedule instead (see :func:`continue_frozen`).
     """
-    S, n, m = _shapes(args, shared)
-    seg_r, seg_f = dispatch_segments(S, n, m, settings,
-                                     factor_batch=1 if shared else S,
-                                     sparse_factor=_sparse_factor(args))
+    S, n, m, (seg_r, seg_f) = _caps(args, settings, shared)
+
     def _conv(s):
         return (bool(hostsync.fetch(s.done).all()) if want_converged
                 else None)
@@ -704,11 +731,9 @@ def solve_frozen_segmented(frozen_fn, args, factors, settings, warm=None,
     :func:`solve_factored_segmented`: the convergence fetch and the
     data-dependent continuation need addressable shards.
     """
-    shared = getattr(args[2], "ndim", None) == 2
-    S, n, m = _shapes(args, shared)
-    seg_r, seg_f = dispatch_segments(S, n, m, settings,
-                                     factor_batch=1 if shared else S,
-                                     sparse_factor=_sparse_factor(args))
+    shared = _is_shared(args)
+    S, n, m, (seg_r, seg_f) = _caps(args, settings, shared)
+
     def _conv(s):
         return (bool(hostsync.fetch(s.done).all()) if want_converged
                 else None)

@@ -212,6 +212,25 @@ def bucket_shared(sub) -> bool:
         and sub.num_scenarios > 1
 
 
+#: Why a refresh solve ran: the ``solve.<cylinder>.refresh.<reason>``
+#: counters, which sum to the refresh solves (``phase.<cylinder>.refresh
+#: .count``).
+_REFRESH_REASONS = ("cold", "signature", "age", "declined")
+
+
+def _refresh_reason(slot, sig, warm, refresh_every):
+    """Why a solve cannot try its slot's frozen factors (one of
+    :data:`_REFRESH_REASONS`), or ``None`` where it can."""
+    if not (refresh_every > 1 and warm and slot.get("warm") is not None
+            and slot.get("factors") is not None):
+        return "cold"
+    if slot.get("sig") != sig:
+        return "signature"
+    if slot.get("age", 0) >= refresh_every:
+        return "age"
+    return None
+
+
 def _certified_dual_eval(args):
     """(dvals, margin) — the weak-duality bound with its X-cap hardening
     margin (admm.dual_objective_margin: extends the certificate's validity
@@ -442,15 +461,14 @@ class SPOpt(SPBase):
         sol = meas = None
         from .solvers import segmented
 
-        if (refresh_every > 1 and warm and slot.get("warm") is not None
-                and slot.get("factors") is not None
-                and slot.get("sig") == sig
-                and slot.get("age", 0) < refresh_every):
+        S = np.shape(args[0])[0]
+        why = _refresh_reason(slot, sig, warm, refresh_every)
+        if why is None:
             # segmented: oversized sweep loops are split into bounded
             # dispatches (segmented's per-dispatch budget);
             # want_converged=False — the convergence vote rides the packed
             # measurement below instead of a separate done fetch
-            with _turns.hub_step(), _trace.span(None, "solve.frozen") as _sp:
+            with _turns.hub_step(), _trace.phase("frozen") as _sp:
                 cand, _ = segmented.solve_frozen_segmented(
                     frozen_fn, args, slot["factors"], self.admm_settings,
                     warm=slot["warm"], want_converged=False)
@@ -458,6 +476,12 @@ class SPOpt(SPBase):
                 if _trace.enabled():   # payload dicts only when tracing
                     _sp.add(iters=meas_c["iters"],
                             all_done=meas_c["all_done"])
+            # a segmented solve's counter is its LAST dispatch's: such a
+            # solve reports no sweeps (and no budget to hold them against)
+            spent = ({"sweeps": meas_c["iters"], "budget":
+                      segmented.frozen_budget(self.admm_settings)}
+                     if segmented.one_dispatch(args, self.admm_settings)
+                     else {})
             worst_c = float(max(np.max(meas_c["pri"]),
                                 np.max(meas_c["dua"])))
             if admm.precision_guard_trips(
@@ -478,20 +502,26 @@ class SPOpt(SPBase):
                     frozen_fn, args, slot["factors"], st_full,
                     warm=slot["warm"], want_converged=False)
                 meas_c = self._fetch_measure(cand)
+                if spent:   # one attempt still: it spent both solves'
+                    spent["sweeps"] += meas_c["iters"]
+                    spent["budget"] += segmented.frozen_budget(st_full)
             # accept when the sweep budget sufficed (converged to eps) OR
             # every scenario already sits inside the rescue-tolerance
             # ladder: an adaptive re-solve of a plateaued batch (UC prox
             # batches plateau at ~1e-3 primal no matter the budget) burns
             # a full factored solve per hub iteration for nothing — the
             # refresh cadence (slot age) re-solves adaptively anyway
-            tol_lp, tol_qp = self._straggler_tols()
-            tol_s = np.where(
-                np.any(np.asarray(args[1]) != 0.0, axis=-1), tol_qp, tol_lp)
-            if (meas_c["all_done"]
-                    or bool(np.all((meas_c["pri"] <= tol_s)
-                                   & (meas_c["dua"] <= tol_s)))):
+            n_in_tol = int(np.count_nonzero(
+                self._rows_in_tol(meas_c, args[1])[0]))
+            if meas_c["all_done"] or n_in_tol == S:
                 sol, meas = cand, meas_c
                 slot["age"] = slot.get("age", 0) + 1
+            else:
+                why = "declined"
+            _trace.outcome(
+                "frozen", count=1, rows=S, rows_done=meas_c["n_done"],
+                rows_in_tol=n_in_tol, accepted=int(sol is not None),
+                **spent)
         if sol is None:
             # the REFRESH runs full precision end to end — including its
             # segmented frozen continuations and polish finale — both by
@@ -526,6 +556,16 @@ class SPOpt(SPBase):
             # operating point — the mixed-precision guard's reference
             slot["ref_worst"] = float(
                 max(np.max(meas["pri"]), np.max(meas["dua"])))
+            # what the device's solve spent and left, before any rescue
+            # (whose check counts rows_in_tol); how often is the phase's
+            # count
+            spent = ({"sweeps": meas["iters"], "budget":
+                      max(1, st_adpt.restarts) * st_adpt.max_iter}
+                     if segmented.one_dispatch(args, st_adpt, adaptive=True)
+                     else {})
+            _trace.outcome(
+                "refresh", rows=S, rows_done=meas["n_done"],
+                **{r: int(r == why) for r in _REFRESH_REASONS}, **spent)
             sol, meas = self._rescue_stragglers(
                 sol, args[0], args[1], args[5], args[6],
                 batch=rescue_batch, meas=meas)
@@ -621,6 +661,17 @@ class SPOpt(SPBase):
             tol_qp = max(1e-2, tol_lp)
         return tol_lp, tol_qp
 
+    def _rows_in_tol(self, meas, q2):
+        """``(ok, is_qp)``, both (S,): the rows of a fetched measurement
+        whose two residuals stand inside :meth:`_straggler_tols` (the
+        frozen attempt's acceptance test and the rescue's selection are
+        this one mask), and which rows carry a quadratic term."""
+        tol_lp, tol_qp = self._straggler_tols()
+        is_qp = np.any(np.asarray(q2) != 0.0, axis=-1)
+        tol_s = np.where(is_qp, tol_qp, tol_lp)
+        # <= so that NaN residuals (diverged solves) stand outside
+        return (meas["pri"] <= tol_s) & (meas["dua"] <= tol_s), is_qp
+
     def _rescue_stragglers(self, sol, q, q2, lb, ub, batch=None, meas=None):
         """Host-exact re-solve of the few scenarios batched ADMM left
         unconverged.  Returns ``(sol, meas)``.
@@ -646,14 +697,10 @@ class SPOpt(SPBase):
             meas = self._fetch_measure(sol)
         if not self.options.get("straggler_rescue", True):
             return sol, meas
-        tol_lp, tol_qp = self._straggler_tols()
-        pri = meas["pri"]
-        dua = meas["dua"]
-        q2_np = np.asarray(q2)
-        is_qp = np.any(q2_np != 0.0, axis=-1)
-        tol_s = np.where(is_qp, tol_qp, tol_lp)
-        # negated <= so NaN residuals (diverged solves) are selected too
-        bad = np.flatnonzero(~(pri <= tol_s) | ~(dua <= tol_s))
+        ok, is_qp = self._rows_in_tol(meas, q2)
+        bad = np.flatnonzero(~ok)
+        # the one mask a refresh's measurement is held to
+        _trace.outcome("refresh", rows_in_tol=ok.size - bad.size)
         if bad.size == 0:
             return sol, meas
         with _trace.phase("rescue", rows=int(bad.size)):
@@ -765,7 +812,8 @@ class SPOpt(SPBase):
             global_toc(
                 f"straggler rescue: {n_resc}/{b.num_scenarios} scenarios "
                 "re-solved host-exact", self.options.get("verbose", False))
-        meas = dict(meas, x=x, pri=pri, dua=dua, all_done=bool(done.all()))
+        meas = dict(meas, x=x, pri=pri, dua=dua, all_done=bool(done.all()),
+                    n_done=int(np.count_nonzero(done)))
         return (sol._replace(x=x, z=z, y=y, yx=yx, pri_res=pri, dua_res=dua,
                              done=done, raw=(x, z, y, yx)), meas)
 
@@ -1005,11 +1053,7 @@ class SPOpt(SPBase):
         self._factors_age += executed
         sf = (segmented.SPARSE_DISPATCH_FACTOR
               if isinstance(arr.A, SparseA) else 1.0)
-        sweeps = float(np.mean(meas["iters"][:executed])) if executed else 0.0
-        # a rejected iterate (refresh_hit) is dispatched-but-discarded
-        # work; its stats sit at index ``executed`` of the packed arrays
-        rej = (float(meas["iters"][executed])
-               if meas["refresh_hit"] and executed < n_req else None)
+        sweeps, rej = self._megastep_outcome(meas, n_req)
         segmented.bill_megastep(S, n, m, executed, sweeps, sparse_factor=sf,
                                 rejected_sweeps=rej)
         if meas.get("bound_computed"):
@@ -1047,6 +1091,30 @@ class SPOpt(SPBase):
             self._factors_age = max(self._factors_age, refresh_every)
             _metrics.inc("megastep.refresh_hits")
         return meas
+
+    def _megastep_outcome(self, meas, n_req: int):
+        """What one megastep window spent, from its fetched measurement:
+        records ``trace.outcome("mega", ...)`` (how many iterations it
+        executed is ``dispatch.mega_iterations``) and returns ``(sweeps,
+        rej)`` for :func:`~tpusppy.solvers.segmented.bill_megastep`: the
+        mean sweeps of an executed iteration, and the sweeps of the iterate
+        the in-scan acceptance test discarded, or None.  Homogeneous and
+        bucketed windows alike (a bucketed window's counter is the
+        cross-bucket max)."""
+        from .solvers import segmented
+
+        executed = meas["executed"]
+        iters = meas["iters"][:executed]
+        # a rejected iterate (refresh_hit) is dispatched-but-discarded
+        # work; its stats sit at index ``executed`` of the packed arrays
+        rej = (float(meas["iters"][executed])
+               if meas["refresh_hit"] and executed < n_req else None)
+        _trace.outcome(
+            "mega", sweeps=int(np.sum(iters)),
+            budget=executed * segmented.frozen_budget(self.admm_settings),
+            all_done=int(np.count_nonzero(meas["all_done"][:executed])),
+            rejected_sweeps=int(rej or 0))
+        return (float(np.mean(iters)) if executed else 0.0), rej
 
     def _mega_arrays_bucketed(self, dt):
         """Per-bucket :class:`~tpusppy.parallel.sharded.PHArrays` tuple
@@ -1231,10 +1299,7 @@ class SPOpt(SPBase):
                 for i in range(executed))
             if guard:
                 _metrics.inc("precision.guard_trips")
-        sweeps = float(np.mean(meas["iters"][:executed])) if executed \
-            else 0.0
-        rej = (float(meas["iters"][executed])
-               if meas["refresh_hit"] and executed < n_req else None)
+        sweeps, rej = self._megastep_outcome(meas, n_req)
         # loop-invariant: the threshold-ladder resolution behind this is
         # a per-bucket scan + verdict lookup, not per-bucket billing work
         pass_evals = (self._inwheel_pass_evals()
@@ -1503,11 +1568,17 @@ class SPOpt(SPBase):
         floors its scaled primal residual around 1e-4.  A solver run at loose
         eps (e.g. via the Gapper schedule) cannot certify feasibility tighter
         than its own tolerance, so the floor scales with eps_rel."""
+        ok = self.feasible_rows(tol)
+        return 1.0 if ok is None else float(self.probs @ ok)
+
+    def feasible_rows(self, tol=None):
+        """(S,) the rows :meth:`feas_prob` counts as feasible, or ``None``
+        before any solve."""
         if tol is None:
             tol = self._inwheel_feas_tol()   # the ONE gate tolerance
         if self.pri_res is None:
-            return 1.0
-        return float(self.probs @ (self.pri_res < tol))
+            return None
+        return np.asarray(self.pri_res) < tol
 
     def infeas_prob(self, tol=None) -> float:
         return 1.0 - self.feas_prob(tol)

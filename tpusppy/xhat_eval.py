@@ -434,7 +434,13 @@ class Xhat_Eval(SPOpt):
         """Expected objective at the fixed candidate; +inf if any scenario is
         infeasible (xhat_eval.py:293-330 + feas_prob check)."""
         x = self._fix_and_solve(nonant_cache)
+        _metrics.inc("xhat.candidates")
         if self.feas_prob() < 1.0 - 1e-9:
+            # refused, and by how many rows of how many
+            bad = ~self.feasible_rows()
+            _metrics.inc("xhat.infeasible")
+            _metrics.inc("xhat.infeasible_rows", int(np.count_nonzero(bad)))
+            _metrics.inc("xhat.infeasible_of_rows", bad.size)
             return np.inf
         return float(self.probs @ self.batch.objective(x))
 
