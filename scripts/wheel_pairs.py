@@ -39,7 +39,9 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COUNTER_METRICS = ("legacy_iter_s", "mega_window_s", "hub_blocked_pct",
                    "spoke_passes_per_iter", "rescued_rows_per_iter",
                    "hub_sync_ms_per_iter", "refresh_lanes_pct",
-                   "refresh_lanes_inverse_pct")
+                   "refresh_lanes_inverse_pct", "hub_sweeps_per_iter",
+                   "spoke_sweeps_per_iter", "sweep_budget_spent_pct",
+                   "solve_rows_done_pct", "sweep_width_pct")
 
 
 def one(root, workload, seed, seconds):

@@ -350,12 +350,7 @@ class TestPolishOnLanesSolve:
         qs, q2s, As, cls, cus, lbs, ubs, _, (x, z, y, yx) = admm._scale(
             c, q2, A, cl, cu, lb, ub, D, E, cost, None,
             (s64.x, s64.z, s64.y, s64.yx), dt)
-        inf = jnp.full((self.S,), jnp.inf, dt)
-        one = jnp.ones((self.S,), dt)
-        state = admm._IterState(
-            x, z, jnp.clip(x, lbs, ubs), y, yx, inf, inf, one, one,
-            jnp.zeros((), jnp.int32), jnp.asarray(jnp.inf, dt),
-            jnp.zeros((), jnp.int32))
+        state = admm._start_state(x, z, jnp.clip(x, lbs, ubs), y, yx)
         xla, lanes = self.both_paths(
             lambda: admm._polish(state, qs, q2s, As, cls, cus, lbs, ubs,
                                  masks, F32), request)

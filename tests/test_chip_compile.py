@@ -261,6 +261,12 @@ def test_wheel_megastep_compiles_at_the_served_farmer_shape(one_chip,
     mem = compiled.memory_analysis()
     print("wheel_megastep farmer S=1000 x4 memory_analysis:", mem)
     assert _has_mosaic_kernel(compiled)
+    # the sweep loop narrows (admm._admm_core): the kernel once at the full
+    # width of 8 blocks and once at its rung of 2 blocks of 128
+    from tpusppy.solvers import admm
+
+    assert admm._rung_width(FARMER_S, 128) == 256
+    assert compiled.as_text().count("tpu_custom_call") == 2
     # one program's footprint must sit far inside one v5e chip (16 GiB)
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)

@@ -835,8 +835,12 @@ def test_measure_pack_round_trips_n_done(engine, vote):
         sol = sol._replace(done=jnp.asarray(vote, bool))
     S, n = sol.x.shape
     vec = np.asarray(admm.measure_pack(sol))
-    assert vec.shape == (S * (n + 2) + 3,)
+    assert vec.shape == (S * (n + 2) + 6,)
     meas = admm.measure_unpack(vec, S, n)
+    # how much of the batch was still being swept: a batch this small has
+    # no narrower rung, and the shared engine's loop has none at any size
+    assert meas["narrow_sweeps"] == 0
+    assert meas["row_sweeps"] == meas["full_row_sweeps"] == 20 * S
     done = np.asarray(sol.done)
     assert meas["n_done"] == int(done.sum())
     assert meas["all_done"] == bool(done.all())

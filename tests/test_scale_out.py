@@ -146,7 +146,7 @@ class TestLeanMegastep:
         full = sharded.megastep_measure_len(4, S, n, K)
         lean = sharded.megastep_measure_len(4, S, n, K, pack="lean")
         assert full - lean == S * n + 2 * S * K
-        assert lean == 6 * 4 + 2 + 3 * S
+        assert lean == sharded.MEGA_STATS * 4 + 2 + 3 * S
 
     def test_lean_pack_device_parity(self):
         """The lean program returns the SAME device state as the full

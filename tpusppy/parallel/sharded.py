@@ -277,7 +277,8 @@ def _solver_fns_for(st: ADMMSettings, mesh, axis):
 
     if mesh is not None:
         sp = jax.sharding.PartitionSpec(axis)
-        sol_spec = admm.BatchSolution(*([sp] * 8), raw=(sp, sp, sp, sp))
+        sol_spec = admm.BatchSolution(
+            *([sp] * 8), raw=(sp, sp, sp, sp), narrow=sp, swept=sp)
         fac_spec = admm.Factors(*([sp] * 7))
         refresh_solve = _shard_map(
             local_refresh, mesh, in_specs=(sp,) * 11,
@@ -457,7 +458,7 @@ def make_ph_step_pair(nonant_idx: np.ndarray, settings: ADMMSettings,
             if mesh is not None:
                 sp = jax.sharding.PartitionSpec(axis)
                 sol_spec = admm.BatchSolution(
-                    *([sp] * 8), raw=(sp, sp, sp, sp))
+                    *([sp] * 8), raw=(sp, sp, sp, sp), narrow=sp, swept=sp)
                 fac_spec = admm.Factors(*([sp] * 7))
                 local_polish = _shard_map(
                     local_polish, mesh,
@@ -733,10 +734,24 @@ def megastep_measure_len(n_iters: int, S: int, n: int, K: int,
     on the window's final device state — compatible with BOTH packs (the
     bound pass emits scalars only); ``int_sweep=True`` is the batched
     integer variant (doc/integer.md) with its longer tail."""
-    base = 6 * n_iters + 2 + 3 * S
+    base = MEGA_STATS * n_iters + 2 + 3 * S
     if pack != "lean":
         base += S * n + 2 * S * K
     return base + bound_pack_len(bounds, int_sweep)
+
+
+# a megastep's per-iteration stats block, row by row (the solo, bucketed
+# and tenant packs alike): the last three are ``admm.width_counters``
+_STATS_ROWS = ("conv", "eobj", "pri_max", "dua_max", "iters",
+               "all_done") + admm.WIDTH_FIELDS
+MEGA_STATS = len(_STATS_ROWS)
+
+
+def _stats_rows(per) -> dict:
+    """A fetched (MEGA_STATS, N) stats block as named per-iteration rows."""
+    out = dict(zip(_STATS_ROWS, per))
+    out["all_done"] = out["all_done"] != 0.0
+    return out
 
 
 def unpack_bound_tail(out: dict, vec, int_sweep: bool = False) -> dict:
@@ -769,8 +784,10 @@ def megastep_unpack(vec, n_iters: int, S: int, n: int, K: int,
 
     Returns per-iteration arrays (length ``n_iters``; entries past
     ``executed`` are inert zeros — the early-exit mask froze those steps):
-    ``conv``, ``eobj``, ``pri_max``, ``dua_max``, ``iters``, ``all_done``;
-    the ``executed`` iteration count; the ``refresh_hit`` flag (an
+    ``conv``, ``eobj``, ``pri_max``, ``dua_max``, ``iters``, ``all_done``,
+    ``narrow_sweeps``, ``row_sweeps``, ``full_row_sweeps`` (how much of
+    the batch the iteration's solve was still sweeping:
+    ``admm.width_counters``); the ``executed`` iteration count; the ``refresh_hit`` flag (an
     iterate failed the in-scan acceptance test — its update was masked
     out, exactly as the serial protocol discards a rejected frozen
     solve, and the host must refresh; index ``executed`` of the per-
@@ -784,14 +801,13 @@ def megastep_unpack(vec, n_iters: int, S: int, n: int, K: int,
     bound tail (:func:`unpack_bound_tail`)."""
     vec = np.asarray(vec)
     N = n_iters
-    per = vec[:6 * N].reshape(6, N)
-    off = 6 * N
+    per = vec[:MEGA_STATS * N].reshape(MEGA_STATS, N)
+    off = MEGA_STATS * N
     executed = int(vec[off])
     refresh_hit = bool(vec[off + 1])
     off += 2
     out = {
-        "conv": per[0], "eobj": per[1], "pri_max": per[2],
-        "dua_max": per[3], "iters": per[4], "all_done": per[5] != 0.0,
+        **_stats_rows(per),
         "executed": executed, "refresh_hit": refresh_hit,
         "pri": vec[off:off + S], "dua": vec[off + S:off + 2 * S],
         "done": vec[off + 2 * S:off + 3 * S] != 0.0,
@@ -1029,7 +1045,8 @@ def make_wheel_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
                     jnp.max(sol.pri_res).astype(dt),
                     jnp.max(sol.dua_res).astype(dt),
                     jnp.max(sol.iters).astype(dt),
-                    jnp.all(sol.done).astype(dt)])
+                    jnp.all(sol.done).astype(dt),
+                    *admm.width_counters(sol).astype(dt)])
                 # rejected iterate: mask the whole STATE update (the
                 # serial protocol discards the failed frozen solve and
                 # re-solves adaptively — the host's refresh does that).
@@ -1048,7 +1065,7 @@ def make_wheel_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
                         stats)
 
             def dead_fn(op):
-                return op, jnp.zeros((6,), dt)
+                return op, jnp.zeros((MEGA_STATS,), dt)
 
             return jax.lax.cond(
                 live, live_fn, dead_fn,
@@ -1110,7 +1127,8 @@ def make_wheel_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
     # serving of a self-certifying wheel stays zero-miss.
     return aot_cache.cached_program(
         mega, "wheel_megastep",
-        key_extra=(settings, n_iters, bool(donate), axis, pack,
+        # _STATS_ROWS: the packed vector's layout, which no argument shows
+        key_extra=(_STATS_ROWS, settings, n_iters, bool(donate), axis, pack,
                    # the rounding constants exist only in the bounds=True
                    # program — keying them while bounds are off would
                    # recompile a byte-identical megastep over an inert
@@ -1141,7 +1159,7 @@ def bucketed_megastep_measure_len(n_iters: int, shapes, K: int,
     ``bounds`` appends the :func:`bound_pack_len` in-wheel bound tail
     (``int_sweep`` = the longer batched-integer variant)."""
     S = sum(s for s, _ in shapes)
-    return (6 * n_iters + 2 + 3 * S
+    return (MEGA_STATS * n_iters + 2 + 3 * S
             + sum(s * n for s, n in shapes) + 2 * S * K
             + bound_pack_len(bounds, int_sweep))
 
@@ -1159,11 +1177,10 @@ def bucketed_megastep_unpack(vec, n_iters: int, shapes, K: int,
     the trailing in-wheel bound tail (:func:`unpack_bound_tail`)."""
     vec = np.asarray(vec)
     N = n_iters
-    per = vec[:6 * N].reshape(6, N)
-    off = 6 * N
+    per = vec[:MEGA_STATS * N].reshape(MEGA_STATS, N)
+    off = MEGA_STATS * N
     out = {
-        "conv": per[0], "eobj": per[1], "pri_max": per[2],
-        "dua_max": per[3], "iters": per[4], "all_done": per[5] != 0.0,
+        **_stats_rows(per),
         "executed": int(vec[off]), "refresh_hit": bool(vec[off + 1]),
     }
     off += 2
@@ -1344,7 +1361,10 @@ def make_bucketed_wheel_megastep(nonant_idx: np.ndarray,
                         [jnp.max(s.dua_res) for s in sols])).astype(dt),
                     jnp.max(jnp.stack(
                         [jnp.max(s.iters) for s in sols])).astype(dt),
-                    all_done.astype(dt)])
+                    all_done.astype(dt),
+                    # the buckets' counters add up (each bucket's full
+                    # count is its own sweeps x its own rows)
+                    *sum(admm.width_counters(s) for s in sols).astype(dt)])
                 sel = lambda a, b: jnp.where(ok, a, b)
                 new_sts = jax.tree.map(sel, new_sts, sts)
                 new_pris = tuple(sel(s.pri_res, p)
@@ -1360,7 +1380,7 @@ def make_bucketed_wheel_megastep(nonant_idx: np.ndarray,
                         stats)
 
             def dead_fn(op):
-                return op, jnp.zeros((6,), dt)
+                return op, jnp.zeros((MEGA_STATS,), dt)
 
             return jax.lax.cond(
                 live, live_fn, dead_fn,
@@ -1492,7 +1512,7 @@ def make_bucketed_wheel_megastep(nonant_idx: np.ndarray,
     # homogeneous megakernel
     return aot_cache.cached_program(
         mega, "bucketed_megastep",
-        key_extra=(settings, n_iters, bool(donate), axis,
+        key_extra=(_STATS_ROWS, settings, n_iters, bool(donate), axis,
                    # bounds-only constants keyed only when the bound-pass
                    # variant is compiled (see the homogeneous kernel);
                    # the integer-sweep ladder/scope likewise only when
@@ -1515,7 +1535,7 @@ def tenant_megastep_measure_len(n_iters: int, S: int, n_tenants: int,
                                 bounds: bool = False) -> int:
     """Length of the packed TENANT-BATCHED measurement
     (:func:`make_tenant_megastep`): per-tenant per-iteration stat blocks
-    (``6 * n_iters`` each, tenant-major), per-tenant ``executed``/
+    (``MEGA_STATS * n_iters`` each, tenant-major), per-tenant ``executed``/
     ``refresh`` scalars, the per-tenant final-iterate ``pri``/``dua``/
     ``done`` diagnostics, and — with ``bounds=True`` — ONE
     :data:`BOUND_PACK_LEN` bound pack PER TENANT (per-tenant masked
@@ -1525,7 +1545,7 @@ def tenant_megastep_measure_len(n_iters: int, S: int, n_tenants: int,
     The pack is LEAN by construction (the big-S wheel posture): x/W/xbars
     stay in the returned per-slot device states, fetched explicitly at
     join/evict/termination boundaries."""
-    return n_tenants * (6 * n_iters + 2 + 3 * S) \
+    return n_tenants * (MEGA_STATS * n_iters + 2 + 3 * S) \
         + (n_tenants * BOUND_PACK_LEN if bounds else 0)
 
 
@@ -1533,8 +1553,8 @@ def tenant_megastep_unpack(vec, n_iters: int, S: int, n_tenants: int,
                            bounds: bool = False) -> dict:
     """Split a fetched :func:`make_tenant_megastep` measurement into
     PER-TENANT lists (index = slot): ``conv``/``eobj``/``pri_max``/
-    ``dua_max``/``iters``/``all_done`` are lists of length-``n_iters``
-    arrays, ``executed``/``refresh_hit`` lists of scalars, ``pri``/
+    ``dua_max``/``iters``/``all_done``/``narrow_sweeps``/``row_sweeps``/
+    ``full_row_sweeps`` are lists of length-``n_iters`` arrays, ``executed``/``refresh_hit`` lists of scalars, ``pri``/
     ``dua``/``done`` lists of (S,) arrays; ``bounds=True`` adds
     ``bound_computed``/``bound_outer``/``bound_inner_obj``/
     ``bound_inner_feas``/``bound_sweeps`` lists (each tenant's own
@@ -1542,19 +1562,14 @@ def tenant_megastep_unpack(vec, n_iters: int, S: int, n_tenants: int,
     (``executed == 0``)."""
     vec = np.asarray(vec)
     N, T = n_iters, n_tenants
-    out = {k: [] for k in ("conv", "eobj", "pri_max", "dua_max", "iters",
-                           "all_done", "executed", "refresh_hit",
-                           "pri", "dua", "done")}
+    out = {k: [] for k in _STATS_ROWS + ("executed", "refresh_hit",
+                                         "pri", "dua", "done")}
     off = 0
     for _t in range(T):
-        per = vec[off:off + 6 * N].reshape(6, N)
-        off += 6 * N
-        out["conv"].append(per[0])
-        out["eobj"].append(per[1])
-        out["pri_max"].append(per[2])
-        out["dua_max"].append(per[3])
-        out["iters"].append(per[4])
-        out["all_done"].append(per[5] != 0.0)
+        per = vec[off:off + MEGA_STATS * N].reshape(MEGA_STATS, N)
+        off += MEGA_STATS * N
+        for k, row in _stats_rows(per).items():
+            out[k].append(row)
         out["executed"].append(int(vec[off]))
         out["refresh_hit"].append(bool(vec[off + 1]))
         off += 2
@@ -1672,7 +1687,8 @@ def make_tenant_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
                         jnp.max(sol.pri_res).astype(dt),
                         jnp.max(sol.dua_res).astype(dt),
                         jnp.max(sol.iters).astype(dt),
-                        jnp.all(sol.done).astype(dt)])
+                        jnp.all(sol.done).astype(dt),
+                        *admm.width_counters(sol).astype(dt)])
                     sel = lambda a, b: jnp.where(ok, a, b)
                     new_st = jax.tree.map(sel, new_st, st)
                     return ((new_st, sel(sol.pri_res, pri),
@@ -1683,7 +1699,7 @@ def make_tenant_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
                             stats)
 
                 def dead_fn(op):
-                    return op, jnp.zeros((6,), dt)
+                    return op, jnp.zeros((MEGA_STATS,), dt)
 
                 live_t = live_m[t] & (~stps[t]) & (k < n_live_t[t])
                 (st2, pri2, dua2, done2, ex2, stp2, rf2), stats_t = \
@@ -1711,7 +1727,7 @@ def make_tenant_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
         carry0 = (states, infs, infs, falses, zeros_i, zeros_b, zeros_b)
         (sts, pris, duas, dones, exs, _, rfs), stats = jax.lax.scan(
             body, carry0, jnp.arange(n_iters, dtype=jnp.int32))
-        # stats is (n_iters, T, 6); pack tenant-major so each tenant's
+        # stats is (n_iters, T, MEGA_STATS); pack tenant-major so each tenant's
         # block reads exactly like a solo measurement prefix
         parts = []
         for t in range(T):
@@ -1746,7 +1762,7 @@ def make_tenant_megastep(nonant_idx: np.ndarray, settings: ADMMSettings,
     # any tenant mix of the family at that K
     return aot_cache.cached_program(
         mega, "tenant_megastep",
-        key_extra=(settings, n_iters, bool(donate), axis,
+        key_extra=(_STATS_ROWS, settings, n_iters, bool(donate), axis,
                    (float(xhat_threshold),
                     None if int_mask is None
                     else aot_cache.array_digest(int_mask))

@@ -177,6 +177,14 @@ class BatchSolution(NamedTuple):
     raw: tuple         # pre-polish (x, z, y, yx) — the ONLY valid warm start
     # (polished states are exact-KKT candidates, not consistent ADMM
     # iterates; feeding them back as warm starts destabilizes later solves)
+    # how much of the batch was still being swept (``_admm_core``'s
+    # narrowing; None from the shared-A engine, whose one loop runs at full
+    # width: ``width_counters`` reads either)
+    narrow: jax.Array | None = None  # (S,) sweeps run below full width
+    # (same for all, like ``iters``)
+    swept: jax.Array | None = None   # (S,) sweeps each row was carried
+    # through: their sum over ``iters`` x S is the share of the full-width
+    # work done
 
 
 class _Scaling(NamedTuple):
@@ -438,6 +446,27 @@ class _IterState(NamedTuple):
     k: jax.Array
     best: jax.Array   # scalar: best batch-worst eps-normalized residual
     stall: jax.Array  # scalar int32: consecutive non-improving windows
+    # what the narrowing sweep loop spent (``_admm_core``), summed over a
+    # solve's core runs: no core run resets them
+    narrow: jax.Array  # scalar int32: sweeps (of k) run below full width
+    swept: jax.Array   # (S,) int32: sweeps each row was carried through
+    since: jax.Array   # (S,) int32: sweeps the row has now passed the test
+    # for, checkpoint after checkpoint (0: it does not pass); kept only
+    # where the loop has a narrower rung to leave the row out of.  An
+    # adaptive solve's restarts carry it on: a row that is done keeps its
+    # rho (``_solve_scaled``: ``where(done, base, new_base)``, no boost), so
+    # the next restart would sweep it on at the factors it was left at
+
+
+def _start_state(x0, z0, zx0, y0, yx0):
+    """The iterate a solve starts from: nothing measured, nothing swept."""
+    S, dt = x0.shape[0], x0.dtype
+    inf = jnp.full((S,), jnp.inf, dt)
+    one = jnp.ones((S,), dt)
+    zero = jnp.zeros((), jnp.int32)
+    return _IterState(x0, z0, zx0, y0, yx0, inf, inf, one, one, zero,
+                      jnp.asarray(jnp.inf, dt), zero, zero,
+                      jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32))
 
 
 def _done_mask(pri, dua, prinorm, duanorm, st: ADMMSettings):
@@ -491,9 +520,54 @@ def _plateau_update(s, pri, dua, prinorm, duanorm, st: ADMMSettings,
     return best, stall
 
 
-def _admm_core(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
-               st: ADMMSettings, P=None, prec=None):
-    """Inner ADMM sweep at fixed rho. Returns final state.
+def _sweep_block(st: ADMMSettings, S, m, n, P=None, prec=None):
+    """``(bs, kprec)``: the block of ``pallas_kernels.fused_sweeps`` at a
+    batch of S (None: XLA's sweep) and the precision the kernel stores its
+    matrices in."""
+    from . import pallas_kernels
+
+    if isinstance(st.use_pallas, str) and st.use_pallas != "auto":
+        raise ValueError(
+            f"use_pallas must be True, False, or 'auto'; got "
+            f"{st.use_pallas!r} (strings other than 'auto' would silently "
+            f"force the kernel on)")
+    # dense-kernel precision: "default" stores the matrices in bf16 (halved
+    # VMEM per scenario, bf16-rounded operands); "high" keeps f32 — the
+    # kernel's VPU contractions run full f32 anyway, so bf16x3 has nothing
+    # to save there (the kernel is then at least as accurate as the mode
+    # asks; see pallas_kernels.fused_sweeps)
+    kprec = "default" if prec == "default" else "highest"
+    if st.use_pallas == "auto":
+        bs = pallas_kernels.usable(S, m, n, P=P, precision=kprec)
+        if bs is not None and bs < S and bs > 512:
+            bs32 = (pallas_kernels.usable(S, m, n, P=P)
+                    if kprec == "default" else bs)
+            if (kprec == "default" and bs32 is not None
+                    and not (bs32 < S and bs32 > 512)):
+                # bf16 storage WIDENED an f32-ACCEPTED block into the
+                # measured-loss band: clamp back to the band's top — the
+                # mode's VMEM dividend must never turn the kernel OFF for
+                # a shape the f32 path accepts.  Shapes the f32 heuristic
+                # itself rejects stay rejected (the loss regime was
+                # measured; bf16 storage doesn't re-litigate it).
+                bs = 512
+            else:
+                bs = None      # measured-loss regime (many coarse blocks)
+    elif st.use_pallas:
+        bs = pallas_kernels.usable(S, m, n, P=P, precision=kprec)
+    else:
+        bs = None
+    return bs, kprec
+
+
+def _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
+                st: ADMMSettings, P=None, prec=None, leave_at=None):
+    """The sweep loop at fixed rho and at the width of its arguments:
+    runs until the budget is spent or every row is done; under
+    ``_admm_core``'s cascade (``leave_at`` not None) it keeps
+    ``state.since`` and, where ``leave_at`` is not 0, also leaves once at
+    most that many rows are not settled (``_settled``).  Returns final
+    state.
 
     ``prec``: None keeps the legacy (ambient-precision) program
     byte-for-byte; a mode string runs the SWEEP matvecs at that precision
@@ -564,6 +638,9 @@ def _admm_core(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
         # OSQP termination: eps_abs + eps_rel * residual-scale norms
         done = _done_mask(s.pri, s.dua, s.prinorm, s.duanorm, st)
         go = (s.k < st.max_iter) & ~jnp.all(done)
+        if leave_at:
+            # the rung of ``_admm_core`` holds the rows left
+            go = go & (jnp.sum(~_settled(s)) > leave_at)
         if st.sweep_plateau_rtol > 0:
             go = go & (s.stall < 2)
         return go
@@ -574,37 +651,7 @@ def _admm_core(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
     from . import pallas_kernels
 
     S, m, n = A.shape
-    if isinstance(st.use_pallas, str) and st.use_pallas != "auto":
-        raise ValueError(
-            f"use_pallas must be True, False, or 'auto'; got "
-            f"{st.use_pallas!r} (strings other than 'auto' would silently "
-            f"force the kernel on)")
-    # dense-kernel precision: "default" stores the matrices in bf16 (halved
-    # VMEM per scenario, bf16-rounded operands); "high" keeps f32 — the
-    # kernel's VPU contractions run full f32 anyway, so bf16x3 has nothing
-    # to save there (the kernel is then at least as accurate as the mode
-    # asks; see pallas_kernels.fused_sweeps)
-    kprec = "default" if prec == "default" else "highest"
-    if st.use_pallas == "auto":
-        bs = pallas_kernels.usable(S, m, n, P=P, precision=kprec)
-        if bs is not None and bs < S and bs > 512:
-            bs32 = (pallas_kernels.usable(S, m, n, P=P)
-                    if kprec == "default" else bs)
-            if (kprec == "default" and bs32 is not None
-                    and not (bs32 < S and bs32 > 512)):
-                # bf16 storage WIDENED an f32-ACCEPTED block into the
-                # measured-loss band: clamp back to the band's top — the
-                # mode's VMEM dividend must never turn the kernel OFF for
-                # a shape the f32 path accepts.  Shapes the f32 heuristic
-                # itself rejects stay rejected (the loss regime was
-                # measured; bf16 storage doesn't re-litigate it).
-                bs = 512
-            else:
-                bs = None      # measured-loss regime (many coarse blocks)
-    elif st.use_pallas:
-        bs = pallas_kernels.usable(S, m, n, P=P, precision=kprec)
-    else:
-        bs = None
+    bs, kprec = _sweep_block(st, S, m, n, P, prec)
     if bs is not None:
         Kinv, K = LK
         tT = lambda a: jnp.transpose(a, (1, 2, 0))
@@ -647,12 +694,121 @@ def _admm_core(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
             best, stall = _plateau_update(s, pri, dua, prinorm, duanorm, st)
         else:
             best, stall = s.best, s.stall
+        since = s.since
+        if leave_at is not None:
+            since = jnp.where(_done_mask(pri, dua, prinorm, duanorm, st),
+                              since + max(1, st.check_every), 0)
         return (_IterState(x, z, zx, y, yx, pri, dua, prinorm, duanorm,
-                           s.k + max(1, st.check_every), best, stall), Ax)
+                           s.k + max(1, st.check_every), best, stall,
+                           s.narrow, s.swept, since), Ax)
 
     Ax0 = jnp.einsum("smn,sn->sm", A, state.x)
     state, _ = jax.lax.while_loop(cont, multi_step, (state, Ax0))
     return state
+
+
+# an ``_IterState``'s fields that hold a row to each scenario
+_ROW_FIELDS = ("x", "z", "zx", "y", "yx", "pri", "dua", "prinorm",
+               "duanorm", "swept", "since")
+
+# Sweeps a row goes on being swept after it first passes the test, before
+# the rung may leave it out.  The test passes a row at float32's residual
+# floor, but its objective is then good to 3e-7..8e-7 in the median and
+# 4e-6..5e-5 at p99, and the sweeps the all-or-nothing loop went on giving
+# it are what every check of the benchmark was set on.  On the chip (farmer
+# S=1000, `scripts/done_trajectory.py --linger`; PERF.md section 6, PR 45)
+# 128 more read 1.1e-7..1.3e-7 and 7e-7..8.5e-7, 192 more are within a
+# tenth of what the whole budget gives (1e-7 and 6e-7..7e-7), and 256 are
+# there: the floor plus a third.  At 256 the wheel's check values are the
+# parent's; at 512 it runs 14% slower for the same values.  Left out at
+# the checkpoint that passes them, rows put the hub's refresh eight times
+# further from HiGHS (`prox_gap_rel` 4e-3 for 5e-4) and the megastep
+# rejected one iterate in eight.
+_LINGER = 256
+
+
+def _settled(s: _IterState):
+    """Rows the rung may leave out: done, and for ``_LINGER`` sweeps
+    running."""
+    return s.since >= _LINGER
+
+# the block of XLA's sweep, which has none of its own: the lanes of one
+# vector register
+_LANES = 128
+
+
+def _rung_width(S, bs):
+    """The width below S at which ``_admm_core`` runs its sweep loop again,
+    chosen at trace time: a quarter of S's blocks (the kernel's ``bs``, 128
+    rows under XLA's sweep), one at least.  0 where S is one block or less:
+    S = 1000 at a block of 128 gives 256, S = 300 gives 128, S <= 128 none.
+    One rung and not a ladder of halvings: each is one more traced copy of
+    the loop body and one more Mosaic kernel in every program that holds
+    the loop, and on the chip farmer's wheel read 4% faster with this one
+    than with rungs at a half and at one block, its set-up 8% over the
+    parent's for 27% (PERF.md section 6, PR 45)."""
+    unit = bs or _LANES
+    blocks = -(-S // unit)
+    return max(1, blocks // 4) * unit if blocks > 1 else 0
+
+
+def _admm_core(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
+               st: ADMMSettings, P=None, prec=None):
+    """Inner ADMM sweeps at fixed rho, narrowing to the rows that are not
+    done.  Returns final state.
+
+    Stopping is per row in the mathematics and all-or-nothing in a batched
+    loop: a row that passed the test at sweep 100 would be swept on for as
+    long as any other row needs.  So the loop runs twice, at the full width
+    and at one narrower static width (``_rung_width``): the full-width loop
+    also leaves once the rows not settled (``_settled``: done for
+    ``_LINGER`` sweeps running) fit the rung; those rows are gathered
+    (settled rows fill what is left of the rung) with all the sweep reads
+    for them, the same loop carries ``k`` on against the same budget at
+    that width, and the rows are scattered back.  A row left behind keeps
+    the iterate and the residuals it had.  The test, eps and the budget are
+    the loop's own throughout, and it still ends when every row passes;
+    ``best``/``stall`` start again at the rung as at a restart.
+
+    Where S is one block or less there is no rung: one ``while_loop``, no
+    gather.  ``state.narrow`` and ``state.swept`` count what ran where.
+    ``prec``: see ``_sweep_loop``."""
+    S, m, n = A.shape
+    width = _rung_width(S, _sweep_block(st, S, m, n, P, prec)[0])
+    k0 = state.k
+    state = _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
+                        st, P, prec, leave_at=width or None)
+    state = state._replace(swept=state.swept + (state.k - k0))
+    if not width:
+        return state
+    # everything the sweep reads, a row to each scenario
+    rows = (q, q2, A, cl, cu, lb, ub, LK, rho_a,
+            jnp.broadcast_to(rho_x, (S, n)), P)
+    settled = _settled(state)
+
+    def rung(state):
+        # the rows not settled first, each group in its own order
+        idx = jnp.argsort(settled, stable=True)[:width]
+        sq, sq2, sA, scl, scu, slb, sub, sLK, sra, srx, sP = (
+            jax.tree.map(lambda a: a[idx], rows))
+        part = {f: getattr(state, f)[idx] for f in _ROW_FIELDS}
+        out = _sweep_loop(
+            sq, sq2, sA, scl, scu, slb, sub,
+            state._replace(best=jnp.full_like(state.best, jnp.inf),
+                           stall=jnp.zeros_like(state.stall), **part),
+            sLK, sra, srx, st, sP, prec, leave_at=0)
+        back = {f: getattr(state, f).at[idx].set(getattr(out, f))
+                for f in _ROW_FIELDS}
+        back["swept"] = state.swept.at[idx].add(out.k - state.k)
+        return out._replace(narrow=state.narrow + (out.k - state.k), **back)
+
+    # budget left, some row not done, and the rows not settled fit the rung
+    # (more of them than fit: the loop above left on its plateau exit, and
+    # nobody sweeps on)
+    go = ((state.k < st.max_iter) & (jnp.sum(~settled) <= width)
+          & ~jnp.all(_done_mask(state.pri, state.dua, state.prinorm,
+                                state.duanorm, st)))
+    return jax.lax.cond(go, rung, lambda s: s, state)
 
 
 def _solve_scaled(q, q2, A, cl, cu, lb, ub, warm, masks, st: ADMMSettings,
@@ -688,11 +844,7 @@ def _solve_scaled(q, q2, A, cl, cu, lb, ub, warm, masks, st: ADMMSettings,
         zx0 = jnp.clip(x0, lb, ub)
 
     base0 = jnp.full((S,), st.rho, dt)
-    inf = jnp.full((S,), jnp.inf, dt)
-    one = jnp.ones((S,), dt)
-    state0 = _IterState(x0, z0, zx0, y0, yx0, inf, inf, one, one,
-                        jnp.zeros((), jnp.int32),
-                        jnp.asarray(jnp.inf, dt), jnp.zeros((), jnp.int32))
+    state0 = _start_state(x0, z0, zx0, y0, yx0)
 
     # Restart loop as a lax.scan with the factorization in the CARRY, so
     # the LAST rho vectors + factorization survive to become the reusable
@@ -1094,6 +1246,7 @@ def _solve_impl(c, q2, A, cl, cu, lb, ub, settings, warm, P=None,
         done=_done_mask(state.pri, state.dua, state.prinorm,
                         state.duanorm, settings),
         raw=raw,
+        narrow=jnp.broadcast_to(state.narrow, (S,)), swept=state.swept,
     )
     if want_factors:
         return sol, Factors(D=D, E=E, cost=cost, rho_a=rho_a, rho_x=rho_x,
@@ -1162,12 +1315,7 @@ def _solve_frozen_impl(c, q2, A, cl, cu, lb, ub, factors: Factors, warm,
         yx0 = jnp.zeros((S, n), dt)
     else:
         x0, z0, y0, yx0 = warm
-    zx0 = jnp.clip(x0, lbs, ubs)
-    inf = jnp.full((S,), jnp.inf, dt)
-    one = jnp.ones((S,), dt)
-    state0 = _IterState(x0, z0, zx0, y0, yx0, inf, inf, one, one,
-                        jnp.zeros((), jnp.int32),
-                        jnp.asarray(jnp.inf, dt), jnp.zeros((), jnp.int32))
+    state0 = _start_state(x0, z0, jnp.clip(x0, lbs, ubs), y0, yx0)
 
     LK = (factors.Kinv, factors.K)
 
@@ -1193,6 +1341,7 @@ def _solve_frozen_impl(c, q2, A, cl, cu, lb, ub, factors: Factors, warm,
         done=_done_mask(state.pri, state.dua, state.prinorm,
                         state.duanorm, settings),
         raw=raw,
+        narrow=jnp.broadcast_to(state.narrow, (S,)), swept=state.swept,
     )
 
 
@@ -1275,13 +1424,34 @@ def precision_guard_trips(sol: BatchSolution, settings: ADMMSettings,
     return worst > bar
 
 
+# how much of the batch a solve was still sweeping: the names of
+# ``width_counters``' three, in its order, wherever they travel (the packed
+# measurements, ``trace.outcome``'s ``solve.<cylinder>.<kind>.<field>``)
+WIDTH_FIELDS = ("narrow_sweeps", "row_sweeps", "full_row_sweeps")
+
+
+def width_counters(sol: BatchSolution):
+    """:data:`WIDTH_FIELDS` of one solve, a (3,) vector (traceable): the
+    sweeps run below full width, the sum over all sweeps of the width each
+    ran at, and sweeps x S beside it, what the same sweeps cost at full
+    width.  The shared-A engine's loop never narrows: 0 and twice the full
+    count."""
+    dt = sol.pri_res.dtype
+    full = sol.iters.max().astype(dt) * sol.iters.shape[0]
+    if sol.swept is None:
+        return jnp.stack([jnp.zeros((), dt), full, full])
+    return jnp.stack([sol.narrow.max().astype(dt),
+                      jnp.sum(sol.swept.astype(dt)), full])
+
+
 @jax.jit
 def measure_pack(sol: BatchSolution):
     """Everything the host wheel iteration reads from one solve, as ONE
     flat device vector: ``[pri_res (S) | dua_res (S) | iters_max |
-    all_done | n_done | x.ravel (S*n)]`` (``n_done``: how many rows the
-    program's own stopping test passed, of which ``all_done`` is the
-    case ``n_done == S``).
+    all_done | n_done | narrow_sweeps | row_sweeps | full_row_sweeps |
+    x.ravel (S*n)]`` (``n_done``: how many rows the program's own stopping
+    test passed, of which ``all_done`` is the case ``n_done == S``; the
+    three after it: :func:`width_counters`).
 
     The amortized solve loop used to fetch ``x``, ``pri_res`` and
     ``dua_res`` separately (plus a ``stop_stats`` fetch when the
@@ -1298,6 +1468,7 @@ def measure_pack(sol: BatchSolution):
         sol.iters.max().astype(dt)[None],
         jnp.all(sol.done).astype(dt)[None],
         jnp.sum(sol.done).astype(dt)[None],
+        width_counters(sol),
         sol.x.astype(dt).reshape(-1),
     ])
 
@@ -1305,13 +1476,14 @@ def measure_pack(sol: BatchSolution):
 # key_extra: the vector's layout, which the call's signature does not show
 measure_pack = _aot.cached_program(
     measure_pack, "admm.measure_pack",
-    key_extra=("pri|dua|iters|all_done|n_done|x",))
+    key_extra=("pri|dua|iters|all_done|n_done|width3|x",))
 
 
 def measure_unpack(vec, S, n):
     """Split a fetched :func:`measure_pack` vector; returns a dict with
     ``pri`` (S,), ``dua`` (S,), ``iters`` (int), ``all_done`` (bool),
-    ``n_done`` (int) and ``x`` (S, n)."""
+    ``n_done`` (int), the three :data:`WIDTH_FIELDS` (int) and ``x``
+    (S, n)."""
     vec = np.asarray(vec)
     return {
         "pri": vec[:S],
@@ -1319,7 +1491,9 @@ def measure_unpack(vec, S, n):
         "iters": int(vec[2 * S]),
         "all_done": bool(vec[2 * S + 1]),
         "n_done": int(vec[2 * S + 2]),
-        "x": vec[2 * S + 3:].reshape(S, n),
+        **{k: int(v) for k, v in zip(WIDTH_FIELDS,
+                                     vec[2 * S + 3:2 * S + 6])},
+        "x": vec[2 * S + 6:].reshape(S, n),
     }
 
 

@@ -218,6 +218,12 @@ def bucket_shared(sub) -> bool:
 _REFRESH_REASONS = ("cold", "signature", "age", "declined")
 
 
+def _width_spent(meas) -> dict:
+    """How much of the batch a solve was still sweeping, as its fetch
+    carries it (``admm.width_counters``) and ``trace.outcome`` takes it."""
+    return {k: meas[k] for k in admm.WIDTH_FIELDS}
+
+
 def _refresh_reason(slot, sig, warm, refresh_every):
     """Why a solve cannot try its slot's frozen factors (one of
     :data:`_REFRESH_REASONS`), or ``None`` where it can."""
@@ -479,7 +485,8 @@ class SPOpt(SPBase):
             # a segmented solve's counter is its LAST dispatch's: such a
             # solve reports no sweeps (and no budget to hold them against)
             spent = ({"sweeps": meas_c["iters"], "budget":
-                      segmented.frozen_budget(self.admm_settings)}
+                      segmented.frozen_budget(self.admm_settings),
+                      **_width_spent(meas_c)}
                      if segmented.one_dispatch(args, self.admm_settings)
                      else {})
             worst_c = float(max(np.max(meas_c["pri"]),
@@ -505,6 +512,8 @@ class SPOpt(SPBase):
                 if spent:   # one attempt still: it spent both solves'
                     spent["sweeps"] += meas_c["iters"]
                     spent["budget"] += segmented.frozen_budget(st_full)
+                    for k, v in _width_spent(meas_c).items():
+                        spent[k] += v
             # accept when the sweep budget sufficed (converged to eps) OR
             # every scenario already sits inside the rescue-tolerance
             # ladder: an adaptive re-solve of a plateaued batch (UC prox
@@ -560,7 +569,8 @@ class SPOpt(SPBase):
             # (whose check counts rows_in_tol); how often is the phase's
             # count
             spent = ({"sweeps": meas["iters"], "budget":
-                      max(1, st_adpt.restarts) * st_adpt.max_iter}
+                      max(1, st_adpt.restarts) * st_adpt.max_iter,
+                      **_width_spent(meas)}
                      if segmented.one_dispatch(args, st_adpt, adaptive=True)
                      else {})
             _trace.outcome(
@@ -1113,7 +1123,8 @@ class SPOpt(SPBase):
             "mega", sweeps=int(np.sum(iters)),
             budget=executed * segmented.frozen_budget(self.admm_settings),
             all_done=int(np.count_nonzero(meas["all_done"][:executed])),
-            rejected_sweeps=int(rej or 0))
+            rejected_sweeps=int(rej or 0),
+            **{k: int(np.sum(meas[k][:executed])) for k in admm.WIDTH_FIELDS})
         return (float(np.mean(iters)) if executed else 0.0), rej
 
     def _mega_arrays_bucketed(self, dt):
@@ -1261,7 +1272,7 @@ class SPOpt(SPBase):
         S, n_max = b.num_scenarios, b.num_vars
         meas = {k: bmeas[k] for k in (
             "conv", "eobj", "pri_max", "dua_max", "iters", "all_done",
-            "executed", "refresh_hit")}
+            "executed", "refresh_hit") + admm.WIDTH_FIELDS}
         if bounds:
             meas.update({k: bmeas[k] for k in (
                 "bound_computed", "bound_outer", "bound_inner_obj",
