@@ -22,6 +22,11 @@ rho and settings the configuration's) and prints JSON lines:
             ``--reps``)
   solve     the refresh and the frozen solve as the cell runs them, warm,
             in milliseconds (median of ``--reps``)
+  split     (``--split``) one traced frozen solve at 256 and at S rows: the
+            device's microseconds a checkpoint (one step of the sweep
+            loop's ``while``) in the sweep kernel (``%fused_sweeps*`` on
+            the trace's ``XLA Ops`` line) and in every other operation that
+            ran as often, each by name
   linger    (``--linger``) what the sweeps after the test are worth: at the
             iterations of ``--probe`` the frozen solve again on the one
             all-or-nothing loop at every budget of ``LADDER``, each row's
@@ -106,6 +111,69 @@ def one_loop(admm, fn):
         return fn()
     finally:
         admm._rung_width = width
+
+
+def op_split(run, out_dir):
+    """{operation: [runs, device ns]} of one traced ``run()``, from the
+    ``XLA Ops`` line of the trace's device plane (an event's name is its
+    whole HLO line: what stands before `` = `` is kept)."""
+    import glob
+    import shutil
+    import tempfile
+
+    import jax
+
+    jax.block_until_ready(run())
+    d = tempfile.mkdtemp(dir=out_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    jax.block_until_ready(run())
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    ops = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if (not plane.name.startswith("/device:TPU:")
+                or "SparseCore" in plane.name):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                c = ops.setdefault(ev.name.split(" = ", 1)[0][:80], [0, 0.0])
+                c[0] += 1
+                c[1] += float(ev.duration_ns)
+    shutil.rmtree(d)
+    return ops
+
+
+def say_split(width, ops, check_every):
+    """One ``split`` line: the kernel against the rest of a step.  An
+    operation of the loop's body or condition ran once a checkpoint, as the
+    kernel did (the condition once more); the ``while`` itself and what ran
+    once a solve are left out."""
+    calls = sum(c for name, (c, _) in ops.items() if "fused_sweeps" in name)
+    if not calls:
+        return say(split=width, kernel_calls=0,
+                   ops={k: v for k, v in sorted(ops.items())})
+    kernel = sum(ns for name, (_, ns) in ops.items()
+                 if "fused_sweeps" in name)
+    rest = {name: (c, ns) for name, (c, ns) in ops.items()
+            if c >= calls and "fused_sweeps" not in name
+            and not name.startswith("%while")}
+    rest_ns = sum(ns for _, ns in rest.values())
+    say(split=width, checkpoints=calls,
+        kernel_us_per_checkpoint=kernel / calls / 1e3,
+        rest_us_per_checkpoint=rest_ns / calls / 1e3,
+        kernel_us_per_sweep=kernel / calls / 1e3 / check_every,
+        rest_us_per_sweep=rest_ns / calls / 1e3 / check_every,
+        rest_ops=len(rest),
+        rest_by_op={name: [c, round(ns / calls / 1e3, 3)]
+                    for name, (c, ns) in sorted(
+                        rest.items(), key=lambda kv: -kv[1][1])},
+        once={name: round(ns / 1e3, 1) for name, (c, ns) in ops.items()
+              if (c < calls or name.startswith("%while")) and ns >= 50})
 
 
 def quantiles(err):
@@ -356,6 +424,9 @@ def tables(args):
             done=int(np.count_nonzero(sol.done)),
             us_per_sweep=1e3 * ms / max(int(sol.iters[0]), 1),
             **width_of(sol))
+        if args.split and width in (256, S):
+            say_split(width, op_split(run, args.out),
+                      max(1, st.check_every))
 
 
 def wheel(args, root):
@@ -416,6 +487,7 @@ def main():
     ap.add_argument("--wheel", action="store_true")
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--linger", action="store_true")
+    ap.add_argument("--split", action="store_true")
     ap.add_argument("--reference", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out"))
     ap.add_argument("--width", type=int, default=None)

@@ -41,7 +41,8 @@ COUNTER_METRICS = ("legacy_iter_s", "mega_window_s", "hub_blocked_pct",
                    "hub_sync_ms_per_iter", "refresh_lanes_pct",
                    "refresh_lanes_inverse_pct", "hub_sweeps_per_iter",
                    "spoke_sweeps_per_iter", "sweep_budget_spent_pct",
-                   "solve_rows_done_pct", "sweep_width_pct")
+                   "solve_rows_done_pct", "sweep_width_pct",
+                   "sweep_kernel_checkpoint_pct")
 
 
 def one(root, workload, seed, seconds):

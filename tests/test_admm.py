@@ -1,6 +1,8 @@
 """Batched ADMM solver vs HiGHS ground truth (property tests per SURVEY §4:
 in-repo solver lets us test against EF/LP ground truth instead of smoke-only)."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -463,3 +465,132 @@ class TestInverseOnLanesSolve:
         metrics = _two_refreshes(self.S, use_pallas)
         assert metrics.value("refresh.lanes_inverse") == counted
         assert metrics.value("refresh.lanes_linalg") == counted
+
+
+@pytest.fixture
+def sweep_kernel_interpreted(monkeypatch):
+    """``pallas_kernels.usable`` answered as on the TPU and the sweep kernel
+    run through the Pallas interpreter, so that ``admm._sweep_block`` picks
+    a block by its own rule: the test steers what the program observes,
+    the program has no option for it."""
+    import functools
+
+    from tpusppy.solvers import pallas_kernels as pk
+
+    usable, sweeps = pk.usable, pk.fused_sweeps
+    monkeypatch.setattr(
+        pk, "usable", lambda S, m, n, platform=None, **kw: usable(
+            S, m, n, "tpu", **kw))
+    monkeypatch.setattr(pk, "fused_sweeps",
+                        functools.partial(sweeps, interpret=True))
+
+
+class TestKernelCheckpoint:
+    """``admm.kernel_checkpoint``, the host's twin of the choice that makes
+    a step of the dense engine's sweep loop one kernel call, and the
+    counter ``refresh.kernel_checkpoint`` spopt keeps by it."""
+
+    @pytest.mark.parametrize("S, m, n, use_pallas, expect", [
+        (1000, 28, 44, "auto", 128),    # farmer x4: the cells' shape
+        (256, 28, 44, "auto", 128),     # its rung
+        (1000, 28, 44, True, 128),
+        (3, 7, 11, "auto", 3),          # one block: the whole batch
+        (1000, 28, 44, False, None),
+        (10000, 7, 11, "auto", None),   # many coarse blocks: measured loss
+        (100, 4626, 2928, True, None),  # past the VMEM budget
+    ])
+    def test_twin_follows_sweep_block(self, sweep_kernel_interpreted, S, m,
+                                      n, use_pallas, expect):
+        from tpusppy.solvers import admm
+
+        st = dataclasses.replace(F32, use_pallas=use_pallas)
+        assert admm._sweep_block(st, S, m, n)[0] == expect
+        assert admm.kernel_checkpoint(st, S, m, n) == (expect is not None)
+
+    @pytest.mark.parametrize("use_pallas", ["auto", True, False])
+    def test_false_off_the_tpu(self, use_pallas):
+        """The tests' backend: ``_sweep_block`` picks no block, XLA's sweep
+        runs, and the twin says so."""
+        from tpusppy.solvers import admm
+
+        st = dataclasses.replace(F32, use_pallas=use_pallas)
+        for S, m, n in ((1000, 28, 44), (3, 7, 11)):
+            assert admm._sweep_block(st, S, m, n)[0] is None
+            assert not admm.kernel_checkpoint(st, S, m, n)
+
+    def test_false_with_a_dense_P(self, sweep_kernel_interpreted):
+        from tpusppy.solvers import admm
+
+        assert admm._sweep_block(F32, 1000, 28, 44, P=object())[0] is None
+
+    @pytest.mark.parametrize("use_pallas, counted", [("auto", 2), (True, 2),
+                                                     (False, 0)])
+    def test_refresh_counter(self, sweep_kernel_interpreted, use_pallas,
+                             counted):
+        """Two refresh solves of a float32 farmer PH with every step of
+        their sweep loops one (interpreted) kernel call: counted once a
+        refresh, never under ``use_pallas=False``; the elimination kernel's
+        counters keep their own gates (the CPU: 0)."""
+        metrics = _two_refreshes(32, use_pallas)
+        assert metrics.value("refresh.kernel_checkpoint") == counted
+        assert metrics.value("refresh.lanes_inverse") == 0
+
+    def test_counter_is_zero_on_xla(self):
+        metrics = _two_refreshes(8, "auto")
+        assert metrics.value("refresh.kernel_checkpoint") == 0
+
+
+# sha256 (first 16 hex digits) of ``lower(...).as_text()`` of the dense
+# engine's two solve programs on the CPU, farmer with 3 scenarios, at the
+# commit before PR 48 (0d46a39): the XLA sweep's loop body is that
+# commit's byte for byte, whatever the kernel path became
+XLA_SWEEP_TEXT = {
+    ("float64", "solve_batch_factored"): "bfdf0de6b7ecd734",
+    ("float64", "solve_batch_frozen"): "fcfbcf84bd7223e8",
+    ("float32", "solve_batch_factored"): "ce70dbe0a9b4b443",
+    ("float32", "solve_batch_frozen"): "b90d92099cc7e486",
+    ("float32_default", "solve_batch_factored"): "ce70dbe0a9b4b443",
+    ("float32_default", "solve_batch_frozen"): "a0b3f78670bab760",
+}
+
+
+@pytest.mark.parametrize("mode", ["float64", "float32", "float32_default"])
+def test_xla_sweep_lowers_to_the_text_it_had(mode):
+    """The lowered text of ``solve_batch_factored`` and
+    ``solve_batch_frozen`` on the CPU at a tier-1 shape (farmer, 3
+    scenarios; float64, float32, and float32 under the lowered sweep
+    mode) hashes to what the parent of PR 48 lowered: the XLA sweep is
+    untouched by the kernel path's one-call step."""
+    import functools
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpusppy.solvers import admm
+
+    S = 3
+    b = ScenarioBatch.from_problems(
+        [farmer.scenario_creator(nm, num_scens=S)
+         for nm in farmer.scenario_names_creator(S)])
+    st = {"float64": ADMMSettings(), "float32": F32,
+          "float32_default": dataclasses.replace(
+              F32, sweep_precision="default")}[mode]
+    dt = st.jdtype()
+    assert not admm.kernel_checkpoint(st, S, b.num_rows, b.num_vars)
+    args = tuple(jnp.asarray(v, dt)
+                 for v in (b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub))
+    _, factors = jax.eval_shape(
+        functools.partial(admm.solve_batch_factored._jitted, settings=st),
+        *args)
+    warm = tuple(jax.ShapeDtypeStruct(s, dt)
+                 for s in ((S, b.num_vars), (S, b.num_rows),
+                           (S, b.num_rows), (S, b.num_vars)))
+    lowered = {
+        "solve_batch_factored": admm.solve_batch_factored._jitted.lower(
+            *args, settings=st),
+        "solve_batch_frozen": admm.solve_batch_frozen._jitted.lower(
+            *args, factors, settings=st, warm=warm)}
+    for prog, low in lowered.items():
+        digest = hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
+        assert digest == XLA_SWEEP_TEXT[mode, prog], (mode, prog, digest)

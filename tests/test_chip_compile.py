@@ -96,22 +96,94 @@ def _has_mosaic_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_sweeps_compiles_at_the_served_farmer_shape(one_chip, chip32):
+@pytest.mark.parametrize("S", [FARMER_S, 256], ids=["full", "rung"])
+def test_fused_sweeps_compiles_at_the_served_farmer_shape(S, one_chip,
+                                                          chip32):
+    """The sweep kernel with its checkpoint (the residual rows of the
+    iterate it ends on) at the cells' batch and at the rung the loop
+    narrows to."""
     b = _farmer_batch(2)
-    S, m, n = FARMER_S, b.num_rows, b.num_vars
+    m, n = b.num_rows, b.num_vars
     bs = pk.usable(S, m, n, platform="tpu")
     # lane-dim blocks: the whole batch or a multiple of 128 (Mosaic tiling)
     assert bs is not None and (bs == S or bs % 128 == 0)
     mat = lambda d0, d1: _spec((d0, d1, S), one_chip)
     vec = lambda d0: _spec((d0, S), one_chip)
-    args = (vec(n), mat(m, n), mat(n, m), mat(n, n), mat(n, n),
+    args = (vec(n), vec(n), mat(m, n), mat(n, m), mat(n, n), mat(n, n),
             vec(m), vec(m), vec(n), vec(n), vec(m), vec(n),
-            vec(n), vec(m), vec(n), vec(m), vec(n), vec(m))
+            vec(n), vec(m), vec(n), vec(m), vec(n))
     st = ADMMSettings(**F32)
     compiled = pk.fused_sweeps.lower(
         *args, n_sweeps=max(1, st.check_every), n_refine=st.solve_refine,
         sigma=float(st.sigma), alpha=float(st.alpha), bs=bs).compile()
     assert _has_mosaic_kernel(compiled)
+
+
+def _while_bodies(text):
+    """{body computation's name: [(opcode, result type), ...]} of every
+    ``while`` in a compiled program's text, bookkeeping left out (operands
+    taken apart and put together, constants, bitcasts)."""
+    import re
+
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            rhs = line.split(" = ", 1)[1]
+            op = re.search(r"\)?\s([a-z\-]+)\(", rhs)
+            comps[name].append((op.group(1) if op else "?",
+                                rhs.split(" ", 1)[0]))
+    quiet = {"parameter", "get-tuple-element", "tuple", "constant",
+             "bitcast"}
+    return {body: [(op, ty) for op, ty in comps[body] if op not in quiet]
+            for body in re.findall(r"body=%?([\w.\-]+)", text)}
+
+
+def test_frozen_solve_makes_one_kernel_call_a_step(one_chip, chip32):
+    """``solve_batch_frozen`` at the cells' shape, compiled for the chip:
+    both of its sweep loops (the full width of 1000 rows and the rung of
+    256) hold the one ``fused_sweeps`` custom call and at most six other
+    operations a step, none of them a layout change of an (S, n) or (S, m)
+    state array: the loop carries the kernel's layout.  (The text at the
+    commit before PR 48: 10 such copies and 6 fusions in either body.)"""
+    import functools
+
+    from tpusppy.solvers import admm
+
+    b = _farmer_batch(2)
+    S, m, n = FARMER_S, b.num_rows, b.num_vars
+    st = ADMMSettings(**F32)
+    assert admm.kernel_checkpoint(st, S, m, n)
+    sh = lambda *shape: _spec(shape, one_chip)
+    args = (sh(S, n), sh(S, n), sh(S, m, n), sh(S, m), sh(S, m), sh(S, n),
+            sh(S, n))
+    warm = (sh(S, n), sh(S, m), sh(S, m), sh(S, n))
+    _, factors = jax.eval_shape(
+        functools.partial(admm.solve_batch_factored._jitted, settings=st),
+        *args)
+    text = admm.solve_batch_frozen._jitted.lower(
+        *args, _on_chip(factors, one_chip), settings=st,
+        warm=warm).compile().as_text()
+    loops = [ops for ops in _while_bodies(text).values()
+             if any(op == "custom-call" for op, _ in ops)]
+    assert len(loops) == 2          # the full width and the rung
+    widths = set()
+    for ops in loops:
+        print("sweep loop body:", [op for op, _ in ops])
+        (call,) = [ty for op, ty in ops if op == "custom-call"]
+        widths.add(int(call.split("f32[%d," % n)[1].split("]")[0]))
+        others = [(op, ty) for op, ty in ops if op != "custom-call"]
+        assert len(others) <= 6, others
+        state = {"f32[%d,%d]" % dims for w in (S, 256) for d in (n, m)
+                 for dims in ((w, d), (d, w))}
+        assert not [(op, ty) for op, ty in others if op.startswith("copy")
+                    and any(shape in ty for shape in state)], others
+    assert widths == {S, 256}
 
 
 def test_lanes_solve_compiles_at_the_served_farmer_polish_shape(one_chip,

@@ -4,9 +4,13 @@ The kernel (solvers/pallas_kernels.py) fuses ``n_sweeps`` ADMM sweeps with
 all matrices VMEM-resident, in scenario-on-lanes layout.  On CPU it runs
 through the Pallas interpreter, which pins its semantics to the reference
 XLA sweep recurrence of ``admm._admm_core`` exactly (same relaxation, same
-incremental-Ax carry, same refinement) — so kernel drift is caught without
-TPU hardware (VERDICT r2 weak #4).
+refinement; the XLA sweep's incrementally carried ``Ax`` feeds its residuals
+alone, and the kernel makes its own from a true product) — so kernel drift
+is caught without TPU hardware (VERDICT r2 weak #4).
 """
+
+import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -82,20 +86,21 @@ def test_fused_sweeps_matches_xla_sweep():
 
     tT = lambda a: jnp.transpose(jnp.asarray(a), (1, 2, 0))
     outs = pallas_kernels.fused_sweeps(
-        jnp.asarray(q).T, tT(A), jnp.transpose(jnp.asarray(A), (2, 1, 0)),
-        tT(Kinv), tT(K),
+        jnp.asarray(q).T, jnp.zeros((n, S)), tT(A),
+        jnp.transpose(jnp.asarray(A), (2, 1, 0)), tT(Kinv), tT(K),
         jnp.asarray(cl).T, jnp.asarray(cu).T,
         jnp.asarray(lb).T, jnp.asarray(ub).T,
         jnp.asarray(rho_a).T, jnp.asarray(rho_x).T,
         jnp.asarray(x).T, jnp.asarray(z).T, jnp.asarray(zx).T,
-        jnp.asarray(y).T, jnp.asarray(yx).T, jnp.asarray(Ax).T,
+        jnp.asarray(y).T, jnp.asarray(yx).T,
         n_sweeps=n_sweeps, n_refine=n_refine, sigma=sigma, alpha=alpha,
         bs=S, interpret=True,
     )
-    got = [np.asarray(o).T for o in outs]
-    for g, r, name in zip(got, ref, ["x", "z", "zx", "y", "yx", "Ax"]):
+    got = [np.asarray(o).T for o in outs[:5]]
+    for g, r, name in zip(got, ref, ["x", "z", "zx", "y", "yx"]):
         np.testing.assert_allclose(g, np.asarray(r), rtol=1e-10, atol=1e-12,
                                    err_msg=name)
+    assert len(outs[5]) == 4 and all(r.shape == (S,) for r in outs[5])
 
 
 def test_usable_gating():
@@ -181,25 +186,229 @@ def test_fused_sweeps_default_precision_matches_emulation():
     tT = lambda a: jnp.transpose(jnp.asarray(a), (1, 2, 0))
     bf = lambda a: a.astype(jnp.bfloat16)
     outs = pallas_kernels.fused_sweeps(
-        jnp.asarray(q).T, bf(tT(A)),
+        jnp.asarray(q).T, jnp.zeros((n, S)), bf(tT(A)),
         bf(jnp.transpose(jnp.asarray(A), (2, 1, 0))), bf(tT(Kinv)), tT(K),
         jnp.asarray(cl).T, jnp.asarray(cu).T,
         jnp.asarray(lb).T, jnp.asarray(ub).T,
         jnp.asarray(rho_a).T, jnp.asarray(rho_x).T,
         jnp.asarray(x).T, jnp.asarray(z).T, jnp.asarray(zx).T,
-        jnp.asarray(y).T, jnp.asarray(yx).T, jnp.asarray(Ax).T,
+        jnp.asarray(y).T, jnp.asarray(yx).T,
         n_sweeps=n_sweeps, n_refine=n_refine, sigma=sigma, alpha=alpha,
         bs=S, precision="default", interpret=True,
     )
-    got = [np.asarray(o).T for o in outs]
+    # the lowered mode's residuals are pinned to float32 operands, which
+    # the kernel (A in bf16) does not hold: it hands back the state alone
+    assert outs[5] is None
+    got = [np.asarray(o).T for o in outs[:5]]
     # tolerance floor: the XLA emulation accumulates in f32 (the TPU MXU
     # accumulator) while the interpret-mode kernel under x64 accumulates
     # the IDENTICAL bf16 products in f64 — a ~1e-7 accumulation-order
     # difference, far below the bf16 operand error the modes introduce
-    for g, r, name in zip(got, (rx, rz, rzx, ry, ryx, rAx),
-                          ["x", "z", "zx", "y", "yx", "Ax"]):
+    for g, r, name in zip(got, (rx, rz, rzx, ry, ryx),
+                          ["x", "z", "zx", "y", "yx"]):
         np.testing.assert_allclose(g, np.asarray(r), rtol=1e-5, atol=1e-6,
                                    err_msg=name)
+
+
+# --------------------------------------------------------------------------
+# The kernel's checkpoint: the residual rows of the iterate it ends on, and
+# the sweep loop that carries the lanes layout
+# --------------------------------------------------------------------------
+
+_F32 = dict(dtype="float32", eps_abs=1e-5, eps_rel=1e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _farmer_step(crops_multiplier, dtype, S=300):
+    """What one step of ``admm._sweep_loop`` reads on a farmer batch, Ruiz
+    scaled as the engine scales it: the problem, the factors of a refresh
+    solve and its raw iterate, with the linear term moved (a PH iteration's
+    W) so that the iterate is some sweeps from the test."""
+    import jax.numpy as jnp
+
+    from tpusppy.ir import ScenarioBatch
+    from tpusppy.models import farmer
+    from tpusppy.solvers import admm
+
+    b = ScenarioBatch.from_problems(
+        [farmer.scenario_creator(nm, num_scens=S,
+                                 crops_multiplier=crops_multiplier)
+         for nm in farmer.scenario_names_creator(S)])
+    st = admm.ADMMSettings(**(_F32 if dtype == "float32" else {}))
+    dt = st.jdtype()
+    prob = (b.c, b.q2, b.A, b.cl, b.cu, b.lb, b.ub)
+    sol, f = admm.solve_batch_factored(*prob, settings=st)
+    c, q2, A, cl, cu, lb, ub, _, _ = admm._prep(*prob, st, None,
+                                                want_masks=False)
+    c = c * (1.0 + 0.05 * jnp.asarray(
+        np.random.RandomState(5).randn(*c.shape), dt))
+    q, q2, A, cl, cu, lb, ub, _, warm = admm._scale(
+        c, q2, A, cl, cu, lb, ub, f.D, f.E, f.cost, None, sol.raw, dt)
+    x, z, y, yx = warm
+    return st, (q, q2, A, cl, cu, lb, ub), f, (x, z, jnp.clip(x, lb, ub),
+                                               y, yx)
+
+
+def _lanes_args(prob, f, state):
+    """``fused_sweeps``'s operands, as ``admm._lanes_sweep_loop`` lays
+    them out."""
+    import jax.numpy as jnp
+
+    q, q2, A, cl, cu, lb, ub = prob
+    tT = lambda a: jnp.transpose(a, (1, 2, 0))
+    return (q.T, q2.T, tT(A), jnp.transpose(A, (2, 1, 0)), tT(f.Kinv),
+            tT(f.K), cl.T, cu.T, lb.T, ub.T, f.rho_a.T, f.rho_x.T,
+            *(v.T for v in state))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("bs", [300, 128], ids=["one_block", "ragged"])
+@pytest.mark.parametrize("crops_multiplier", [1, 4])
+def test_fused_sweeps_hands_back_the_residual_rows_of_its_last_iterate(
+        crops_multiplier, bs, dtype):
+    """Through the interpreter, on a farmer batch (n = 11 or 44; one block,
+    or blocks of 128 with a ragged last one of 44): the state is the XLA
+    sweep recurrence's, and the four residual rows are
+    ``admm.residual_rows`` (the formulas of ``_sweep_loop``'s XLA
+    checkpoint, with a true ``A x``) of the iterate the kernel ended on."""
+    import jax.numpy as jnp
+
+    from tpusppy.solvers import admm
+
+    st, prob, f, state = _farmer_step(crops_multiplier, dtype)
+    q, q2, A, cl, cu, lb, ub = prob
+    S, m, n = A.shape
+    assert (m, n) == (7 * crops_multiplier, 11 * crops_multiplier)
+    assert A.dtype == jnp.dtype(dtype)
+    n_sweeps = max(1, st.check_every)
+    *got, rows = pallas_kernels.fused_sweeps(
+        *_lanes_args(prob, f, state), n_sweeps=n_sweeps,
+        n_refine=st.solve_refine, sigma=float(st.sigma),
+        alpha=float(st.alpha), bs=bs, interpret=True)
+    got = [o.T for o in got]
+    Ax0 = jnp.einsum("smn,sn->sm", A, state[0])
+    ref = _xla_sweeps(q, A, cl, cu, lb, ub, f.rho_a, f.rho_x,
+                      (*state, Ax0), n_sweeps, st.solve_refine, st.sigma,
+                      st.alpha, f.Kinv, f.K)
+    # float32: both sum the same products in another order, four sweeps
+    # through a K of condition 1e3
+    tol = 2e-4 if dtype == "float32" else 1e-9
+    for g, r, name in zip(got, ref, ["x", "z", "zx", "y", "yx"]):
+        assert g.dtype == jnp.dtype(dtype) and g.shape == r.shape
+        scale = float(jnp.max(jnp.abs(r))) + 1.0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0,
+                                   atol=tol * scale, err_msg=name)
+    x, z, zx, y, yx = got
+    want = admm.residual_rows(
+        q, x, z, zx, y, yx, jnp.einsum("smn,sn->sm", A, x),
+        lambda y: jnp.einsum("smn,sm->sn", A, y), lambda x: q2 * x,
+        lambda v: jnp.max(jnp.abs(v), axis=1))
+    assert len(rows) == 4
+    # the residuals cancel against norms of order one: held to the norms
+    norm = np.maximum(np.asarray(want[2]), np.asarray(want[3])) + 1.0
+    tol = 2e-6 if dtype == "float32" else 1e-13
+    for g, r, name in zip(rows, want,
+                          ["pri", "dua", "prinorm", "duanorm"]):
+        assert g.shape == (S,) and g.dtype == jnp.dtype(dtype)
+        assert np.all(np.abs(np.asarray(g) - np.asarray(r)) <= tol * norm), \
+            name
+
+
+@pytest.fixture
+def sweep_kernel_interpreted(monkeypatch):
+    """``admm._sweep_block`` answered as on the TPU (a block of 128, the
+    whole batch where it is smaller) and the kernel run through the Pallas
+    interpreter: the test steers the program's choice, the program has no
+    option for it."""
+    from tpusppy.solvers import admm
+
+    monkeypatch.setattr(
+        admm, "_sweep_block",
+        lambda st, S, m, n, P=None, prec=None: (
+            min(S, 128), "default" if prec == "default" else "highest"))
+    monkeypatch.setattr(
+        pallas_kernels, "fused_sweeps",
+        functools.partial(pallas_kernels.fused_sweeps, interpret=True))
+
+
+@pytest.mark.parametrize("leave_at", [None, 0, 40],
+                         ids=["plain", "rung", "full_width"])
+def test_sweep_loop_on_the_kernel_agrees_with_the_xla_loop(
+        leave_at, request):
+    """``admm._sweep_loop`` on one farmer batch (cm1, S = 300, float64),
+    on XLA's sweep and with each step one kernel call (interpreted, three
+    blocks, the state carried in the lanes layout): the same ``k``, the
+    same rows done, ``since`` within one checkpoint, the iterates to float
+    tolerance; in each of the loop's three roles under ``_admm_core``."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpusppy.solvers import admm
+
+    st, prob, f, (x, z, zx, y, yx) = _farmer_step(1, "float64")
+    st = dataclasses.replace(st, max_iter=120, eps_abs=1e-4, eps_rel=1e-4)
+    state0 = admm._start_state(x, z, zx, y, yx)
+
+    def loop():
+        return jax.jit(lambda: admm._sweep_loop(
+            *prob, state0, (f.Kinv, f.K), f.rho_a, f.rho_x, st,
+            leave_at=leave_at))()
+
+    xla = loop()
+    request.getfixturevalue("sweep_kernel_interpreted")
+    lanes = loop()
+    assert int(lanes.k) == int(xla.k) > 0
+    done = lambda s: np.asarray(admm._done_mask(s.pri, s.dua, s.prinorm,
+                                                s.duanorm, st))
+    np.testing.assert_array_equal(done(lanes), done(xla))
+    assert 0 < done(xla).sum() < x.shape[0]
+    ck = max(1, st.check_every)
+    assert np.all(np.abs(np.asarray(lanes.since) - np.asarray(xla.since))
+                  <= ck)
+    if leave_at is None:
+        assert not np.any(np.asarray(lanes.since))      # not kept
+    else:
+        assert np.any(np.asarray(lanes.since))
+    for name in ("x", "z", "zx", "y", "yx", "pri", "dua", "prinorm",
+                 "duanorm"):
+        a, b = getattr(lanes, name), getattr(xla, name)
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0,
+            atol=1e-9 * (float(jnp.max(jnp.abs(b))) + 1.0), err_msg=name)
+    for name in ("narrow", "swept", "best", "stall"):
+        np.testing.assert_array_equal(np.asarray(getattr(lanes, name)),
+                                      np.asarray(getattr(xla, name)))
+
+
+def test_sweep_loop_lowered_mode_keeps_its_residuals_in_float32(
+        sweep_kernel_interpreted):
+    """Under ``prec="default"`` the kernel holds ``A`` in bf16 and hands
+    back no residual rows: the loop makes them in XLA from the float32
+    ``A``, on the lanes-layout state.  The rows it ends with are
+    ``residual_rows`` of its final iterate against the float32 matrices to
+    float32 rounding, which bf16 products (2e-3) would miss by far."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpusppy.solvers import admm
+
+    st, prob, f, (x, z, zx, y, yx) = _farmer_step(1, "float32")
+    st = dataclasses.replace(st, max_iter=8)
+    q, q2, A, cl, cu, lb, ub = prob
+    out = jax.jit(lambda: admm._sweep_loop(
+        *prob, admm._start_state(x, z, zx, y, yx), (f.Kinv, f.K), f.rho_a,
+        f.rho_x, st, prec="default"))()
+    assert int(out.k) == 8
+    hi = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    want = admm.residual_rows(
+        q, out.x, out.z, out.zx, out.y, out.yx, hi("smn,sn->sm", A, out.x),
+        lambda y: hi("smn,sm->sn", A, y), lambda x: q2 * x,
+        lambda v: jnp.max(jnp.abs(v), axis=1))
+    norm = np.maximum(np.asarray(want[2]), np.asarray(want[3])) + 1.0
+    for name, r in zip(("pri", "dua", "prinorm", "duanorm"), want):
+        assert np.all(np.abs(np.asarray(getattr(out, name)) - np.asarray(r))
+                      <= 2e-6 * norm), name
 
 
 # --------------------------------------------------------------------------

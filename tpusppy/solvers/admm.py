@@ -560,6 +560,15 @@ def _sweep_block(st: ADMMSettings, S, m, n, P=None, prec=None):
     return bs, kprec
 
 
+def kernel_checkpoint(st: ADMMSettings, S, m, n) -> bool:
+    """Whether an adaptive (refresh) solve of an (S, m, n) dense batch runs
+    each step of its sweep loop as one kernel call that makes the residuals
+    too (``_lanes_sweep_loop`` at full precision, as a refresh always is):
+    the host's twin of the choice ``_sweep_block`` makes while tracing
+    (spopt counts ``refresh.kernel_checkpoint`` by it)."""
+    return _sweep_block(st, S, m, n)[0] is not None
+
+
 def _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
                 st: ADMMSettings, P=None, prec=None, leave_at=None):
     """The sweep loop at fixed rho and at the width of its arguments:
@@ -567,7 +576,9 @@ def _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
     ``_admm_core``'s cascade (``leave_at`` not None) it keeps
     ``state.since`` and, where ``leave_at`` is not 0, also leaves once at
     most that many rows are not settled (``_settled``).  Returns final
-    state.
+    state.  Where ``_sweep_block`` picks a block, the ``while_loop`` is
+    ``_lanes_sweep_loop``'s (one kernel call a step); the test ``cont`` and
+    the bookkeeping ``checkpoint`` are the same for both.
 
     ``prec``: None keeps the legacy (ambient-precision) program
     byte-for-byte; a mode string runs the SWEEP matvecs at that precision
@@ -612,29 +623,7 @@ def _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
         yx_new = yx + rho_x * (alpha * xt + (1 - alpha) * zx - zx_new)
         return x_new, z_new, zx_new, y_new, yx_new, Ax_new
 
-    def residuals(x, z, zx, y, yx, Ax):
-        pri = jnp.maximum(
-            jnp.max(jnp.abs(Ax - z), axis=1),
-            jnp.max(jnp.abs(x - zx), axis=1),
-        )
-        Aty = hi("smn,sm->sn", A, y)
-        Pxv = Px(x)
-        dua = jnp.max(jnp.abs(Pxv + q + Aty + yx), axis=1)
-        # OSQP-normalized residual scales, for tolerances and rho adaptation
-        prinorm = jnp.maximum(
-            jnp.max(jnp.abs(Ax), axis=1), jnp.max(jnp.abs(z), axis=1)
-        )
-        duanorm = jnp.maximum(
-            jnp.maximum(
-                jnp.max(jnp.abs(Pxv), axis=1),
-                jnp.max(jnp.abs(Aty), axis=1),
-            ),
-            jnp.max(jnp.abs(q), axis=1),
-        )
-        return pri, dua, prinorm, duanorm
-
-    def cont(carry):
-        s, Ax = carry
+    def cont(s):
         # OSQP termination: eps_abs + eps_rel * residual-scale norms
         done = _done_mask(s.pri, s.dua, s.prinorm, s.duanorm, st)
         go = (s.k < st.max_iter) & ~jnp.all(done)
@@ -645,51 +634,12 @@ def _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
             go = go & (s.stall < 2)
         return go
 
-    # fused Pallas sweep block on TPU: all matrices stay in VMEM across the
-    # check_every sweeps instead of re-streaming from HBM every sweep, in
-    # scenario-on-lanes layout (matrices transposed ONCE per rho setting)
-    from . import pallas_kernels
+    ck = max(1, st.check_every)
 
-    S, m, n = A.shape
-    bs, kprec = _sweep_block(st, S, m, n, P, prec)
-    if bs is not None:
-        Kinv, K = LK
-        tT = lambda a: jnp.transpose(a, (1, 2, 0))
-        AT, AtT = tT(A), jnp.transpose(A, (2, 1, 0))
-        KinvT, KT = tT(Kinv), tT(K)
-        if kprec == "default":
-            # bf16 storage for the sweep matrices (halved VMEM -> bigger
-            # blocks); K stays f32 — it is the refinement DEFECT operand,
-            # which must be exact (matches the XLA path's pinned-f32 defect)
-            AT, AtT, KinvT = (a.astype(jnp.bfloat16)
-                              for a in (AT, AtT, KinvT))
-        qT, clT, cuT, lbT, ubT = q.T, cl.T, cu.T, lb.T, ub.T
-        rho_aT, rho_xT = rho_a.T, jnp.broadcast_to(rho_x, (S, n)).T
-
-    def multi_step(carry):
-        # unrolled sweeps between termination checks: each sweep is a handful
-        # of tiny batched matvecs, so per-iteration overhead and residual
-        # bookkeeping are amortized over check_every sweeps
-        s, Ax = carry
-        x, z, zx, y, yx = s.x, s.z, s.zx, s.y, s.yx
-        if bs is not None:
-            outs = pallas_kernels.fused_sweeps(
-                qT, AT, AtT, KinvT, KT, clT, cuT, lbT, ubT, rho_aT, rho_xT,
-                x.T, z.T, zx.T, y.T, yx.T, Ax.T,
-                n_sweeps=max(1, st.check_every),
-                n_refine=st.solve_refine, sigma=float(sigma),
-                alpha=float(alpha), bs=bs, precision=kprec,
-            )
-            x, z, zx, y, yx, Ax = (o.T for o in outs)
-        else:
-            for _ in range(max(1, st.check_every)):
-                x, z, zx, y, yx, Ax = sweep(x, z, zx, y, yx, Ax)
-        # re-anchor the incrementally carried Ax: the relaxation combination
-        # (alpha=1.6) amplifies carried floating error exponentially across
-        # sweeps, so one true matvec per checkpoint resets the drift
-        # (pinned f32 under a low sweep mode — the defect control)
-        Ax = hi("smn,sn->sm", A, x)
-        pri, dua, prinorm, duanorm = residuals(x, z, zx, y, yx, Ax)
+    def checkpoint(s, x, z, zx, y, yx, res):
+        """The state after one step: the iterate, its residual rows and the
+        (S,) bookkeeping on them."""
+        pri, dua, prinorm, duanorm = res
         if st.sweep_plateau_rtol > 0:
             best, stall = _plateau_update(s, pri, dua, prinorm, duanorm, st)
         else:
@@ -697,14 +647,119 @@ def _sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
         since = s.since
         if leave_at is not None:
             since = jnp.where(_done_mask(pri, dua, prinorm, duanorm, st),
-                              since + max(1, st.check_every), 0)
-        return (_IterState(x, z, zx, y, yx, pri, dua, prinorm, duanorm,
-                           s.k + max(1, st.check_every), best, stall,
-                           s.narrow, s.swept, since), Ax)
+                              since + ck, 0)
+        return _IterState(x, z, zx, y, yx, pri, dua, prinorm, duanorm,
+                          s.k + ck, best, stall, s.narrow, s.swept, since)
+
+    S, m, n = A.shape
+    bs, kprec = _sweep_block(st, S, m, n, P, prec)
+    if bs is not None:
+        return _lanes_sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a,
+                                 rho_x, st, bs, kprec, cont, checkpoint)
+
+    def multi_step(carry):
+        # unrolled sweeps between termination checks: each sweep is a handful
+        # of tiny batched matvecs, so per-iteration overhead and residual
+        # bookkeeping are amortized over check_every sweeps
+        s, Ax = carry
+        x, z, zx, y, yx = s.x, s.z, s.zx, s.y, s.yx
+        for _ in range(ck):
+            x, z, zx, y, yx, Ax = sweep(x, z, zx, y, yx, Ax)
+        # re-anchor the incrementally carried Ax: the relaxation combination
+        # (alpha=1.6) amplifies carried floating error exponentially across
+        # sweeps, so one true matvec per checkpoint resets the drift
+        # (pinned f32 under a low sweep mode — the defect control)
+        Ax = hi("smn,sn->sm", A, x)
+        res = residual_rows(
+            q, x, z, zx, y, yx, Ax, lambda y: hi("smn,sm->sn", A, y), Px,
+            lambda v: jnp.max(jnp.abs(v), axis=1))
+        return checkpoint(s, x, z, zx, y, yx, res), Ax
 
     Ax0 = jnp.einsum("smn,sn->sm", A, state.x)
-    state, _ = jax.lax.while_loop(cont, multi_step, (state, Ax0))
+    state, _ = jax.lax.while_loop(lambda carry: cont(carry[0]), multi_step,
+                                  (state, Ax0))
     return state
+
+
+def residual_rows(q, x, z, zx, y, yx, Ax, Aty_of, Px_of, top):
+    """``(pri, dua, prinorm, duanorm)`` of an iterate, one value a scenario:
+    the residuals of the sweep loop's test and the OSQP-normalized scales
+    for its tolerances and the rho adaptation.  One set of formulas for
+    every layout the loop runs in: ``Ax`` is a TRUE product of ``x``,
+    ``Aty_of(y)`` gives ``A' y``, ``Px_of(x)`` the quadratic term's product
+    and ``top(v)`` the largest magnitude of a scenario's entries (the XLA
+    sweep's rows; the same on the lanes, in XLA's hands under the lowered
+    mode and in ``pallas_kernels.fused_sweeps`` otherwise)."""
+    pri = jnp.maximum(top(Ax - z), top(x - zx))
+    Aty = Aty_of(y)
+    Pxv = Px_of(x)
+    dua = top(Pxv + q + Aty + yx)
+    prinorm = jnp.maximum(top(Ax), top(z))
+    duanorm = jnp.maximum(jnp.maximum(top(Pxv), top(Aty)), top(q))
+    return pri, dua, prinorm, duanorm
+
+
+def _lanes_sweep_loop(q, q2, A, cl, cu, lb, ub, state, LK, rho_a, rho_x,
+                      st: ADMMSettings, bs, kprec, cont, checkpoint):
+    """``_sweep_loop``'s ``while_loop`` where the fused Pallas sweep block
+    runs it (``_sweep_block`` picked ``bs``): all matrices stay in VMEM
+    across the check_every sweeps instead of re-streaming from HBM every
+    sweep, in scenario-on-lanes layout.  Matrices and state are transposed
+    ONCE per rho setting, on the way in, and the state once on the way out:
+    a step of the loop is one kernel call, which hands back the residual
+    rows of the iterate it ends on, and ``checkpoint``'s (S,) bookkeeping.
+
+    Under the lowered sweep mode (``kprec == "default"``) the kernel holds
+    ``A`` in bf16, and the residuals are pinned to float32 operands
+    (doc/precision.md): their products stay XLA's, on the lanes-layout
+    state and the float32 ``A`` from before the cast."""
+    from . import pallas_kernels
+
+    S, m, n = A.shape
+    Kinv, K = LK
+    tT = lambda a: jnp.transpose(a, (1, 2, 0))
+    AT, AtT = tT(A), jnp.transpose(A, (2, 1, 0))
+    KinvT, KT = tT(Kinv), tT(K)
+    qT, q2T = q.T, q2.T
+    lowered = kprec == "default"
+    if lowered:
+        from . import precision
+        hi = lambda spec, a, b: precision.contract(spec, a, b, "highest")
+        A32 = AT
+        # bf16 storage for the sweep matrices (halved VMEM -> bigger
+        # blocks); K stays f32 — it is the refinement DEFECT operand,
+        # which must be exact (matches the XLA path's pinned-f32 defect)
+        AT, AtT, KinvT = (a.astype(jnp.bfloat16) for a in (AT, AtT, KinvT))
+    fixed = (qT, q2T, AT, AtT, KinvT, KT, cl.T, cu.T, lb.T, ub.T, rho_a.T,
+             jnp.broadcast_to(rho_x, (S, n)).T)
+
+    # the residual rows travel as the one (4, S) array the kernel makes
+    rows_of = lambda s: jnp.stack([s.pri, s.dua, s.prinorm, s.duanorm])
+    bare = lambda s: s._replace(pri=None, dua=None, prinorm=None,
+                                duanorm=None)
+    whole = lambda carry: carry[0]._replace(
+        **dict(zip(("pri", "dua", "prinorm", "duanorm"), carry[1])))
+
+    def step(carry):
+        s = whole(carry)
+        *it, res = pallas_kernels.fused_sweeps(
+            *fixed, s.x, s.z, s.zx, s.y, s.yx,
+            n_sweeps=max(1, st.check_every), n_refine=st.solve_refine,
+            sigma=float(st.sigma), alpha=float(st.alpha), bs=bs,
+            precision=kprec)
+        if lowered:
+            res = jnp.stack(residual_rows(
+                qT, *it, hi("mns,ns->ms", A32, it[0]),
+                lambda y: hi("mns,ms->ns", A32, y), lambda x: q2T * x,
+                lambda v: jnp.max(jnp.abs(v), axis=0)))
+        return bare(checkpoint(s, *it, tuple(res))), res
+
+    lanes = lambda s: s._replace(x=s.x.T, z=s.z.T, zx=s.zx.T, y=s.y.T,
+                                 yx=s.yx.T)
+    state = lanes(state)
+    return lanes(whole(jax.lax.while_loop(
+        lambda carry: cont(whole(carry)), step,
+        (bare(state), rows_of(state)))))
 
 
 # an ``_IterState``'s fields that hold a row to each scenario

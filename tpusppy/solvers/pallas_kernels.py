@@ -20,6 +20,15 @@ once per kernel call instead of once per sweep — the hot-op fusion the build
 brief calls for (SURVEY §7 step 2; the XLA einsum path remains the fallback for
 CPU, dense-P, and shapes that exceed the VMEM budget).
 
+One call is one whole step of the engine's sweep loop (``admm._sweep_loop``):
+beside the state the kernel hands back the four residual rows of the iterate
+it ends on (``admm.residual_rows``, from a true ``A x`` of the final ``x``),
+while ``A`` is at hand and not padded, and the state's outputs alias its
+inputs, so the loop carries the state in this layout from its first step to
+its last and nothing but (S,) bookkeeping stands between two calls.  The
+lowered mode (bf16 matrix storage) hands back the state alone: its residuals
+are pinned to float32 operands, which the kernel does not hold.
+
 All contractions are per-scenario matvecs with tiny n/m (tens), so the VPU
 multiply-reduce form ``(M * v[:, None, :]).sum(-1)`` is used rather than MXU
 dots (the 128-lane MXU tiles would be mostly padding at these sizes).
@@ -60,11 +69,11 @@ def sweep_block_size(S, m, n, itemsize=4, precision="highest") -> int:
     return int(min(S, bs))
 
 
-def _sweeps_kernel(q_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
-                   lb_ref, ub_ref, rho_a_ref, rho_x_ref, x_ref, z_ref,
-                   zx_ref, y_ref, yx_ref, Ax_ref, x_out, z_out, zx_out,
-                   y_out, yx_out, Ax_out, *, n_sweeps, n_refine, sigma,
-                   alpha, m, n, precision):
+def _sweeps_kernel(q_ref, q2_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref,
+                   cu_ref, lb_ref, ub_ref, rho_a_ref, rho_x_ref, x_ref,
+                   z_ref, zx_ref, y_ref, yx_ref, x_out, z_out, zx_out,
+                   y_out, yx_out, res_out=None, *, n_sweeps, n_refine,
+                   sigma, alpha, m, n, precision):
     """Scenario-on-lanes layout: every tensor is (..., Sb) with the scenario
     block on the 128-lane axis, so each matvec step is a full-width VPU
     multiply-accumulate.  Contractions loop over the LEADING (untiled) dim
@@ -79,7 +88,11 @@ def _sweeps_kernel(q_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
     XLA mixed-precision sweep emulation (solvers/precision.py), with the
     refinement defect against the f32 K exact.  Every other mode runs the
     exact f32 path (the VPU has no MXU passes to economize, so "high"
-    here is simply full f32 — at least as accurate as bf16x3 asks)."""
+    here is simply full f32 — at least as accurate as bf16x3 asks).
+
+    ``res_out`` (4, Sb), in every mode but "default": the residual rows
+    ``pri``, ``dua``, ``prinorm``, ``duanorm`` of the iterate the sweeps end
+    on, the formulas of the engine's own checkpoint."""
     dt = K_ref.dtype
     # matrices stay in their STORAGE dtype (bf16 under "default" — that is
     # the VMEM dividend); upcasts happen per leading-dim slice inside the
@@ -91,8 +104,7 @@ def _sweeps_kernel(q_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
     q = q_ref[:]          # (n, Sb)
     cl, cu, lb, ub = cl_ref[:], cu_ref[:], lb_ref[:], ub_ref[:]
     rho_a, rho_x = rho_a_ref[:], rho_x_ref[:]
-    x, z, zx, y, yx, Ax = (x_ref[:], z_ref[:], zx_ref[:], y_ref[:],
-                           yx_ref[:], Ax_ref[:])
+    x, z, zx, y, yx = x_ref[:], z_ref[:], zx_ref[:], y_ref[:], yx_ref[:]
     lowered = precision == "default"
 
     def rnd(v):
@@ -108,7 +120,7 @@ def _sweeps_kernel(q_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
         return acc
 
     def body(_, carry):
-        x, z, zx, y, yx, Ax = carry
+        x, z, zx, y, yx = carry
         rhs = (sigma * x - q + contract(A, rnd(rho_a * z - y), m)
                + (rho_x * zx - yx))
         xt = contract(Kinv, rnd(rhs), n)      # Kinv symmetric
@@ -117,7 +129,6 @@ def _sweeps_kernel(q_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
             xt = xt + contract(Kinv, rnd(r), n)
         Axt = contract(At, rnd(xt), n)
         x_new = alpha * xt + (1 - alpha) * x
-        Ax_new = alpha * Axt + (1 - alpha) * Ax
 
         za_arg = alpha * Axt + (1 - alpha) * z + y / rho_a
         z_new = jnp.clip(za_arg, cl, cu)
@@ -126,26 +137,40 @@ def _sweeps_kernel(q_ref, A_ref, At_ref, Kinv_ref, K_ref, cl_ref, cu_ref,
         zx_arg = alpha * xt + (1 - alpha) * zx + yx / rho_x
         zx_new = jnp.clip(zx_arg, lb, ub)
         yx_new = yx + rho_x * (alpha * xt + (1 - alpha) * zx - zx_new)
-        return x_new, z_new, zx_new, y_new, yx_new, Ax_new
+        return x_new, z_new, zx_new, y_new, yx_new
 
-    x, z, zx, y, yx, Ax = jax.lax.fori_loop(
-        0, n_sweeps, body, (x, z, zx, y, yx, Ax))
+    x, z, zx, y, yx = jax.lax.fori_loop(0, n_sweeps, body,
+                                        (x, z, zx, y, yx))
     x_out[:] = x
     z_out[:] = z
     zx_out[:] = zx
     y_out[:] = y
     yx_out[:] = yx
-    Ax_out[:] = Ax
+    if res_out is not None:
+        from .admm import residual_rows
+
+        q2 = q2_ref[:]
+        rows = residual_rows(
+            q, x, z, zx, y, yx, contract(At, x, n),
+            lambda y: contract(A, y, m), lambda x: q2 * x,
+            lambda v: jnp.max(jnp.abs(v), axis=0, keepdims=True))
+        for i, row in enumerate(rows):
+            res_out[i:i + 1, :] = row
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_sweeps", "n_refine", "sigma", "alpha",
                                     "bs", "precision", "interpret"))
-def fused_sweeps(q, A, At, Kinv, K, cl, cu, lb, ub, rho_a, rho_x,
-                 x, z, zx, y, yx, Ax, n_sweeps, n_refine, sigma, alpha, bs,
+def fused_sweeps(q, q2, A, At, Kinv, K, cl, cu, lb, ub, rho_a, rho_x,
+                 x, z, zx, y, yx, n_sweeps, n_refine, sigma, alpha, bs,
                  precision="highest", interpret=False):
     """Run ``n_sweeps`` sweeps; ALL arrays in scenario-last layout
-    (m,n,S)/(n,S) etc.  Returns transposed-state (x, z, zx, y, yx, Ax).
+    (m,n,S)/(n,S) etc.  Returns the transposed state and the residual rows
+    of the iterate it ends on, ``(x, z, zx, y, yx, res)``: ``res`` (4, S)
+    holds ``pri``, ``dua``, ``prinorm``, ``duanorm``, one value a scenario,
+    in full float32 from a true ``A x`` of the final ``x`` (``None`` under
+    ``precision="default"``, whose matrices the kernel holds in bf16: the
+    caller keeps those products).  The state's outputs alias its inputs.
 
     ``precision="default"`` is the mixed-precision sweep mode: pass
     A/At/Kinv in bf16 (callers cast; K stays f32 for exact defects) —
@@ -171,19 +196,13 @@ def fused_sweeps(q, A, At, Kinv, K, cl, cu, lb, ub, rho_a, rho_x,
                              n_refine=n_refine, sigma=sigma, alpha=alpha,
                              m=m, n=n, precision=precision)
     dt = K.dtype
-    out_shape = [
-        jax.ShapeDtypeStruct((n, S), dt),   # x
-        jax.ShapeDtypeStruct((m, S), dt),   # z
-        jax.ShapeDtypeStruct((n, S), dt),   # zx
-        jax.ShapeDtypeStruct((m, S), dt),   # y
-        jax.ShapeDtypeStruct((n, S), dt),   # yx
-        jax.ShapeDtypeStruct((m, S), dt),   # Ax
-    ]
-    return pl.pallas_call(
+    # x z zx y yx, and the four residual rows
+    dims = [n, m, n, m, n] + ([] if precision == "default" else [4])
+    outs = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            spec2(n),            # q
+            spec2(n), spec2(n),  # q q2
             spec3(m, n),         # A
             spec3(n, m),         # At
             spec3(n, n),         # Kinv
@@ -192,13 +211,14 @@ def fused_sweeps(q, A, At, Kinv, K, cl, cu, lb, ub, rho_a, rho_x,
             spec2(n), spec2(n),  # lb ub
             spec2(m), spec2(n),  # rho_a rho_x
             spec2(n), spec2(m), spec2(n), spec2(m), spec2(n),  # x z zx y yx
-            spec2(m),            # Ax
         ],
-        out_specs=[spec2(n), spec2(m), spec2(n), spec2(m), spec2(n),
-                   spec2(m)],
-        out_shape=out_shape,
+        out_specs=[spec2(d) for d in dims],
+        out_shape=[jax.ShapeDtypeStruct((d, S), dt) for d in dims],
+        # the state is updated in place: inputs 12-16 are outputs 0-4
+        input_output_aliases={12 + i: i for i in range(5)},
         interpret=interpret,
-    )(q, A, At, Kinv, K, cl, cu, lb, ub, rho_a, rho_x, x, z, zx, y, yx, Ax)
+    )(q, q2, A, At, Kinv, K, cl, cu, lb, ub, rho_a, rho_x, x, z, zx, y, yx)
+    return (*outs[:5], outs[5] if len(outs) > 5 else None)
 
 
 def usable(S, m, n, platform=None, P=None, precision="highest") -> int | None:

@@ -557,6 +557,10 @@ class SPOpt(SPBase):
                 if admm.lanes_inverse(st_adpt, *args[2].shape):
                     # and its four K's were inverted on the same kernel
                     _metrics.inc("refresh.lanes_inverse")
+                if admm.kernel_checkpoint(st_adpt, *args[2].shape):
+                    # each step of its sweep loop was one call of
+                    # pallas_kernels.fused_sweeps, residuals included
+                    _metrics.inc("refresh.kernel_checkpoint")
             if shared and isinstance(factors.Kinv, DiagLowRank):
                 # this refresh's factors apply K^-1 as diagonal plus
                 # low rank (structured_kkt.lowrank_kinv)
